@@ -308,3 +308,38 @@ def find_gathers(instrs, min_bytes=0):
     GSPMD full-remat embedding-gather shape report."""
     return [ins for ins in instrs
             if ins.op == "gather" and ins.bytes >= min_bytes]
+
+
+MOSAIC_TARGET = "tpu_custom_call"
+_PALLAS_SCOPE_RE = re.compile(r'op_name="(?:[^"]*/)?([^/"]+)/pallas_call')
+_LHS_NAME_RE = re.compile(r"\s*(?:ROOT\s+)?%?([\w.-]+)\s*=")
+
+
+def mosaic_kernels(hlo_text):
+    """{kernel name: count} of the Pallas/Mosaic kernels in a compiled
+    HLO module: every ``custom-call`` whose target is
+    ``tpu_custom_call``. The name is the ``pallas_call``'s ``name=``
+    argument, which XLA carries as the path element before
+    ``pallas_call`` in the instruction's ``op_name`` metadata, bare
+    (".../flash_fwd/pallas_call") or inside autodiff wrappers
+    (".../transpose(jvp(flash_dq))/pallas_call"); a kernel without it
+    counts under the instruction's own name. chip_smoke.py asserts on this
+    that the step the chip ran holds the kernel and not a reference
+    path."""
+    # line scan, not parse_instructions: TPU tuple results carry tiled
+    # layouts with nested parentheses, which _INSTR_RE's type group
+    # does not span
+    out = {}
+    marker = 'custom_call_target="%s"' % MOSAIC_TARGET
+    for line in hlo_text.splitlines():
+        if marker not in line:
+            continue
+        scope = _PALLAS_SCOPE_RE.search(line)
+        inner = re.findall(r"[\w.-]+", scope.group(1)) if scope else []
+        if inner:
+            name = inner[-1]
+        else:
+            lhs = _LHS_NAME_RE.match(line)
+            name = lhs.group(1) if lhs else "?"
+        out[name] = out.get(name, 0) + 1
+    return out

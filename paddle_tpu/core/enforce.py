@@ -1,7 +1,7 @@
-"""Typed error taxonomy + enforce helpers — the PADDLE_ENFORCE analog.
+"""Typed error hierarchy + enforce helpers — the PADDLE_ENFORCE analog.
 
 Parity: reference PADDLE_ENFORCE macro family (phi/core/enforce.h) and
-the error-code taxonomy (paddle/utils/error.h / platform/errors.h:
+the error-code hierarchy (paddle/utils/error.h / platform/errors.h:
 InvalidArgument, NotFound, OutOfRange, AlreadyExists, PermissionDenied,
 ResourceExhausted, PreconditionNotMet, Unimplemented, Unavailable,
 Fatal, ExecutionTimeout) plus the external-error summary formatting.

@@ -37,7 +37,13 @@ class Place:
             raise RuntimeError(
                 "No %s devices visible to PJRT" % self.device_type
             )
-        return devs[self.device_id % len(devs)]
+        # no wrap-around: TPUPlace(3) on a one-chip host is an error,
+        # not chip 0 under another name
+        if not 0 <= self.device_id < len(devs):
+            raise RuntimeError(
+                "%r: device id out of range, PJRT sees %d %s device(s)"
+                % (self, len(devs), self.device_type))
+        return devs[self.device_id]
 
 
 class TPUPlace(Place):
@@ -67,9 +73,10 @@ def _devices_by_type(device_type: str):
     if device_type.startswith("custom:"):
         # a registered PJRT plugin's OWN devices — never another backend
         return tuple(jax.devices(device_type.split(":", 1)[1]))
-    # "tpu" means "the accelerator backend" — whatever PJRT says is default.
-    devs = tuple(d for d in jax.devices() if d.platform != "cpu")
-    return devs or tuple(jax.devices())
+    # "tpu" means "the accelerator backend" — whatever PJRT says is
+    # default. A host with no accelerator has no such devices: resolving
+    # an accelerator place to a CPU device would hide that.
+    return tuple(d for d in jax.devices() if d.platform != "cpu")
 
 
 def is_compiled_with_cuda():  # API-compat shim: this framework targets TPU

@@ -6,7 +6,7 @@ Parity:
   array_write, array_read, array_length) used by while_loop bodies.
 - Scope: reference paddle/fluid/framework/scope.h — hierarchical
   name->Variable maps with parent lookup; Executor runs against a scope.
-- errors: reference PADDLE_ENFORCE error taxonomy
+- errors: reference PADDLE_ENFORCE error hierarchy
   (phi/core/enforce.h + platform/errors.h: InvalidArgument, NotFound,
   OutOfRange, Unimplemented, ...) surfaced as typed python exceptions.
 

@@ -18,13 +18,32 @@ from .completion import Completer, _entries
 from .partitioner import infer_reshard_comm, local_shape
 
 
-class MachineSpec:
-    """Per-chip peak numbers (defaults ~ v5e)."""
+# Published per-chip peaks, keyed by the string
+# ``jax.devices()[0].device_kind`` reports. A device that is not here is
+# an error for anything that divides by a peak (monitor/perf.py
+# machine_spec), never a default.
+DEVICE_PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s
+    # HBM (and 1,600 Gbit/s of interconnect per chip, all links, both
+    # ways). ici_bw is ONE link one way, 4.5e10 B/s — the public
+    # scaling-book hardware table — which is what a ring collective on
+    # one mesh axis sees.
+    "TPU v5 lite": {"peak_flops": 197e12, "hbm_bw": 819e9,
+                    "ici_bw": 45e9},
+}
 
-    def __init__(self, peak_flops=197e12, hbm_bw=819e9, ici_bw=45e9):
-        self.peak_flops = peak_flops
-        self.hbm_bw = hbm_bw
-        self.ici_bw = ici_bw
+
+class MachineSpec:
+    """Per-chip peak numbers of the machine a plan is made FOR. The
+    planner runs offline (tools/llama7b_plan.py plans a v5e pod from a
+    CPU host), so its default is a stated target — the v5e row of
+    DEVICE_PEAKS — not a guess about the local device."""
+
+    def __init__(self, peak_flops=None, hbm_bw=None, ici_bw=None):
+        target = DEVICE_PEAKS["TPU v5 lite"]
+        self.peak_flops = peak_flops or target["peak_flops"]
+        self.hbm_bw = hbm_bw or target["hbm_bw"]
+        self.ici_bw = ici_bw or target["ici_bw"]
 
 
 def _numel(shape):

@@ -27,18 +27,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, **kw):  # jax>=0.8 renamed check_rep -> check_vma
-        kw["check_vma"] = kw.pop("check_rep", False)
-        return _shard_map(f, **kw)
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map  # noqa: F401  (re-exported: one import site)
 
 from ..core.tensor import Tensor
 from ..monitor import flight_recorder as _flight
 from . import mesh as _mesh
+
 
 _REDUCE_OPS = {"sum": jax.lax.psum, "max": jax.lax.pmax, "min": jax.lax.pmin}
 
@@ -225,7 +219,7 @@ def _compiled_collective(kind, axis, shape, dtype, extra=()):
     else:
         raise ValueError(kind)
     fn = shard_map(f, mesh=mesh, in_specs=(in_spec,), out_specs=out_spec,
-                   check_rep=False)
+                   check_vma=False)
     return jax.jit(fn)
 
 

@@ -146,8 +146,16 @@ class Controller:
     # -- pod construction ----------------------------------------------
     def build_pod(self, restart_round=0):
         cfg = self.cfg
-        nodes = self._rendezvous(restart_round)
         nproc = cfg.nproc_per_node
+        if nproc > 1:
+            # one process per chip: several workers on one TPU host
+            # would each try to take every chip. (nproc == 1 never asks:
+            # this parent must stay off the backend its child needs.)
+            from .. import refuse_multiprocess_on_tpu
+
+            refuse_multiprocess_on_tpu(
+                "launch --nproc_per_node %d" % nproc)
+        nodes = self._rendezvous(restart_round)
         world = cfg.nnodes * nproc
         base_port = 6170
         endpoints = ",".join(

@@ -9,6 +9,8 @@ are mesh axis names instead of ranks+ring ids.
 """
 from __future__ import annotations
 
+import contextlib
+
 import numpy as np
 
 import jax
@@ -28,11 +30,38 @@ def set_mesh(mesh):
 
 
 def get_mesh():
+    """The current mesh. With none built, a one-axis 'dp' mesh over ALL
+    ``jax.devices()`` is created and kept: on a four-chip host that is
+    four-way data parallel, so an entry point that means one chip builds
+    its mesh from ``jax.devices()[:1]`` and says so."""
     global _global_mesh
     if _global_mesh is None:
         devs = np.array(jax.devices())
         _global_mesh = Mesh(devs, ("dp",))
     return _global_mesh
+
+
+def current_mesh():
+    """The mesh somebody built or scoped, or None — never creates the
+    all-devices default. For code that must not turn "no mesh" into
+    "every chip of the host" (the flash kernel's shard_map)."""
+    return _global_mesh
+
+
+@contextlib.contextmanager
+def scoped_mesh(mesh):
+    """Make ``mesh`` the current mesh for the duration of a trace. The
+    step builders (parallel.engine.CompiledTrainStep, serving.Engine)
+    trace under their OWN mesh, so code that must know how the step is
+    partitioned — the flash kernel's shard_map, activation sharding
+    marks — sees that mesh and not whatever the process built last."""
+    global _global_mesh
+    prev = _global_mesh
+    _global_mesh = mesh
+    try:
+        yield mesh
+    finally:
+        _global_mesh = prev
 
 
 def build_hybrid_mesh(dp=1, mp=1, pp=1, sharding=1, sep=1, devices=None):

@@ -83,8 +83,6 @@ class TCPStore:
         # recorded result instead of re-applying the delta.
         self._nonce_cid = int.from_bytes(os.urandom(8), "little")
         self._nonce_seq = 0
-        self._add_nonced = getattr(self._lib, "pt_store_add_nonced",
-                                   None)
         if is_master:
             self._server = self._lib.pt_store_server_start(port)
             if self._server < 0:
@@ -292,26 +290,15 @@ class TCPStore:
         # applies the delta at most once no matter how many replies
         # are lost. Allocation takes the op lock — threads sharing
         # this store (elastic heartbeats) must never mint one seq
-        # twice. A legacy .so on THIS host (no nonced symbol) degrades
-        # to the non-idempotent wire form; note both endpoints build
-        # from the same csrc tree — a NEW client against a
-        # still-running LEGACY server is not a supported mix (the old
-        # server drops unknown ops).
-        if self._add_nonced is not None:
-            with self._mu:
-                self._nonce_seq += 1
-                seq = self._nonce_seq
-            rc = self._int_op(
-                "add", key,
-                lambda: self._add_nonced(self._fd, key.encode(),
-                                         int(delta), self._nonce_cid,
-                                         seq, ctypes.byref(out)))
-        else:
-            rc = self._int_op(
-                "add", key,
-                lambda: self._lib.pt_store_add(self._fd, key.encode(),
-                                               int(delta),
-                                               ctypes.byref(out)))
+        # twice.
+        with self._mu:
+            self._nonce_seq += 1
+            seq = self._nonce_seq
+        rc = self._int_op(
+            "add", key,
+            lambda: self._lib.pt_store_add_nonced(
+                self._fd, key.encode(), int(delta), self._nonce_cid,
+                seq, ctypes.byref(out)))
         if rc is None:
             # injected drop: add has no silent no-op form (callers need
             # the counter value) — surface it as the op failure it is

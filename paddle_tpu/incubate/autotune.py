@@ -4,8 +4,8 @@ Parity: reference python/paddle/incubate/autotune.py set_config(config)
 with "kernel" (exhaustive cudnn algo search), "layout" (NCHW<->NHWC
 autotune), "dataloader" (num_workers tuning) sections. TPU-native mapping:
 - kernel  -> XLA's autotuner already picks MXU tilings per-compile; the
-  knob toggles jax persistent compilation caching so tuned programs are
-  reused across processes.
+  knob turns on jax persistent compilation caching (placed by
+  core/compile_cache.py) so tuned programs are reused across processes.
 - layout  -> conv layouts: XLA on TPU canonicalizes internally; we record
   the preference for the conv lowering.
 - dataloader -> tunes DataLoader prefetch depth.
@@ -46,12 +46,7 @@ def get_config():
 
 def _apply():
     if _config["kernel"]["enable"]:
-        import jax
+        # persistent compilation cache = cross-process kernel reuse
+        from ..core import compile_cache
 
-        try:  # persistent compilation cache = cross-process kernel reuse
-            jax.config.update("jax_compilation_cache_dir",
-                              "/tmp/paddle_tpu_xla_cache")
-        # ptlint: silent-except-ok — older jax without the
-        # compilation-cache config key; tuning stays best-effort
-        except Exception:
-            pass
+        compile_cache.configure()

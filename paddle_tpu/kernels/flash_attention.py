@@ -27,11 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4/0.5; accept
-# either so the kernels run on both
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 # measured on v5e (8x1024x6x128 causal): 512/512 is ~31% faster than
 # 128/128 — bigger tiles amortize the softmax-rescale epilogue between
 # MXU dots. min()-clamped to the sequence length at call time.
@@ -39,6 +34,22 @@ DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
 _STAT_LANES = 128  # lane width for the m/l scratch (TPU min tile)
+
+
+def resolve_interpret(interpret):
+    """The one place a Pallas kernel decides between Mosaic and the
+    interpreter: ``None`` means Mosaic on a TPU backend and the
+    interpreter elsewhere (CPU tests). An explicit ``True`` on a TPU
+    backend is refused — an interpreted kernel there would pass every
+    check while hiding that the real kernel never ran."""
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError(
+            "interpret=True on a TPU backend: the Pallas interpreter "
+            "would stand in for the Mosaic kernel")
+    return bool(interpret)
 
 
 def _dot(a, b, dims, batch=((), ())):
@@ -67,8 +78,8 @@ def _dot(a, b, dims, batch=((), ())):
 def _fa_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_k,
                segmented):
     """One (bh, q_block, kv_block) program. Refs: q [1, bq, d];
-    k/v [1, block_k, d]; optional segment-id refs sq [1, bq], sk
-    [1, block_k] (ragged/packed sequences: tokens attend only within
+    k/v [1, block_k, d]; optional segment-id refs sq [1, 1, bq], sk
+    [1, 1, block_k] (ragged/packed sequences: tokens attend only within
     their segment — the serving varlen path); o [1, bq, d]; lse [1, bq]
     (softmax log-sum-exp, saved for the Pallas backward); scratch m/l
     [bq, 128], acc [bq, d]."""
@@ -103,7 +114,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_k,
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
         if segmented:
             s = jnp.where(
-                sq_ref[0][:, None] == sk_ref[0][None, :], s, NEG_INF)
+                sq_ref[0, 0][:, None] == sk_ref[0, 0][None, :], s, NEG_INF)
         m_prev = m_scr[...][:, :1]                      # [bq, 1]
         l_prev = l_scr[...][:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -152,12 +163,13 @@ def _flash_fwd_bhnd(q, k, v, scale, causal, block_q, block_k, interpret,
     args = [q, k, v]
     if segmented:
         in_specs += [
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k), lambda b, i, j: (b, j),
+            pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, j),
                          memory_space=pltpu.VMEM),
         ]
-        args += [segs, segs]
+        # [BH, 1, N]: same singleton-axis plane as lse (tiling rule)
+        args += [segs[:, None, :]] * 2
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -180,9 +192,10 @@ def _flash_fwd_bhnd(q, k, v, scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(*args)
 
 
@@ -220,7 +233,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *rest,
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
         if segmented:
             s = jnp.where(
-                sq_ref[0][:, None] == sk_ref[0][None, :], s, NEG_INF)
+                sq_ref[0, 0][:, None] == sk_ref[0, 0][None, :], s, NEG_INF)
         p = jnp.exp(s - lse)
         dp = _dot(do, v, ((1,), (1,)))          # [bq, bk]
         ds = (p * (dp - delta)).astype(k.dtype)
@@ -273,7 +286,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *rest,
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
         if segmented:
             s = jnp.where(
-                sq_ref[0][:, None] == sk_ref[0][None, :], s, NEG_INF)
+                sq_ref[0, 0][:, None] == sk_ref[0, 0][None, :], s, NEG_INF)
         p = jnp.exp(s - lse)                             # [bq, bk]
         dv_scr[...] += _dot(p.astype(do.dtype), do, ((0,), (0,)))          # [bk, d]
         dp = _dot(do, v, ((1,), (1,)))          # [bq, bk]
@@ -321,12 +334,13 @@ def _flash_bwd_bhnd(q, k, v, out, lse, g, scale, causal, block_q, block_k,
     dq_args = [q, k, v, g, lse, delta]
     if segmented:
         dq_in_specs += [
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k), lambda b, i, j: (b, j),
+            pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, j),
                          memory_space=pltpu.VMEM),
         ]
-        dq_args += [segs, segs]
+        # [BH, 1, N]: same singleton-axis plane as lse (tiling rule)
+        dq_args += [segs[:, None, :]] * 2
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           block_k=block_k, segmented=segmented),
@@ -336,9 +350,10 @@ def _flash_bwd_bhnd(q, k, v, out, lse, g, scale, causal, block_q, block_k,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((bh, n, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_dq",
     )(*dq_args)
     dkv_in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0),
@@ -357,12 +372,13 @@ def _flash_bwd_bhnd(q, k, v, out, lse, g, scale, causal, block_q, block_k,
     dkv_args = [q, k, v, g, lse, delta]
     if segmented:
         dkv_in_specs += [
-            pl.BlockSpec((1, block_q), lambda b, j, i: (b, i),
+            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k), lambda b, j, i: (b, j),
+            pl.BlockSpec((1, 1, block_k), lambda b, j, i: (b, 0, j),
                          memory_space=pltpu.VMEM),
         ]
-        dkv_args += [segs, segs]
+        # [BH, 1, N]: same singleton-axis plane as lse (tiling rule)
+        dkv_args += [segs[:, None, :]] * 2
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, segmented=segmented),
@@ -382,9 +398,10 @@ def _flash_bwd_bhnd(q, k, v, out, lse, g, scale, causal, block_q, block_k,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_dkv",
     )(*dkv_args)
     return dq, dk, dv
 
@@ -451,8 +468,7 @@ def flash_attention(q, k, v, causal=False, scale=None,
     kv_n = k.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     block_q = min(block_q, n)
     block_k = min(block_k, kv_n)
     # Kernel path requires Mosaic-tileable blocks: q blocks on the sublane

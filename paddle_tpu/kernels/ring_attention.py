@@ -107,5 +107,5 @@ def sequence_parallel_attention(q, k, v, mesh=None, causal=False, scale=None,
         lambda q_, k_, v_: ring_attention(q_, k_, v_, axis_name=axis_name,
                                           causal=causal, scale=scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
     return fn(q, k, v)

@@ -83,9 +83,8 @@ whether or not the watchdog thread is running (the verdict just reads
 "watchdog: disabled" when it is not).
 
 Snapshot artifacts (``write_snapshot``) carry metadata —
-``written_at``/``pid``/caller-supplied context — so bench staleness is
-detectable from the artifact itself (VERDICT r5: BENCH_r05 went stale
-silently).
+``written_at``/``pid``/caller-supplied context — so an old artifact is
+detectable from the artifact itself.
 """
 from __future__ import annotations
 

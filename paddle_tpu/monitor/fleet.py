@@ -1464,11 +1464,11 @@ def router_trace_federation(trace_id):
     return dict(segments(trace_id), enabled=True)
 
 
-# -- fleet snapshot artifact (bench.py staleness discipline) ------------------
+# -- fleet snapshot artifact ---------------------------------------------------
 
 def snapshot_dict(collector=None):
-    """JSON-ready fleet snapshot: the per-rank table + aggregates the
-    tunnel-battery fleet row commits as ``tools/fleet_snapshot.json``."""
+    """JSON-ready fleet snapshot: the per-rank table + aggregates that
+    tools/fleet_battery.py writes as ``tools/fleet_snapshot.json``."""
     c = collector or _collector
     if c is None:
         return {"kind": "fleet_snapshot", "version": 1, "ok": False,
@@ -1495,30 +1495,15 @@ def snapshot_dict(collector=None):
     }
 
 
-def write_snapshot_artifact(path, collector=None, stale_reason=None):
-    """Write the fleet snapshot artifact, with bench.py's staleness
-    discipline: when this round produced NOTHING scrapeable (or the
-    caller says so via ``stale_reason``) and a previous artifact
-    exists, RE-EMIT it marked ``stale: true`` with
-    ``stale_generations``/``stale_since`` — a photocopied fleet table
-    must confess from the artifact itself. Returns the dict written."""
+def write_snapshot_artifact(path, collector=None):
+    """Write the fleet snapshot artifact and return the dict. A round
+    that produced NOTHING scrapeable (``ok`` false) is returned but not
+    written: a missing measurement leaves no file behind, and the
+    caller's exit code says so."""
     snap = snapshot_dict(collector)
-    if stale_reason is None and not snap.get("ok"):
-        stale_reason = snap.get("error") or "no rank answered the scrape"
-    if stale_reason is not None and os.path.exists(path):
-        try:
-            with open(path) as f:
-                last = json.load(f)
-        except (OSError, ValueError):
-            last = None
-        if last and last.get("kind") == "fleet_snapshot":
-            last["stale"] = True
-            last["stale_reason"] = stale_reason
-            last["stale_generations"] = \
-                int(last.get("stale_generations", 0)) + 1
-            last.setdefault("stale_since",
-                            last.get("written_at"))
-            snap = last
+    if not snap.get("ok"):
+        snap.setdefault("error", "no rank answered the scrape")
+        return snap
     d = os.path.dirname(os.path.abspath(path))
     if d:
         os.makedirs(d, exist_ok=True)

@@ -114,35 +114,58 @@ def sentinels_enabled():
     return _state.listener_installed
 
 
-_machine_cache = None
+_PEAK_ENV = (("peak_flops", "PT_PERF_PEAK_FLOPS"),
+             ("hbm_bw", "PT_PERF_HBM_BW"),
+             ("ici_bw", "PT_PERF_ICI_BW"))
+
+
+class UnknownDeviceKindError(RuntimeError):
+    """The local device has no row in DEVICE_PEAKS and no PT_PERF_*
+    override: there is no honest denominator for an MFU here."""
+
+
+def _local_peaks():
+    """(device_kind, peaks) for the device this process runs on: the
+    DEVICE_PEAKS row for ``jax.devices()[0].device_kind`` (empty for an
+    unknown kind) with PT_PERF_{PEAK_FLOPS,HBM_BW,ICI_BW} laid over it.
+    A malformed override raises — a typo must not silently select the
+    table value."""
+    import jax
+
+    from ..distributed.auto_parallel.cost_model import DEVICE_PEAKS
+
+    kind = jax.devices()[0].device_kind
+    spec = dict(DEVICE_PEAKS.get(kind, ()))
+    for key, env in _PEAK_ENV:
+        raw = os.environ.get(env)
+        if raw:
+            spec[key] = float(raw)
+    return kind, spec
+
+
+def device_fields():
+    """Where a number came from, as JAX reports it — the fields every
+    benchmark row carries (bench.py, tools/serving_benchmark.py)."""
+    import jax
+
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "device_kind": dev.device_kind,
+            "device_count": len(jax.devices())}
 
 
 def machine_spec():
-    """Per-chip peak numbers: the auto-parallel cost model's
-    MachineSpec (~v5e) with PT_PERF_{PEAK_FLOPS,HBM_BW,ICI_BW} env
-    overrides — the denominator of every MFU in this module."""
-    global _machine_cache
-    if _machine_cache is None:
-        try:
-            from ..distributed.auto_parallel.cost_model import MachineSpec
-
-            m = MachineSpec()
-            spec = {"peak_flops": m.peak_flops, "hbm_bw": m.hbm_bw,
-                    "ici_bw": m.ici_bw}
-        except Exception:
-            spec = {"peak_flops": 197e12, "hbm_bw": 819e9,
-                    "ici_bw": 45e9}
-        for key, env in (("peak_flops", "PT_PERF_PEAK_FLOPS"),
-                         ("hbm_bw", "PT_PERF_HBM_BW"),
-                         ("ici_bw", "PT_PERF_ICI_BW")):
-            raw = os.environ.get(env)
-            if raw:
-                try:
-                    spec[key] = float(raw)
-                except ValueError:
-                    pass
-        _machine_cache = spec
-    return dict(_machine_cache)
+    """Per-chip peak numbers of the LOCAL device — the denominator of
+    every MFU in this module. Raises UnknownDeviceKindError when the
+    device kind is not in the table and the PT_PERF_* overrides do not
+    cover it (a CPU test that needs a denominator passes one)."""
+    kind, spec = _local_peaks()
+    missing = [env for key, env in _PEAK_ENV if key not in spec]
+    if missing:
+        raise UnknownDeviceKindError(
+            "no peak numbers for device kind %r: add a sourced row to "
+            "DEVICE_PEAKS (distributed/auto_parallel/cost_model.py) or "
+            "set %s" % (kind, ", ".join(missing)))
+    return spec
 
 
 # -- executable analysis -----------------------------------------------------
@@ -214,12 +237,11 @@ def bench_fields(analysis, tokens_per_s=None, tokens_per_step=None,
         out["hbm_peak_bytes"] = analysis["hbm_peak_bytes"]
         if analysis.get("hbm_peak_is_estimate"):
             out["hbm_peak_is_estimate"] = True
-    peak = peak_flops or machine_spec()["peak_flops"]
     if flops and tokens_per_s and tokens_per_step:
+        peak = peak_flops or machine_spec()["peak_flops"]
         steps_per_s = tokens_per_s / float(tokens_per_step)
         out["model_flops_per_s"] = round(flops * steps_per_s)
-        # 3 significant digits, never rounded to a flat 0: a CPU smoke
-        # MFU of 3.6e-6 must stay a real number in the artifact
+        # 3 significant digits, never rounded to a flat 0
         out["mfu"] = float("%.3g" % (flops * steps_per_s / peak))
         out["mfu_peak_flops"] = peak
     return out
@@ -747,13 +769,17 @@ def perf_payload():
     + the machine model the MFUs were computed against."""
     with _state.lock:
         jobs = {j: dict(r) for j, r in _state.jobs.items()}
+    kind, peaks = _local_peaks()
     return {
         "enabled": {
             "attribution": attribution_enabled(),
             "timeseries": _timeseries.is_enabled(),
             "sentinels": sentinels_enabled(),
         },
-        "machine": machine_spec(),
+        "device_kind": kind,
+        # whatever is known about this kind; an unknown kind shows {}
+        # and every job above carries no mfu (machine_spec raised)
+        "machine": peaks,
         "jobs": jobs,
         "anomalies": anomaly_summary(),
         "time": time.time(),

@@ -42,6 +42,50 @@ def _sdpa_reference(q, k, v, mask=None, dropout_p=0.0, causal=False, scale=None)
     return out
 
 
+def _flash_on_mesh(q, k, v, causal, scale):
+    """The flash kernel, per shard when the traced step spans devices.
+
+    GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+    shard_map"), so on a mesh of more than one device the kernel runs
+    inside a shard_map over what attention is independent in: batch over
+    the data-parallel axes ('dp', 'sharding') and heads over 'mp' — no
+    collective is needed. The mesh is the one the step builder scoped
+    for this trace (distributed/mesh.py scoped_mesh). Axes that an
+    enclosing shard_map already made manual (the quantized grad-sync
+    body) are left out; a dim the axes do not divide stays whole."""
+    from ...kernels.flash_attention import flash_attention as _fa
+
+    from ...distributed import mesh as _mesh
+
+    mesh = _mesh.current_mesh()
+    if mesh is None or mesh.size == 1 \
+            or not isinstance(q, jax.core.Tracer):
+        # no mesh built (plain single-device jit), one device, or an
+        # eager call on concrete arrays
+        return _fa(q, k, v, causal=causal, scale=scale)
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
+
+    def axes_for(names, dim):
+        axes = tuple(a for a in names if a in mesh.axis_names
+                     and a not in manual and mesh.shape[a] > 1)
+        size = 1
+        for a in axes:
+            size *= mesh.shape[a]
+        return axes if axes and dim % size == 0 else None
+
+    batch = axes_for(("dp", "sharding"), q.shape[0])
+    heads = axes_for(("mp",), q.shape[2])
+    if batch is None and heads is None:
+        return _fa(q, k, v, causal=causal, scale=scale)
+    from jax.sharding import PartitionSpec as P
+
+    spec = P(batch, None, heads, None)
+    return jax.shard_map(
+        lambda q_, k_, v_: _fa(q_, k_, v_, causal=causal, scale=scale),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)(q, k, v)
+
+
 @primitive
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False, scale=None,
@@ -78,27 +122,19 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         jax.default_backend() == "tpu"
         and attn_mask is None
         and dropout_p == 0.0
-        # validated head_dims only: 128-multiples (measured) and exactly
-        # 64 (kernel-exact, flag-gated pending on-chip Mosaic check) —
-        # NOT every 64-multiple (192/320 are untested lane layouts)
+        # validated head_dims only: 128-multiples (run on the chip) and
+        # exactly 64 (kernel-exact and Mosaic-compiled, flag-gated until
+        # a ledger row decides it) — NOT every 64-multiple (192/320 are
+        # untested lane layouts)
         and (q.shape[-1] % 128 == 0 or q.shape[-1] == 64)
         and q.shape[-1] >= min_d
         and q.shape[1] % 128 == 0
         and k.shape[1] % 128 == 0
     )
     if use_flash:
-        try:
-            from ...kernels.flash_attention import flash_attention as _fa
-
-            return _fa(q, k, v, causal=is_causal, scale=scale)
-        except Exception as e:
-            from ...monitor.registry import warn_once
-
-            warn_once(
-                "attention.flash_fallback",
-                "paddle_tpu.nn.functional: flash_attention path "
-                "unavailable, using reference SDPA (slower): "
-                "%r" % (e,))
+        # no catch: on a TPU a flash path that cannot be traced is an
+        # error, not a reason to run the reference SDPA unannounced
+        return _flash_on_mesh(q, k, v, is_causal, scale)
     return _sdpa_reference(q, k, v, mask=attn_mask, dropout_p=dropout_p,
                            causal=is_causal, scale=scale)
 

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import monitor as _monitor
+from ..distributed import mesh as _mesh
 from ..resilience import faultinject as _fi
 from .kv_cache import (
     PagedDecodeView,
@@ -213,6 +214,9 @@ class Engine:
         self._quarantine = set()
         self._names, values = model.functional_state()
         self._state_vals = list(values)
+        # everything lives where the weights live: one device
+        device = min(values[0].devices(), key=lambda d: d.id)
+        self._mesh = _mesh.Mesh(np.array([device]), ("dp",))
         # weight-only quantized decode (FLAGS_serving_quant_weights):
         # projection weights quantized ONCE here; _decode_vals is the
         # state the decode/mixed steps bind — each quantized leaf is an
@@ -979,6 +983,31 @@ class Engine:
 
     # -- graph analysis ---------------------------------------------------
 
+    def _hot_step(self):
+        """(name, jitted fn, raw fn, args) of THE hot step — the mixed
+        step under chunked prefill, else decode — at the engine's own
+        fixed shapes (that fixedness IS the compile-once contract)."""
+        S = self.max_slots
+        bt = jnp.asarray(self.cache.block_tables)
+        lens = jnp.asarray(self.cache.seq_lens)
+        if self.chunked_prefill:
+            toks = jnp.zeros((S, self.prefill_chunk), jnp.int32)
+            ql = jnp.zeros((S,), jnp.int32)
+            return ("mixed", self._mixed, self._mixed_fn,
+                    (self._decode_vals, self.cache.pools, toks, bt, lens,
+                     ql))
+        toks = jnp.zeros((S,), jnp.int32)
+        return ("decode", self._decode, self._decode_fn,
+                (self._decode_vals, self.cache.pools, toks, bt, lens))
+
+    def hot_step_hlo(self):
+        """Compiled-HLO text of the hot step (AOT lower + compile, never
+        executed) — what chip_smoke.py reads to assert that the Mosaic
+        paged kernel is in the step the chip runs. Lowering traces, and
+        a trace counts into ``decode_compiles``: read the stats first."""
+        _, jit_fn, _, args = self._hot_step()
+        return self._run_eval(jit_fn.lower, *args).compile().as_text()
+
     def graph_report(self):
         """AOT-lower (never execute) every compiled step this engine
         configuration would run — the ONE mixed step under chunked
@@ -996,10 +1025,7 @@ class Engine:
             param_census
         from ..monitor import perf as _perf
 
-        S = self.max_slots
         pools = self.cache.pools
-        bt = jnp.asarray(self.cache.block_tables)
-        lens = jnp.asarray(self.cache.seq_lens)
 
         def artifact(jit_fn, raw_fn, args):
             lowered = jit_fn.lower(*args)
@@ -1019,17 +1045,9 @@ class Engine:
             }
 
         steps = {}
-        if self.chunked_prefill:
-            toks = jnp.zeros((S, self.prefill_chunk), jnp.int32)
-            ql = jnp.zeros((S,), jnp.int32)
-            steps["mixed"] = artifact(
-                self._mixed, self._mixed_fn,
-                (self._decode_vals, pools, toks, bt, lens, ql))
-        else:
-            toks = jnp.zeros((S,), jnp.int32)
-            steps["decode"] = artifact(
-                self._decode, self._decode_fn,
-                (self._decode_vals, pools, toks, bt, lens))
+        name, jit_fn, raw_fn, args = self._hot_step()
+        steps[name] = artifact(jit_fn, raw_fn, args)
+        if not self.chunked_prefill:
             P = self._bucket(8)
             ids = jnp.zeros((1, P), jnp.int32)
             row = jnp.asarray(self.cache.block_tables[0])
@@ -1089,10 +1107,15 @@ class Engine:
         return out
 
     def _run_eval(self, fn, *args):
+        """Call (and on first use trace) a compiled step: model in eval
+        mode, and under the engine's own one-device mesh — the engine
+        is single-device by construction, whatever mesh the process
+        built for training (distributed/mesh.py scoped_mesh)."""
         was_training = self.model.training
         self.model.eval()
         try:
-            return fn(*args)
+            with _mesh.scoped_mesh(self._mesh):
+                return fn(*args)
         finally:
             if was_training:
                 self.model.train()

@@ -75,7 +75,7 @@ class TestEagerCollectives:
 
 class TestTracedCollectives:
     def test_psum_inside_shard_map(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         mesh = pmesh.build_hybrid_mesh(dp=8)
         g = dist.Group("dp", mesh)

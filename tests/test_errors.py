@@ -14,7 +14,7 @@ def _t(a):
     return paddle.to_tensor(np.asarray(a))
 
 
-class TestTaxonomy:
+class TestErrorHierarchy:
     def test_typed_errors_subclass_builtins(self):
         # the reference's pybind mapping: typed error AND builtin
         assert issubclass(errors.InvalidArgumentError, ValueError)

@@ -255,8 +255,8 @@ class TestFlashPallasBackward:
 class TestFlashMinHeadDimFlag:
     """FLAGS_flash_min_head_dim gates sdpa routing into the kernel:
     default 128 keeps the measured path; 64 is kernel-exact (the d=64
-    parity tests above) and awaits on-chip Mosaic validation before the
-    default flips (tools/tunnel_battery.sh probes it)."""
+    parity tests above) and Mosaic-compiles (test_tpu_lowering.py); the
+    default flips when a ledger row says so (ROADMAP D2)."""
 
     def test_default_is_128(self):
         from paddle_tpu.core import flags as fl

@@ -16,14 +16,7 @@ from paddle_tpu.parallel.mp_layers import (
     parallel_softmax_cross_entropy,
 )
 
-try:
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, **kw):
-        kw["check_vma"] = kw.pop("check_rep", False)
-        return _shard_map(f, **kw)
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _dense_ce(x, li):
@@ -74,7 +67,7 @@ class TestParallelCrossEntropy:
 
         f = jax.jit(shard_map(body, mesh=mesh,
                               in_specs=(P(None, "mp"), P()),
-                              out_specs=P(), check_rep=False))
+                              out_specs=P(), check_vma=False))
         out = f(x, li)
         np.testing.assert_allclose(np.asarray(out), _dense_ce(x, li),
                                    rtol=1e-5)
@@ -95,7 +88,7 @@ class TestParallelCrossEntropy:
                     Tensor(xx), Tensor(ls))._value
 
             f = shard_map(body, mesh=mesh, in_specs=(P(None, "mp"), P()),
-                          out_specs=P(), check_rep=False)
+                          out_specs=P(), check_vma=False)
             return f(xs, li).sum()
 
         g = jax.jit(jax.grad(loss))(x)
@@ -155,7 +148,7 @@ class TestRngTracker:
                                  ("local_seed", False)]:
             f = jax.jit(shard_map(
                 lambda xs, n=name: body(xs, n), mesh=mesh,
-                in_specs=(P("mp"),), out_specs=P("mp"), check_rep=False))
+                in_specs=(P("mp"),), out_specs=P("mp"), check_vma=False))
             out = np.asarray(f(x))
             masks = [out[r] != 0 for r in range(4)]
             equal = all((m == masks[0]).all() for m in masks[1:])

@@ -12,7 +12,7 @@ On-discipline: admission + terminal capture (prompt ids, latched flag
 snapshot, weights generation, output token hash, shed/expired
 reasons), bounded finished-evicted-first eviction, versioned JSONL
 round-trip, and the replay half (tools/ptreplay.py, loaded by file
-path like test_bench_stale.py loads bench tools): a mixed workload —
+path): a mixed workload —
 prefix hits + chunked prefill + quant-kv + forced preempt/resume —
 re-executes with ZERO divergences and ``decode_compiles == 1``, a
 deliberately perturbed weight leaf is detected, and the flag matrix
@@ -53,7 +53,7 @@ _PTREPLAY = None
 
 
 def _ptreplay():
-    """tools/ptreplay.py by file path (the test_bench_stale idiom)."""
+    """tools/ptreplay.py by file path."""
     global _PTREPLAY
     if _PTREPLAY is None:
         path = os.path.join(os.path.dirname(os.path.dirname(

@@ -20,8 +20,8 @@ Pins the contracts the rest of the stack routes on:
 * the fleet merge (``fleet_incidents_payload``): dedup by id across
   local + scraped tables, local wins, peer wall stamps shifted by the
   per-rank clock offset, capture manifests back-link capture dirs;
-* tools/slo_report.py: --once artifact + the stale re-emit discipline
-  (rc=3, ``stale``/``stale_reason``/``stale_generations``).
+* tools/slo_report.py: --once artifact; a failed measurement is rc=3
+  and writes nothing.
 """
 from __future__ import annotations
 
@@ -461,39 +461,28 @@ class TestSloReportCLI:
         assert snap["source"] == "once"
         assert "slo" in snap and "incidents" in snap
 
-    def test_stale_reemit_discipline(self, tmp_path):
+    def test_failed_scrape_leaves_previous_artifact_alone(self,
+                                                           tmp_path):
         mod = _load_slo_report()
         out = str(tmp_path / "slo_snapshot.json")
         good = dict(mod._base("measure"), slo={"enabled": True},
                     incidents={"enabled": True})
         mod.write_artifact(out, good)
-        # a dead endpoint fails the scrape -> previous artifact
-        # re-emitted marked stale, rc=3
+        with open(out, "rb") as f:
+            before = f.read()
+        # a dead endpoint fails the scrape: rc=3, artifact untouched
         rc = mod.main(["--endpoint", "http://127.0.0.1:1",
                        "--out", out])
         signal.alarm(0)
         assert rc == 3
-        with open(out) as f:
-            snap = json.load(f)
-        assert snap["stale"] is True
-        assert snap["stale_generations"] == 1
-        assert snap["stale_reason"]
-        assert snap["stale_since"] == good["written_at"]
-        assert snap["slo"] == {"enabled": True}     # the old verdicts
-        # a second failure bumps the generation counter
-        assert mod.main(["--endpoint", "http://127.0.0.1:1",
-                         "--out", out]) == 3
-        signal.alarm(0)
-        with open(out) as f:
-            assert json.load(f)["stale_generations"] == 2
+        with open(out, "rb") as f:
+            assert f.read() == before
 
-    def test_no_previous_artifact_writes_not_ok_stub(self, tmp_path):
+    def test_failed_scrape_writes_no_file(self, tmp_path):
         mod = _load_slo_report()
         out = str(tmp_path / "slo_snapshot.json")
         rc = mod.main(["--endpoint", "http://127.0.0.1:1",
                        "--out", out])
         signal.alarm(0)
         assert rc == 3
-        with open(out) as f:
-            snap = json.load(f)
-        assert snap["ok"] is False and snap["kind"] == "slo_snapshot"
+        assert not os.path.exists(out)

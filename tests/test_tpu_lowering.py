@@ -1,0 +1,285 @@
+"""Every Pallas kernel, lowered and compiled for the TPU from the CPU.
+
+Two layers, both at the chip_smoke.py geometry (llama1b widths):
+
+- ``TestCrossLowering``: ``jit(f).trace(...).lower(lowering_platforms=
+  ("tpu",))`` with ``interpret=False``. This is Pallas's own TPU
+  lowering — block-shape legality, supported primitives — and needs no
+  TPU software at all. It is what refused the fused lm_head+CE kernel's
+  ``(1, block_t)`` planes.
+- ``TestMosaicCompile``: the same functions AOT-compiled against a
+  ``v5e:1x1`` topology description. libtpu ships the real compiler, so
+  this is Mosaic proper — layout inference, the scoped-VMEM limit — with
+  no chip attached. Skipped where libtpu cannot describe the topology.
+
+Neither layer executes anything: numerics on the chip are
+chip_smoke.py's job.
+"""
+from __future__ import annotations
+
+import importlib
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.analysis.graph.hlo import mosaic_kernels
+from paddle_tpu.kernels import flash_attention as fa
+from paddle_tpu.kernels import fused_ce as fc
+
+# the package re-exports a function under the module's name
+pa = importlib.import_module("paddle_tpu.serving.kernels.paged_attention")
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+# smoke geometry: 8 x 1024 tokens, 16 heads x 128, vocab 32000;
+# serving: 8 slots, 512 pages of 16, 128 pages per slot
+B, N, H, D = 8, 1024, 16, 128
+T, HID, V = B * N, 2048, 32000
+S, NB, BS, MB, C = 8, 512, 16, 128, 16
+
+
+def _flash(dtype, d, segmented=False):
+    shape = ((B, N, H, d), dtype)
+    args = [shape, shape, shape] + ([((B, N), I32)] if segmented else [])
+
+    def fwd(q, k, v, seg=None):
+        return fa.flash_attention(q, k, v, causal=True, interpret=False,
+                                  segment_ids=seg)
+
+    def bwd(q, k, v, seg=None):
+        return jax.grad(
+            lambda q, k, v: fwd(q, k, v, seg).astype(F32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    return fwd, bwd, args
+
+
+def _cases():
+    """(name, fn, args, kernel names). A backward case lowers the
+    forward kernel too, so only the main geometry has a forward-only
+    case per kernel family."""
+    cases = []
+    for name, dtype, d, seg in (("flash_bf16_d128", BF16, 128, False),
+                                ("flash_f32_d128", F32, 128, False),
+                                ("flash_bf16_d64", BF16, 64, False),
+                                ("flash_bf16_d128_segmented", BF16, 128,
+                                 True)):
+        fwd, bwd, args = _flash(dtype, d, seg)
+        if name == "flash_bf16_d128":
+            cases.append((name + "_fwd", fwd, args, {"flash_fwd"}))
+        cases.append((name + "_bwd", bwd, args,
+                      {"flash_fwd", "flash_dq", "flash_dkv"}))
+    for name, dtype, h, hkv in (("paged_decode_bf16_mha", BF16, 16, 16),
+                                ("paged_decode_f32_mha", F32, 16, 16),
+                                ("paged_decode_bf16_gqa", BF16, 32, 8)):
+        pool = ((NB, BS, hkv, D), dtype)
+        cases.append((
+            name,
+            lambda q, k, v, bt, ln: pa.paged_attention_kernel(
+                q, k, v, bt, ln, interpret=False),
+            [((S, h, D), dtype), pool, pool, ((S, MB), I32), ((S,), I32)],
+            {"paged_decode"}))
+    for name, h, hkv in (("paged_mixed_bf16_mha", 16, 16),
+                         ("paged_mixed_bf16_gqa", 32, 8)):
+        pool = ((NB, BS, hkv, D), BF16)
+        cases.append((
+            name,
+            lambda q, k, v, bt, hl, ql: pa.mixed_paged_attention_kernel(
+                q, k, v, bt, hl, ql, interpret=False),
+            [((S, C, h, D), BF16), pool, pool, ((S, MB), I32),
+             ((S,), I32), ((S,), I32)],
+            {"paged_mixed"}))
+    for name, dtype in (("fused_ce_bf16", BF16), ("fused_ce_f32", F32)):
+        args = [((T, HID), dtype), ((HID, V), dtype), ((T,), I32)]
+
+        def ce(h, w, lbl):
+            return fc.fused_lm_head_ce(h, w, lbl, interpret=False)
+
+        if dtype == BF16:
+            cases.append((name + "_fwd", ce, args, {"fused_ce_fwd"}))
+        cases.append((
+            name + "_bwd",
+            lambda h, w, lbl: jax.grad(
+                lambda h, w: ce(h, w, lbl).sum(), argnums=(0, 1))(h, w),
+            args, {"fused_ce_fwd", "fused_ce_dh", "fused_ce_dw"}))
+    return cases
+
+
+CASES = _cases()
+IDS = [c[0] for c in CASES]
+
+
+class TestCrossLowering:
+    @pytest.mark.parametrize("name,fn,args,kernels", CASES, ids=IDS)
+    def test_lowers_to_a_tpu_custom_call(self, name, fn, args, kernels):
+        avals = [jax.ShapeDtypeStruct(s, d) for s, d in args]
+        text = jax.jit(fn).trace(*avals).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert text.count("tpu_custom_call") >= len(kernels), name
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One abstract v5e device to compile for, or skip."""
+    # libtpu asks the (absent) metadata server for these; naming them
+    # only silences the warnings
+    os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+    try:
+        from jax.experimental import topologies
+
+        topo = topologies.get_topology_desc(
+            topology_name="v5e:1x1", platform="tpu",
+            chips_per_host_bounds=(1, 1, 1))
+    except Exception as e:     # no libtpu, or it cannot run here
+        pytest.skip("no TPU topology description available: %r" % (e,))
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+class TestMosaicCompile:
+    def test_topology_is_the_chip_we_run_on(self, v5e):
+        from paddle_tpu.distributed.auto_parallel.cost_model import \
+            DEVICE_PEAKS
+
+        (dev,) = v5e.device_set
+        assert dev.platform == "tpu"
+        # the string chip_smoke.py's device phase printed on the chip
+        assert dev.device_kind == "TPU v5 lite"
+        assert dev.device_kind in DEVICE_PEAKS
+
+    @pytest.mark.parametrize("name,fn,args,kernels", CASES, ids=IDS)
+    def test_compiles_under_mosaic(self, v5e, name, fn, args, kernels):
+        avals = [jax.ShapeDtypeStruct(s, d, sharding=v5e)
+                 for s, d in args]
+        compiled = jax.jit(fn).lower(*avals).compile()
+        assert kernels <= set(mosaic_kernels(compiled.as_text())), name
+
+
+class TestInterpretNeverOnTPU:
+    def test_resolve_interpret(self, monkeypatch):
+        assert fa.resolve_interpret(None) is True       # CPU: interpreter
+        assert fa.resolve_interpret(False) is False     # cross-lowering
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert fa.resolve_interpret(None) is False
+        with pytest.raises(ValueError, match="interpret=True on a TPU"):
+            fa.resolve_interpret(True)
+
+    def test_reference_branch_on_tpu_is_said_once_by_name(
+            self, monkeypatch, capsys):
+        from paddle_tpu.monitor import registry
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        key = "serving.paged_attention.reference_on_tpu"
+        with registry._warned_lock:
+            registry._warned.discard(key)
+        q = jnp.zeros((2, 4, 16), F32)          # head_dim 16: not tileable
+        pool = jnp.zeros((4, 8, 4, 16), F32)
+        bt = jnp.zeros((2, 2), I32)
+        lens = jnp.ones((2,), I32)
+        for _ in range(2):
+            out = pa.paged_attention(q, pool, pool, bt, lens)
+        assert out.shape == (2, 4, 16)
+        err = capsys.readouterr().err
+        assert err.count("paged_attention takes the jnp gather "
+                         "reference on the TPU") == 1
+
+
+class TestMosaicKernelsParser:
+    def test_names_bare_wrapped_and_unnamed(self):
+        text = "\n".join([
+            '  %flash_fwd.1 = (bf16[8,1024,128]{2,1,0:T(8,128)(2,1)S(1)},'
+            ' f32[8,1,1024]{2,1,0:T(1,128)}) custom-call(%a, %b, %c),'
+            ' custom_call_target="tpu_custom_call", metadata={op_name='
+            '"jit(step)/checkpoint/flash_fwd/pallas_call"}',
+            '  %x.2 = bf16[8,1024,128]{2,1,0} custom-call(%a),'
+            ' custom_call_target="tpu_custom_call", metadata={op_name='
+            '"jit(step)/transpose(jvp(flash_dq))/pallas_call"}',
+            '  %x.3 = bf16[8,1024,128]{2,1,0} custom-call(%a),'
+            ' custom_call_target="tpu_custom_call", metadata={op_name='
+            '"jit(step)/jvp(flash_fwd)/pallas_call"}',
+            '  %anon.4 = f32[8]{0} custom-call(%a),'
+            ' custom_call_target="tpu_custom_call"',
+            '  %lapack.5 = f32[8]{0} custom-call(%a),'
+            ' custom_call_target="lapack_sgetrf"',
+        ])
+        assert mosaic_kernels(text) == {
+            "flash_fwd": 2, "flash_dq": 1, "anon.4": 1}
+
+
+class TestFlashOnAMesh:
+    """GSPMD refuses to partition a Mosaic kernel; on a multi-device
+    mesh the flash call goes through shard_map (batch over dp/sharding,
+    heads over mp). Found by AOT-compiling the dp=2 x mp=2 step."""
+
+    def _mesh(self):
+        from jax.sharding import Mesh
+
+        return Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                    ("dp", "mp"))
+
+    def test_unwrapped_kernel_cannot_be_partitioned(self):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        sh = NamedSharding(self._mesh(), P("dp", None, "mp", None))
+        aval = jax.ShapeDtypeStruct((4, 256, 4, 128), BF16, sharding=sh)
+        with pytest.raises(NotImplementedError,
+                           match="cannot be automatically partitioned"):
+            jax.jit(lambda q, k, v: fa.flash_attention(
+                q, k, v, causal=True, interpret=False)).trace(
+                    aval, aval, aval).lower(lowering_platforms=("tpu",))
+
+    def test_dispatch_shards_batch_and_heads(self, monkeypatch):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from paddle_tpu.distributed import mesh as pmesh
+        from paddle_tpu.nn.functional import attention as att
+
+        seen = []
+
+        def fake_flash(q, k, v, causal=False, scale=None):
+            seen.append(tuple(q.shape))
+            return att._sdpa_reference(q, k, v, causal=causal,
+                                       scale=scale)
+
+        monkeypatch.setattr(fa, "flash_attention", fake_flash)
+        mesh = self._mesh()
+        rng = np.random.RandomState(0)
+        q, k, v = (jnp.asarray(rng.randn(4, 16, 4, 8), F32)
+                   for _ in range(3))
+        want = att._sdpa_reference(q, k, v, causal=True)
+        sh = NamedSharding(mesh, P("dp", None, "mp", None))
+        with pmesh.scoped_mesh(mesh):
+            got = jax.jit(
+                lambda q, k, v: att._flash_on_mesh(q, k, v, True, None),
+                in_shardings=(sh, sh, sh))(q, k, v)
+        assert seen == [(2, 16, 2, 8)]      # batch / dp, heads / mp
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+
+    def test_no_mesh_or_one_device_calls_the_kernel_directly(
+            self, monkeypatch):
+        from jax.sharding import Mesh
+
+        from paddle_tpu.distributed import mesh as pmesh
+        from paddle_tpu.nn.functional import attention as att
+
+        seen = []
+
+        def fake_flash(q, k, v, causal=False, scale=None):
+            seen.append(tuple(q.shape))
+            return q
+
+        monkeypatch.setattr(fa, "flash_attention", fake_flash)
+        q = jnp.zeros((4, 16, 4, 8), F32)
+        one = Mesh(np.array(jax.devices()[:1]), ("dp",))
+        for mesh in (None, one):
+            with pmesh.scoped_mesh(mesh):
+                jax.jit(lambda q: att._flash_on_mesh(q, q, q, True,
+                                                     None))(q)
+        assert seen == [(4, 16, 4, 8)] * 2
+        # and "no mesh" did not conjure the all-devices default
+        with pmesh.scoped_mesh(None):
+            assert pmesh.current_mesh() is None

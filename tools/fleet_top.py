@@ -22,12 +22,9 @@ Modes:
                   print the table, exit
   --json          print the machine-readable snapshot instead of the
                   table (scripts; implies --once unless live)
-  --out PATH      write the fleet snapshot artifact. bench.py's
-                  staleness discipline applies: if NOTHING answered
-                  the scrape and PATH already holds a previous
-                  snapshot, it is re-emitted marked ``stale: true``
-                  (+ stale_generations/stale_since) instead of
-                  silently photocopying — and the exit code is 3.
+  --out PATH      write the fleet snapshot artifact. If NOTHING
+                  answered the scrape, nothing is written and the exit
+                  code is 3.
 
 Usage:
   python tools/fleet_top.py --endpoints http://h1:9000,http://h2:9000
@@ -207,17 +204,18 @@ def main(argv=None):
             if args.out:
                 snap = fleet.write_snapshot_artifact(args.out,
                                                      collector=c)
-                print("fleet_top: wrote %s (%d rank(s)%s)"
-                      % (args.out, len(snap.get("ranks") or ()),
-                         ", STALE re-emit" if snap.get("stale")
-                         else ""), file=sys.stderr)
+                print("fleet_top: %s %s (%d rank(s))"
+                      % ("wrote" if snap.get("ok")
+                         else "nothing scraped, did not write",
+                         args.out, len(snap.get("ranks") or ())),
+                      file=sys.stderr)
             if args.json:
                 json.dump(json_safe(snap), sys.stdout,
                           indent=1, default=str)
                 sys.stdout.write("\n")
             else:
                 print(render_table(c.ranks_table(), c.summary()))
-            return 3 if snap.get("stale") or not snap.get("ok") else 0
+            return 0 if snap.get("ok") else 3
         deadline = (time.monotonic() + args.duration
                     if args.duration > 0 else None)
         while True:

@@ -1,4 +1,4 @@
-"""Memory-plane snapshot artifact: the tunnel battery's mem row.
+"""Memory-plane snapshot artifact.
 
 Runs the bench-family decoder for a few compiled steps with the memory
 plane ON (``FLAGS_monitor_memory`` + ``FLAGS_perf_attribution`` so the
@@ -7,13 +7,8 @@ compiled transient peak feeds the headroom math) and commits the
 reconciliation, static-vs-transient split, headroom — as
 ``tools/mem_snapshot.json``.
 
-Staleness discipline (bench.py / fleet_snapshot): when the measuring
-child fails and a previous artifact exists, the previous artifact is
-RE-EMITTED marked ``stale: true`` (+ ``stale_reason`` /
-``stale_generations`` / ``stale_since``) and the exit code is 3 — a
-photocopied memory table must confess from the artifact itself, and
-the battery row goes red instead of silently committing a rotted
-number.
+On failure nothing is written and the exit code is 3: a measurement
+that did not happen leaves no artifact behind.
 
 Usage:
   python tools/mem_snapshot.py [--steps N] [--out tools/mem_snapshot.json]
@@ -117,33 +112,9 @@ def measure(steps=5):
     }
 
 
-def write_artifact(path, snap=None, stale_reason=None):
-    """Write the artifact with the stale re-emit discipline. When the
-    measurement failed (``snap is None`` / caller passes
-    ``stale_reason``) and a previous artifact exists, re-emit it
-    marked stale; otherwise write a not-ok stub. Returns the dict
-    written."""
-    if snap is None or stale_reason is not None:
-        reason = stale_reason or "measurement failed"
-        last = None
-        if os.path.exists(path):
-            try:
-                with open(path) as f:
-                    last = json.load(f)
-            except (OSError, ValueError):
-                last = None
-        if last and last.get("kind") == "mem_snapshot":
-            last["stale"] = True
-            last["stale_reason"] = reason
-            last["stale_generations"] = \
-                int(last.get("stale_generations", 0)) + 1
-            last.setdefault("stale_since", last.get("written_at"))
-            snap = last
-        else:
-            snap = {"kind": "mem_snapshot", "version": 1, "ok": False,
-                    "error": reason,
-                    "written_at": time.strftime("%Y-%m-%dT%H:%M:%SZ",
-                                                time.gmtime())}
+def write_artifact(path, snap):
+    """Atomic write of a real snapshot. A failed measurement writes
+    nothing (main returns 3): there is no previous artifact to re-emit."""
     d = os.path.dirname(os.path.abspath(path))
     if d:
         os.makedirs(d, exist_ok=True)
@@ -159,7 +130,7 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--steps", type=int, default=5)
     ap.add_argument("--out", default=DEFAULT_OUT,
-                    help="artifact path (stale re-emit on failure)")
+                    help="artifact path (nothing is written on failure)")
     ap.add_argument("--json", action="store_true",
                     help="print the snapshot JSON to stdout")
     a = ap.parse_args(argv)
@@ -169,9 +140,6 @@ def main(argv=None):
         snap = measure(a.steps)
     except Exception as e:
         sys.stderr.write("mem_snapshot: measurement failed: %r\n" % (e,))
-        snap = write_artifact(a.out, None, stale_reason=repr(e))
-        if a.json:
-            print(json.dumps(snap, default=str))
         return 3
     write_artifact(a.out, snap)
     if a.json:

@@ -20,9 +20,8 @@ Both `check` and `update` print a COVERAGE summary (how many of the
 measured cases the baseline actually guards) and list every UNGUARDED
 row — a case with no baseline entry passes the gate vacuously, which is
 how the committed TPU baseline quietly guarded only 8 of 44 cases.
-`--strict-coverage` turns any unguarded row into a nonzero exit (the
-tunnel battery's update row runs with it, so a partial refresh can
-never masquerade as a full one).
+`--strict-coverage` turns any unguarded row into a nonzero exit, so a
+partial refresh can never masquerade as a full one.
 """
 from __future__ import annotations
 

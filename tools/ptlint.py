@@ -4,8 +4,7 @@
     python tools/ptlint.py [paths...]            # lint (default paths
                                                  # from [tool.ptlint])
     python tools/ptlint.py --json                # JSON report on stdout
-    python tools/ptlint.py --out report.json     # JSON artifact (the
-                                                 # tunnel-battery row)
+    python tools/ptlint.py --out report.json     # JSON artifact
     python tools/ptlint.py --write-baseline      # re-grandfather the
                                                  # current flag/trace/
                                                  # thread findings

@@ -28,16 +28,16 @@ Modes:
     --against <journal2>   diff two recordings pairwise (the canary
                            story: record on weights generation N,
                            record on N+1, diff — no engine rebuilt)
-  smoke                    host-only CPU self-check for the battery
-                           row: record a mixed tiny workload (prefix
+  smoke                    host-only CPU self-check: record a mixed
+                           tiny workload (prefix
                            hits + chunked prefill + quant-kv +
                            forced preempt/resume), require a
                            zero-divergence identity replay with
                            decode_compiles == 1, prove detection power
                            on a deliberately perturbed weight leaf
                            (and that --matrix names ``weights``), and
-                           commit tools/replay_snapshot.json with the
-                           stale re-emit discipline (rc=3 on failure)
+                           commit tools/replay_snapshot.json (rc=3 and
+                           nothing written when the smoke cannot run)
 
 Divergences count into ``replay_divergences_total{axis}`` and open a
 ``replay_divergence`` incident (evidence: the report path) when the
@@ -395,9 +395,10 @@ def _smoke_record(tmpdir):
 
 
 def run_smoke(args):
-    """The tunnel_battery serving_replay row: record -> identity
-    replay -> perturbed detection -> matrix bisect, committed as one
-    artifact with the stale re-emit discipline (rc=3 on failure)."""
+    """Self-check: record -> identity replay -> perturbed detection ->
+    matrix bisect, committed as one artifact. A smoke that fails to run
+    writes nothing and returns 3; one that runs and finds a divergence
+    writes its report and returns 2."""
     import tempfile
 
     import jax
@@ -462,38 +463,13 @@ def run_smoke(args):
                 and matrix_perturbed["bisected_axes"] == ["weights"])
     except Exception as e:
         sys.stderr.write("replay smoke failed: %r\n" % (e,))
-        _reemit_stale(args.out, "smoke_failed: %r" % (e,))
         return 3
     _write_report(args.out, report)
     print(json.dumps(report), flush=True)
     print("wrote", args.out, flush=True)
     if not report["ok"]:
-        _reemit_stale(args.out, None)    # artifact already fresh;
-        return 2                         # the row goes red on content
+        return 2
     return 0
-
-
-def _reemit_stale(path, stale_reason):
-    """bench.py's staleness discipline: a failed smoke re-emits the
-    previous artifact marked stale instead of photocopying silently."""
-    if stale_reason is None or not os.path.exists(path):
-        return
-    try:
-        with open(path) as f:
-            last = json.load(f)
-    except (OSError, ValueError):
-        return
-    if last.get("kind") != "replay_snapshot":
-        return
-    last["stale"] = True
-    last["stale_reason"] = stale_reason
-    last["stale_generations"] = int(last.get("stale_generations", 0)) + 1
-    last.setdefault("stale_since", last.get("measured_at"))
-    tmp = path + ".tmp"
-    with open(tmp, "w") as f:
-        json.dump(last, f, indent=1, default=str)
-        f.write("\n")
-    os.replace(tmp, path)
 
 
 def main():

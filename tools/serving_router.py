@@ -13,7 +13,8 @@ its own MetricsServer —
     GET  /debugz/router/replicas per-replica table
 
 Replica mode (``--replica``): the worker process the benchmark forks
-(and a multi-host launcher runs one-per-host). Builds the preset model
+(and a multi-host launcher runs one-per-host; one process per chip —
+two replicas cannot share a TPU host yet). Builds the preset model
 + ``serving.Engine``, wraps it in ``fleet.Replica`` — which announces
 the endpoint in the store, heartbeats the liveness lease, and serves
 the enqueue/result/load protocol until SIGTERM (handled as a graceful
@@ -51,11 +52,22 @@ def _store_from(spec, timeout_s=10.0):
 
 
 def run_replica(args):
+    import jax
+
     import paddle_tpu as paddle
     from paddle_tpu import serving
+    from paddle_tpu.core import compile_cache
     from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
     from paddle_tpu.serving.fleet import Replica
 
+    # One process per chip: this replica takes every chip JAX shows it
+    # and serves from device 0. A second replica on the same TPU host
+    # cannot start until something pins each to its own chip (the
+    # benchmark's --fleet parent refuses there; ROADMAP D5/R2).
+    compile_cache.configure()
+    sys.stderr.write("replica %d: device 0 of %d %s device(s)\n"
+                     % (args.rank, len(jax.devices()),
+                        jax.devices()[0].platform))
     paddle.seed(args.seed)
     cfg = LlamaConfig(use_parallel=False, **PRESETS[args.preset])
     model = LlamaForCausalLM(cfg)

@@ -1,4 +1,4 @@
-"""SLO/incident snapshot artifact: the tunnel battery's slo row.
+"""SLO/incident snapshot artifact.
 
 Runs the bench-family decoder for a few compiled steps with the SLO
 plane ON (``FLAGS_monitor_slo`` — the timeseries ring, the objective
@@ -16,12 +16,8 @@ Alternative sources:
   --once           emit the current in-process payload without
                    driving any workload (smoke mode)
 
-Staleness discipline (bench.py / mem_snapshot): when the measurement
-fails and a previous artifact exists, the previous artifact is
-RE-EMITTED marked ``stale: true`` (+ ``stale_reason`` /
-``stale_generations`` / ``stale_since``) and the exit code is 3 — a
-photocopied verdict must confess from the artifact itself, and the
-battery row goes red instead of silently committing a rotted number.
+On failure nothing is written and the exit code is 3: a measurement
+that did not happen leaves no artifact behind.
 
 Usage:
   python tools/slo_report.py [--steps N] [--out tools/slo_snapshot.json]
@@ -148,33 +144,9 @@ def measure(steps=5):
     return snap
 
 
-def write_artifact(path, snap=None, stale_reason=None):
-    """Write the artifact with the stale re-emit discipline. When the
-    measurement failed (``snap is None`` / caller passes
-    ``stale_reason``) and a previous artifact exists, re-emit it
-    marked stale; otherwise write a not-ok stub. Returns the dict
-    written."""
-    if snap is None or stale_reason is not None:
-        reason = stale_reason or "measurement failed"
-        last = None
-        if os.path.exists(path):
-            try:
-                with open(path) as f:
-                    last = json.load(f)
-            except (OSError, ValueError):
-                last = None
-        if last and last.get("kind") == "slo_snapshot":
-            last["stale"] = True
-            last["stale_reason"] = reason
-            last["stale_generations"] = \
-                int(last.get("stale_generations", 0)) + 1
-            last.setdefault("stale_since", last.get("written_at"))
-            snap = last
-        else:
-            snap = {"kind": "slo_snapshot", "version": 1, "ok": False,
-                    "error": reason,
-                    "written_at": time.strftime("%Y-%m-%dT%H:%M:%SZ",
-                                                time.gmtime())}
+def write_artifact(path, snap):
+    """Atomic write of a real snapshot. A failed measurement writes
+    nothing (main returns 3): there is no previous artifact to re-emit."""
     d = os.path.dirname(os.path.abspath(path))
     if d:
         os.makedirs(d, exist_ok=True)
@@ -223,7 +195,7 @@ def main(argv=None):
                     help="scrape a live process's /debugz/slo + "
                     "/debugz/incidents instead of measuring")
     ap.add_argument("--out", default=DEFAULT_OUT,
-                    help="artifact path (stale re-emit on failure)")
+                    help="artifact path (nothing is written on failure)")
     ap.add_argument("--json", action="store_true",
                     help="print the snapshot JSON to stdout")
     a = ap.parse_args(argv)
@@ -238,9 +210,6 @@ def main(argv=None):
             snap = measure(a.steps)
     except Exception as e:
         sys.stderr.write("slo_report: measurement failed: %r\n" % (e,))
-        snap = write_artifact(a.out, None, stale_reason=repr(e))
-        if a.json:
-            print(json.dumps(snap, default=str))
         return 3
     write_artifact(a.out, snap)
     if a.json:
