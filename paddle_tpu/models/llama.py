@@ -306,13 +306,17 @@ class LlamaDecoderLayer(Layer):
         self.mlp = LlamaMLP(config)
 
     def forward(self, x, cache=None, position_offset=0):
-        h = self.input_layernorm(x)
-        if cache is not None:
-            attn, cache = self.self_attn(h, cache, position_offset)
-        else:
-            attn = self.self_attn(h)
-        x = x + attn
-        x = x + self.mlp(self.post_attention_layernorm(x))
+        # named scopes cost nothing but a compiled instruction's
+        # op_name: a device trace can then tell this block's time apart
+        with jax.named_scope("attn"):
+            h = self.input_layernorm(x)
+            if cache is not None:
+                attn, cache = self.self_attn(h, cache, position_offset)
+            else:
+                attn = self.self_attn(h)
+            x = x + attn
+        with jax.named_scope("mlp"):
+            x = x + self.mlp(self.post_attention_layernorm(x))
         if cache is not None:
             return x, cache
         return x
@@ -375,13 +379,14 @@ class LlamaModel(Layer):
         new_caches = []
         use_remat = self.config.recompute and caches is None
         for i, layer in enumerate(self.layers):
-            if caches is not None:
-                x, c = layer(x, caches[i], position_offset)
-                new_caches.append(c)
-            elif use_remat:
-                x = _remat_layer(layer, x)
-            else:
-                x = layer(x)
+            with jax.named_scope("layer_%d" % i):
+                if caches is not None:
+                    x, c = layer(x, caches[i], position_offset)
+                    new_caches.append(c)
+                elif use_remat:
+                    x = _remat_layer(layer, x)
+                else:
+                    x = layer(x)
         x = self.norm(x)
         if caches is not None:
             return x, new_caches
@@ -424,37 +429,39 @@ class LlamaForCausalLM(GenerationMixin, Layer):
 
     def forward(self, input_ids, labels=None):
         h = self.llama(input_ids)
-        if labels is not None:
-            fused = self._maybe_fused_ce(h, labels)
-            if fused is not None:
-                return fused
-        logits = self.lm_head(h)
-        if labels is not None:
-            if self.config.use_parallel:
-                # vocab stays mp-sharded through the loss (sharded-vocab
-                # c_softmax_with_cross_entropy, mp_layers.py) — no
-                # full-vocab gather under the partitioner
-                from ..parallel.mp_layers import (
-                    parallel_softmax_cross_entropy,
-                )
+        with jax.named_scope("lm_head"):
+            if labels is not None:
+                fused = self._maybe_fused_ce(h, labels)
+                if fused is not None:
+                    return fused
+            logits = self.lm_head(h)
+            if labels is not None:
+                if self.config.use_parallel:
+                    # vocab stays mp-sharded through the loss (sharded-vocab
+                    # c_softmax_with_cross_entropy, mp_layers.py) — no
+                    # full-vocab gather under the partitioner
+                    from ..parallel.mp_layers import (
+                        parallel_softmax_cross_entropy,
+                    )
 
-                flat = labels.reshape([-1])
-                per_tok = parallel_softmax_cross_entropy(
-                    logits.reshape([-1, self.config.vocab_size]), flat)
-                # mean over VALID tokens (same contract as the
-                # F.cross_entropy branch: ignore_index rows excluded)
-                valid = (flat != -100).astype(per_tok.dtype)
-                return per_tok.sum() / valid.sum().clip(min=1.0)
-            loss = F.cross_entropy(
-                logits.reshape([-1, self.config.vocab_size]),
-                labels.reshape([-1]))
-            return loss
-        return logits
+                    flat = labels.reshape([-1])
+                    per_tok = parallel_softmax_cross_entropy(
+                        logits.reshape([-1, self.config.vocab_size]), flat)
+                    # mean over VALID tokens (same contract as the
+                    # F.cross_entropy branch: ignore_index rows excluded)
+                    valid = (flat != -100).astype(per_tok.dtype)
+                    return per_tok.sum() / valid.sum().clip(min=1.0)
+                loss = F.cross_entropy(
+                    logits.reshape([-1, self.config.vocab_size]),
+                    labels.reshape([-1]))
+                return loss
+            return logits
 
     def generate_step(self, input_ids, caches, position_offset):
         """Single decode step with functional cache."""
         h, caches = self.llama(input_ids, caches, position_offset)
-        logits = self.lm_head(h)
+        with jax.named_scope("lm_head"):
+            logits = self.lm_head(h)
         return logits, caches
 
     def max_decode_len(self):
@@ -489,7 +496,8 @@ class LlamaForCausalLM(GenerationMixin, Layer):
         return self.llama.embed_tokens(input_ids)
 
     def forward_head(self, h):
-        return self.lm_head(self.llama.norm(h))
+        with jax.named_scope("lm_head"):
+            return self.lm_head(self.llama.norm(h))
 
     def forward_head_loss(self, h, labels):
         """Fused pipeline loss tail (mean CE over non-ignored tokens —
@@ -498,4 +506,5 @@ class LlamaForCausalLM(GenerationMixin, Layer):
         does not apply. Consulted only under PipelinedTrainStep's
         EXPLICIT fused_loss_tail=True opt-in: it replaces the step's
         loss_fn, which is only valid for the plain-CE objective."""
-        return self._maybe_fused_ce(self.llama.norm(h), labels)
+        with jax.named_scope("lm_head"):
+            return self._maybe_fused_ce(self.llama.norm(h), labels)

@@ -450,10 +450,13 @@ class Engine:
                 # unlike the poison paths there is no recovery here
                 if self._mem is not None and _fi.is_enabled():
                     _fi.fire("mem.oom")
-                self._expire_waiting()
+                # the engine's own account (serving/metrics.py): one
+                # now() at each phase boundary, a span between them
+                self.metrics.on_step_begin()
+                self._scheduling(self._expire_waiting)
                 self._timed_phase(prof, "prefill",
                                   self._admit_and_prefill)
-                self._grow_or_preempt()
+                self._scheduling(self._grow_or_preempt)
                 # perf attribution (FLAGS_perf_attribution): KV-page
                 # occupancy + goodput per engine iteration, sampled at
                 # the step's high-water point (pages grown, nothing
@@ -476,6 +479,7 @@ class Engine:
                     if active:
                         self._timed_phase(prof, "decode",
                                           self._decode_once, active)
+                self.metrics.on_step_end()
                 if self.prefix_cache is not None:
                     self.metrics.on_prefix_stats(
                         self.prefix_cache.stats(),
@@ -497,6 +501,16 @@ class Engine:
                 # between iterations
                 prof.step_end(_pt0, time.perf_counter())
         return self.has_work()
+
+    def _scheduling(self, fn):
+        """``fn()`` as scheduler and KV-allocator time: under the span
+        ``serving.schedule`` and in the open step's ``schedule``
+        seconds."""
+        t0 = now()
+        with span("serving.schedule"):
+            out = fn()
+        self.metrics.phase_s["schedule"] += now() - t0
+        return out
 
     def _timed_phase(self, prof, phase, fn, *args):
         """Run one step phase, feeding its host wall into the ptprof
@@ -581,19 +595,10 @@ class Engine:
 
     def _admit_and_prefill(self):
         while True:
-            if self._quarantine and self.scheduler.slots_active() > 0:
-                # poison bisect in progress: serialize admissions so a
-                # failing decode names a single request
-                return
-            admitted = self.scheduler.admit_next()
+            admitted = self._scheduling(self._admit_one)
             if admitted is None:
                 return
             slot, req = admitted
-            self.metrics.on_admission()
-            if self._mem is not None:
-                self._mem.note_decision(
-                    "admit", request=req.id, slot=slot,
-                    kv_pages_free=self.cache.allocator.free_blocks)
             if self.chunked_prefill:
                 # no synchronous prefill: the request sits in PREFILL
                 # state and its prompt streams through the mixed step
@@ -619,6 +624,23 @@ class Engine:
                 self._prefill_request(slot, req)
             except Exception as e:  # poison quarantine: the request's
                 self._fail_request(req, e)  # OWN step failed, not the engine
+
+    def _admit_one(self):
+        """The admission decision: (slot, request) of the next waiting
+        request that fits, or None."""
+        if self._quarantine and self.scheduler.slots_active() > 0:
+            # poison bisect in progress: serialize admissions so a
+            # failing decode names a single request
+            return None
+        admitted = self.scheduler.admit_next()
+        if admitted is not None:
+            slot, req = admitted
+            self.metrics.on_admission()
+            if self._mem is not None:
+                self._mem.note_decision(
+                    "admit", request=req.id, slot=slot,
+                    kv_pages_free=self.cache.allocator.free_blocks)
+        return admitted
 
     def _fail_request(self, req, exc):
         """Poison quarantine: one request's step raised — fail IT with
@@ -694,49 +716,57 @@ class Engine:
             hist = req.cached_tokens
             if not self.cache.make_writable(slot, hist, L):
                 raise AssertionError("COW clone raced the allocator")
-            suffix = tokens[hist:]
-            Ls = len(suffix)
-            P = self._bucket(Ls)
+            feed = tokens[hist:]
+            P = self._bucket(len(feed))
             req.trace_phase("prefill", slot=slot, tokens=L, bucket=P,
                             cached=hist,
                             resume=req.metrics.preemptions > 0)
-            ids = np.zeros((1, P), np.int32)
-            ids[0, :Ls] = suffix
-            with span("serving.prefill"):
-                tok, new_pools = self._run_eval(
-                    self._suffix_prefill, self._state_vals,
-                    self.cache.pools, jnp.asarray(ids),
-                    jnp.asarray(self.cache.block_tables[slot]),
-                    jnp.asarray(hist, jnp.int32),
-                    jnp.asarray(Ls, jnp.int32))
         else:
+            hist = None
+            feed = tokens
             P = self._bucket(L)
             req.trace_phase("prefill", slot=slot, tokens=L, bucket=P,
                             resume=req.metrics.preemptions > 0)
+        t0 = now()
+        with span("serving.prefill", request=req.id, tokens=L, bucket=P):
             ids = np.zeros((1, P), np.int32)
-            ids[0, :L] = tokens
-            with span("serving.prefill"):
+            ids[0, :len(feed)] = feed
+            with span("serving.upload"):
+                args = [jnp.asarray(ids),
+                        jnp.asarray(self.cache.block_tables[slot])]
+                if hist is None:
+                    fn = self._prefill
+                else:
+                    fn = self._suffix_prefill
+                    args.append(jnp.asarray(hist, jnp.int32))
+                args.append(jnp.asarray(len(feed), jnp.int32))
+            with span("serving.dispatch"):
                 tok, new_pools = self._run_eval(
-                    self._prefill, self._state_vals, self.cache.pools,
-                    jnp.asarray(ids),
-                    jnp.asarray(self.cache.block_tables[slot]),
-                    jnp.asarray(L, jnp.int32))
-        self.cache.pools = new_pools
-        self.cache.seq_lens[slot] = L
-        self.metrics.on_prefill_run()
-        if self.prefix_cache is not None:
-            # publish the freshly-computed prompt pages immediately —
-            # the next queued request sharing this prompt head admits
-            # against them, not against a finished-request race
-            self.prefix_cache.insert(tokens, self.cache.slot_pages(slot),
-                                     L)
-        req.state = RequestState.DECODING
-        req.metrics.on_first_token(now())
-        # decode phase opens BEFORE the first token is accepted: a
-        # max_new_tokens=1 request finishes inside _accept_token and
-        # its trace_finish must close the decode span, not prefill
-        req.trace_phase("decode", slot=slot)
-        self._accept_token(req, int(tok))
+                    fn, self._state_vals, self.cache.pools, *args)
+            self.cache.pools = new_pools
+            with span("serving.readback"):
+                tok = int(tok)
+            # the token is on the host: the prefill's end, and the
+            # token's stamp
+            t1 = now()
+        with span("serving.accept"):
+            self.cache.seq_lens[slot] = L
+            self.metrics.on_prefill_run()
+            self.metrics.on_prefill_done(t1 - t0, L, P)
+            if self.prefix_cache is not None:
+                # publish the freshly-computed prompt pages immediately
+                # — the next queued request sharing this prompt head
+                # admits against them, not against a finished-request
+                # race
+                self.prefix_cache.insert(
+                    tokens, self.cache.slot_pages(slot), L)
+            req.state = RequestState.DECODING
+            req.metrics.on_first_token(t1)
+            # decode phase opens BEFORE the first token is accepted: a
+            # max_new_tokens=1 request finishes inside _accept_token and
+            # its trace_finish must close the decode span, not prefill
+            req.trace_phase("decode", slot=slot)
+            self._accept_token(req, tok, t1)
 
     def _grow_or_preempt(self):
         """Every live row writes K/V this step — decode rows one
@@ -809,31 +839,56 @@ class Engine:
                         "preempt", victim=victim.id, grower=req.id,
                         kv_pages_free=self.cache.allocator.free_blocks)
 
+    def _batched_step(self, name, rows, fn, *host_args):
+        """One batched step through ``fn`` as the host lives it, under
+        the span ``name``: upload ``host_args``, dispatch, wait for the
+        tokens. -> (the tokens on the host, the stamp taken when they
+        got there), or None when the step raised and
+        ``_on_decode_failure`` has dealt with it."""
+        with span(name, step=self.metrics.decode_steps, rows=len(rows)):
+            try:
+                # batched injection site: a decode failure is NOT
+                # attributable to one request — the quarantine
+                # serializes the batch until it is
+                if _fi.is_enabled():
+                    _fi.fire("serving.decode", batch=len(rows))
+                t0 = now()
+                with span("serving.upload"):
+                    args = [jnp.asarray(a) for a in host_args]
+                t1 = now()
+                with span("serving.dispatch"):
+                    next_toks, new_pools = self._run_eval(
+                        fn, self._decode_vals, self.cache.pools, *args)
+            except Exception as e:  # poison quarantine
+                self._on_decode_failure(rows, e)
+                return None
+            t2 = now()
+            self.cache.pools = new_pools
+            with span("serving.readback"):
+                out = np.asarray(next_toks)
+            t3 = now()
+        phase_s = self.metrics.phase_s
+        phase_s["upload"] += t1 - t0
+        phase_s["dispatch"] += t2 - t1
+        phase_s["readback"] += t3 - t2
+        return out, t3
+
     def _decode_once(self, active):
-        try:
-            # batched injection site: a decode failure is NOT
-            # attributable to one request — the quarantine below
-            # serializes the batch until it is
-            if _fi.is_enabled():
-                _fi.fire("serving.decode", batch=len(active))
-            bt = jnp.asarray(self.cache.block_tables)
-            lens = jnp.asarray(self.cache.seq_lens)
-            toks = jnp.asarray(self._slot_tokens)
-            with span("serving.decode_step"):
-                next_toks, new_pools = self._run_eval(
-                    self._decode, self._decode_vals, self.cache.pools,
-                    toks, bt, lens)
-        except Exception as e:  # poison quarantine (see _on_decode_failure)
-            self._on_decode_failure(active, e)
+        done = self._batched_step(
+            "serving.decode_step", active, self._decode,
+            self._slot_tokens, self.cache.block_tables,
+            self.cache.seq_lens)
+        if done is None:
             return
-        self.cache.pools = new_pools
-        out = np.asarray(next_toks)
-        self.metrics.on_decode_step(len(active))
-        self._note_quant_step()
-        for slot, req in active:
-            # the input token's K/V row landed at position seq_len
-            self.cache.seq_lens[slot] += 1
-            self._accept_token(req, int(out[slot]))
+        out, t = done
+        with span("serving.accept"):
+            self.metrics.on_decode_step(len(active))
+            self._note_quant_step()
+            for slot, req in active:
+                # the input token's K/V row landed at position seq_len
+                self.cache.seq_lens[slot] += 1
+                self._accept_token(req, int(out[slot]), t)
+        self.metrics.phase_s["accept"] += now() - t
 
     def _mixed_once(self, rows):
         """ONE mixed ragged step (chunked prefill): decode rows feed
@@ -856,44 +911,37 @@ class Engine:
             else:
                 tokens[slot, 0] = self._slot_tokens[slot]
                 q_lens[slot] = 1
-        try:
-            # same batched injection site as the split decode step: a
-            # failure is not attributable to one request until the
-            # quarantine serializes the batch
-            if _fi.is_enabled():
-                _fi.fire("serving.decode", batch=len(rows))
-            bt = jnp.asarray(self.cache.block_tables)
-            lens = jnp.asarray(self.cache.seq_lens)
-            with span("serving.mixed_step"):
-                next_toks, new_pools = self._run_eval(
-                    self._mixed, self._decode_vals, self.cache.pools,
-                    jnp.asarray(tokens), bt, lens, jnp.asarray(q_lens))
-        except Exception as e:
-            self._on_decode_failure(rows, e)
+        done = self._batched_step(
+            "serving.mixed_step", rows, self._mixed, tokens,
+            self.cache.block_tables, self.cache.seq_lens, q_lens)
+        if done is None:
             return
-        self.cache.pools = new_pools
-        out = np.asarray(next_toks)
-        self.metrics.on_decode_step(len(rows))
-        self._note_quant_step()
-        for _ in range(chunk_rows):
-            self.metrics.on_prefill_chunk()
-        for slot, req in rows:
-            n = int(q_lens[slot])
-            self.cache.seq_lens[slot] += n
-            if req.state is RequestState.PREFILL:
-                req.prefill_pos += n
-                if req.prefill_pos < len(req.resume_tokens):
-                    continue        # mid-prompt: sampled token discarded
-                # final chunk: its last position's logits are the first
-                # generated token — the request becomes a decode row
-                if self.prefix_cache is not None:
-                    self.prefix_cache.insert(
-                        req.resume_tokens, self.cache.slot_pages(slot),
-                        int(self.cache.seq_lens[slot]))
-                req.state = RequestState.DECODING
-                req.metrics.on_first_token(now())
-                req.trace_phase("decode", slot=slot)
-            self._accept_token(req, int(out[slot]))
+        out, t = done
+        with span("serving.accept"):
+            self.metrics.on_decode_step(len(rows))
+            self._note_quant_step()
+            for _ in range(chunk_rows):
+                self.metrics.on_prefill_chunk()
+            for slot, req in rows:
+                n = int(q_lens[slot])
+                self.cache.seq_lens[slot] += n
+                if req.state is RequestState.PREFILL:
+                    req.prefill_pos += n
+                    if req.prefill_pos < len(req.resume_tokens):
+                        continue    # mid-prompt: sampled token discarded
+                    # final chunk: its last position's logits are the
+                    # first generated token — the request becomes a
+                    # decode row
+                    if self.prefix_cache is not None:
+                        self.prefix_cache.insert(
+                            req.resume_tokens,
+                            self.cache.slot_pages(slot),
+                            int(self.cache.seq_lens[slot]))
+                    req.state = RequestState.DECODING
+                    req.metrics.on_first_token(t)
+                    req.trace_phase("decode", slot=slot)
+                self._accept_token(req, int(out[slot]), t)
+        self.metrics.phase_s["accept"] += now() - t
 
     def _note_quant_step(self):
         """Per-step quant-KV accounting (FLAGS_serving_quant_kv; one
@@ -953,10 +1001,12 @@ class Engine:
                     slots_active=self.scheduler.slots_active())
         self._recover_consumed_pools()
 
-    def _accept_token(self, req, tok):
+    def _accept_token(self, req, tok, t):
+        """``t``: when the token reached the host, the stamp taken right
+        after its step's readback."""
         req.generated.append(tok)
         self._slot_tokens[req.slot] = tok
-        self.metrics.on_output_token()
+        self.metrics.on_output_token(req.metrics.on_token(t))
         done = (req.remaining <= 0
                 or (req.eos_token_id is not None
                     and tok == req.eos_token_id))

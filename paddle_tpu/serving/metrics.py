@@ -27,17 +27,40 @@ TTFT/TPOT/queue/e2e histograms, so serving shows up on the same
 /metrics endpoint and JSON snapshots as training telemetry. The dict
 API above stays — it is the benchmark-artifact schema.
 
-Chrome-trace spans: ``span("serving.decode_step")`` bridges into the
-native host recorder (csrc/trace.cc via profiler.RecordEvent, which
-also annotates the Xprof device timeline), so engine phases line up
-with kernel activity in the merged trace. Guarded: a build without the
-native lib degrades to a no-op, never breaks serving.
+The engine's own account of a step (always on; ``Engine.step()`` takes
+one ``now()`` at each phase boundary and keeps the rows in bounded rings
+here, ``to_dict()`` reduces them when asked):
+  host_ms        {schedule, upload, dispatch, readback, accept}: median
+      milliseconds of each host phase over the recent steps in which a
+      decode ran and no prefill did; the device works under ``readback``
+      and waits under the other four
+  recent_steps   how many steps those medians are over
+  prefill_ms     median milliseconds of one prefill, from building its
+      ids to its token on the host, over the recent prefills
+  itl_ms         {p50, p95} of the time between consecutive output
+      tokens of one request, each stamped when its step's tokens reached
+      the host (a prefill's token and the same step's first decode token
+      get their own two stamps)
+An empty ring gives ``None`` for its entry, never 0.
+
+Spans: ``span(name, **meta)`` IS ``jax.profiler.TraceAnnotation`` — a
+span lands in the profiler's trace, on the device trace's clock, and
+nowhere else; with no profiler session it costs under a microsecond.
+The engine opens eight names at the same boundaries as the stamps:
+``serving.schedule``, ``serving.prefill``, ``serving.decode_step`` /
+``serving.mixed_step`` (each ends after its readback), their children
+``serving.upload``, ``serving.dispatch``, ``serving.readback``, and
+``serving.accept``.
 """
 from __future__ import annotations
 
-import contextlib
+import collections
 import itertools
+import math
+import statistics
 import time
+
+import jax
 
 from ..monitor import counter as _mcounter
 from ..monitor import gauge as _mgauge
@@ -147,28 +170,21 @@ def now():
     return time.monotonic()
 
 
-@contextlib.contextmanager
-def span(name, level=1):
-    """Scoped chrome-trace span through csrc/trace.cc; no-op without
-    the native lib."""
-    ev = None
-    try:
-        from ..profiler import RecordEvent
+span = jax.profiler.TraceAnnotation
 
-        ev = RecordEvent(name, level=level)
-        ev.begin()
-    except Exception:
-        ev = None
-    try:
-        yield
-    finally:
-        if ev is not None:
-            try:
-                ev.end()
-            # ptlint: silent-except-ok — native trace-event teardown
-            # is best-effort; the span simply ends unclosed
-            except Exception:
-                pass
+# the host phases of one Engine.step(), in the order a step runs them
+HOST_PHASES = ("schedule", "upload", "dispatch", "readback", "accept")
+# stats() is read at the end of a run and must not hold the warm-up's
+# compiles, so the account is over recent rows, not since construction
+STEP_RING = 512
+PREFILL_RING = 512
+GAP_RING = 16384
+
+
+def _percentile(ordered, q):
+    """The value at rank ceil(q n) of a sorted sample."""
+    return ordered[min(max(math.ceil(q * len(ordered)) - 1, 0),
+                       len(ordered) - 1)]
 
 
 def counter(name, value):
@@ -197,6 +213,7 @@ class RequestMetrics:
         self.arrival_t = arrival_t
         self.first_admit_t = None
         self.first_token_t = None
+        self.last_token_t = None
         self.finish_t = None
         self.prompt_tokens = 0
         self.output_tokens = 0
@@ -240,6 +257,14 @@ class RequestMetrics:
             self.first_token_t = t
             with _mtrace.exemplar_context(self.trace_id):
                 _TTFT.observe(t - self.arrival_t)
+
+    def on_token(self, t):
+        """One output token reached the host at ``t``; -> seconds since
+        this request's previous one (None for its first). A preempted
+        request keeps its stamp: the gap over its recompute is one its
+        reader waited through."""
+        last, self.last_token_t = self.last_token_t, t
+        return None if last is None else t - last
 
     def on_finish(self, t, output_tokens):
         self.finish_t = t
@@ -319,6 +344,14 @@ class EngineMetrics:
         # KV quantization (FLAGS_serving_quant_kv; 0 with the flag off)
         self.kv_quant_pages = 0
         self.quant_dequant_bytes = 0
+        # the engine's account of its own time (module docstring):
+        # phase_s is the open step's seconds by host phase, which the
+        # engine adds to at each boundary; a finished step's row is
+        # (*phase seconds, prefills run, rows decoded)
+        self.on_step_begin()
+        self.steps = collections.deque(maxlen=STEP_RING)
+        self.prefills = collections.deque(maxlen=PREFILL_RING)
+        self.token_gaps = collections.deque(maxlen=GAP_RING)
 
     # -- engine hooks (mirror every sample into the shared registry) ---
 
@@ -397,9 +430,30 @@ class EngineMetrics:
             self.quant_dequant_bytes += int(dequant_bytes)
             _QUANT_DEQ_BYTES.inc(int(dequant_bytes))
 
-    def on_output_token(self):
+    def on_output_token(self, gap_s=None):
+        """``gap_s``: seconds since the same request's previous token
+        (``RequestMetrics.on_token``)."""
         self.output_tokens += 1
         _TOKENS.inc()
+        if gap_s is not None:
+            self.token_gaps.append(gap_s)
+
+    def on_step_begin(self):
+        self.phase_s = dict.fromkeys(HOST_PHASES, 0.0)
+        self._prefills_before = self.prefill_runs
+        self._rows = 0
+
+    def on_step_end(self):
+        """Close the open step's row. A step that found nothing to do
+        leaves none, so polling an idle engine does not push the
+        working steps out of the ring."""
+        prefills = self.prefill_runs - self._prefills_before
+        if self._rows or prefills:
+            self.steps.append(
+                (*self.phase_s.values(), prefills, self._rows))
+
+    def on_prefill_done(self, seconds, tokens, bucket):
+        self.prefills.append((seconds, tokens, bucket))
 
     def on_decode_compile(self):
         self.decode_compiles += 1
@@ -411,6 +465,7 @@ class EngineMetrics:
 
     def on_decode_step(self, active_slots):
         self.decode_steps += 1
+        self._rows = active_slots
         self._occupancy_sum += active_slots
         _DECODE_STEPS.inc()
         self._active_gauge.set(active_slots)
@@ -474,6 +529,11 @@ class EngineMetrics:
         occ = (self._occupancy_sum / (self.decode_steps * self.max_slots)
                if self.decode_steps else 0.0)
         throughput = self.output_tokens / wall if wall else 0.0
+        # list()/sorted() copy a deque in one C call, so a reader on
+        # another thread (fleet/replica.py) never sees one mid-append
+        decode_only = [r for r in list(self.steps) if r[-1] and not r[-2]]
+        prefills = list(self.prefills)
+        gaps = sorted(self.token_gaps)
         return {
             "requests_in": self.requests_in,
             "requests_finished": self.requests_finished,
@@ -501,4 +561,14 @@ class EngineMetrics:
             "prefill_chunks": self.prefill_chunks,
             "kv_quant_pages": self.kv_quant_pages,
             "quant_dequant_bytes": self.quant_dequant_bytes,
+            "host_ms": ({
+                phase: 1e3 * statistics.median(r[i] for r in decode_only)
+                for i, phase in enumerate(HOST_PHASES)}
+                if decode_only else None),
+            "recent_steps": len(decode_only),
+            "prefill_ms": (1e3 * statistics.median(r[0] for r in prefills)
+                           if prefills else None),
+            "itl_ms": ({"p50": 1e3 * _percentile(gaps, 0.50),
+                        "p95": 1e3 * _percentile(gaps, 0.95)}
+                       if gaps else None),
         }

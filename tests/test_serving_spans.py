@@ -1,0 +1,257 @@
+"""The engine's account of its own time: the ``serving.*`` spans it
+writes into the profiler's trace, and the stamps it keeps at the same
+boundaries for ``Engine.stats()``.
+
+One profiler session for the whole file (``traced``): a warmed split
+engine (``serving.prefill`` + ``serving.decode_step``) and a warmed
+chunked-prefill engine (``serving.mixed_step``) each serve a few
+requests under a span of the test's own, and the trace is read back with
+``jax.profiler.ProfileData``. Times are CPU times: asserted on for their
+order and their shares, never for their size.
+"""
+import glob
+import os
+import statistics
+import time
+
+import numpy as np
+import pytest
+
+import jax
+
+import paddle_tpu as paddle
+from paddle_tpu import serving
+from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.serving import metrics as smetrics
+
+STEP_SPANS = {"serving.prefill", "serving.decode_step",
+              "serving.mixed_step"}
+CHILD_SPANS = {"serving.upload", "serving.dispatch", "serving.readback"}
+ALL_SPANS = STEP_SPANS | CHILD_SPANS | {"serving.schedule",
+                                        "serving.accept"}
+
+
+@pytest.fixture(scope="module")
+def llama():
+    paddle.seed(0)
+    cfg = LlamaConfig(vocab_size=64, hidden_size=32, intermediate_size=64,
+                      num_hidden_layers=2, num_attention_heads=4,
+                      max_position_embeddings=128, use_parallel=False)
+    return LlamaForCausalLM(cfg)
+
+
+def _engine(model, chunked=False, **kw):
+    paddle.set_flags({"FLAGS_serving_chunked_prefill": chunked})
+    try:
+        return serving.Engine(model, **dict(
+            dict(max_slots=4, num_blocks=64, block_size=4), **kw))
+    finally:
+        paddle.set_flags({"FLAGS_serving_chunked_prefill": False})
+
+
+def _submit(eng, n, seed, new_tokens=6):
+    rng = np.random.RandomState(seed)
+    return [eng.add_request(rng.randint(0, 64, (int(rng.randint(3, 12)),))
+                            .tolist(), max_new_tokens=new_tokens)
+            for _ in range(n)]
+
+
+@pytest.fixture(scope="module")
+def traced(llama, tmp_path_factory):
+    """{"split" | "chunked": [(name, start_ns, end_ns, stats)] of the
+    ``serving.*`` spans of that engine's traced run, in start order}."""
+    engines = {"split": _engine(llama), "chunked": _engine(llama, True)}
+    for eng in engines.values():        # every shape compiled beforehand
+        _submit(eng, 6, seed=1)
+        eng.run()
+    trace_dir = str(tmp_path_factory.mktemp("serving_spans"))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    try:
+        for kind, eng in engines.items():
+            _submit(eng, 6, seed=2)
+            with jax.profiler.TraceAnnotation("test." + kind):
+                eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    events = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(("serving.", "test.")):
+                    events.append((ev.name, ev.start_ns,
+                                   ev.start_ns + ev.duration_ns,
+                                   dict(ev.stats)))
+    events.sort(key=lambda e: (e[1], -e[2]))
+    out = {}
+    for kind in engines:
+        (_, lo, hi, _), = [e for e in events if e[0] == "test." + kind]
+        out[kind] = [e for e in events if e[0].startswith("serving.")
+                     and lo <= e[1] and e[2] <= hi]
+    return out
+
+
+def _inside(inner, outer):
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+@pytest.mark.parametrize("kind,steps", [
+    ("split", {"serving.prefill", "serving.decode_step"}),
+    ("chunked", {"serving.mixed_step"})])
+def test_only_the_tables_names_appear(traced, kind, steps):
+    names = {e[0] for e in traced[kind]}
+    assert names <= ALL_SPANS
+    assert names == (ALL_SPANS - STEP_SPANS) | steps
+
+
+@pytest.mark.parametrize("kind", ["split", "chunked"])
+def test_children_lie_inside_a_step_that_ends_after_its_readback(
+        traced, kind):
+    spans = traced[kind]
+    parents = [e for e in spans if e[0] in STEP_SPANS]
+    children = [e for e in spans if e[0] in CHILD_SPANS]
+    assert parents and len(children) == 3 * len(parents)
+    for child in children:
+        assert sum(_inside(child, p) for p in parents) == 1, child
+    for parent in parents:
+        mine = [c for c in children if _inside(c, parent)]
+        assert [c[0] for c in mine] == [
+            "serving.upload", "serving.dispatch", "serving.readback"]
+        assert parent[2] >= mine[-1][2]
+    # scheduling and accepting are a step's siblings, not its children
+    for e in spans:
+        if e[0] in ("serving.schedule", "serving.accept"):
+            assert not any(_inside(e, p) for p in parents), e
+
+
+def test_metadata_names_the_request_and_the_step(traced):
+    prefills = [e for e in traced["split"] if e[0] == "serving.prefill"]
+    assert len(prefills) == 6
+    assert len({e[3]["request"] for e in prefills}) == 6
+    assert all(3 <= e[3]["tokens"] <= e[3]["bucket"] for e in prefills)
+    steps = [e[3] for e in traced["split"]
+             if e[0] == "serving.decode_step"]
+    assert [s["step"] for s in steps] == list(
+        range(steps[0]["step"], steps[0]["step"] + len(steps)))
+    assert all(1 <= s["rows"] <= 4 for s in steps)
+    assert all("step" in e[3] and "rows" in e[3]
+               for e in traced["chunked"] if e[0] == "serving.mixed_step")
+
+
+@pytest.mark.parametrize("kind", ["split", "chunked"])
+def test_spans_cover_the_engines_time(traced, kind):
+    spans = traced[kind]
+    lo, hi = spans[0][1], max(e[2] for e in spans)
+    covered, upto = 0, lo
+    for _, start, end, _ in spans:      # in start order
+        if end > upto:
+            covered += end - max(start, upto)
+            upto = end
+    assert covered >= 0.8 * (hi - lo)
+
+
+def _timed_run(eng):
+    """Run the engine dry; -> the walls of its decode-only steps."""
+    walls = []
+    while eng.has_work():
+        before = eng.metrics.prefill_runs
+        t0 = time.monotonic()
+        eng.step()
+        wall = time.monotonic() - t0
+        if eng.metrics.prefill_runs == before:
+            walls.append(wall)
+    return walls
+
+
+def test_stats_account_for_a_decode_step(llama):
+    eng = _engine(llama)
+    empty = eng.stats()
+    assert empty["host_ms"] is None and empty["prefill_ms"] is None
+    assert empty["itl_ms"] is None and empty["recent_steps"] == 0
+    _submit(eng, 20, seed=3, new_tokens=12)
+    walls = _timed_run(eng)
+    stats = eng.stats()
+    assert stats["requests_finished"] == 20
+    host = stats["host_ms"]
+    assert tuple(host) == smetrics.HOST_PHASES == (
+        "schedule", "upload", "dispatch", "readback", "accept")
+    assert all(v >= 0 for v in host.values())
+    assert stats["recent_steps"] == len(walls) > 20
+    # the phases are disjoint parts of the call the test timed: step by
+    # step they add up to no more than its wall, and to most of it
+    rows = [r for r in eng.metrics.steps if r[-1] and not r[-2]]
+    assert len(rows) == len(walls)
+    shares = [sum(r[:5]) / wall for r, wall in zip(rows, walls)]
+    assert max(shares) <= 1.0
+    assert statistics.median(shares) >= 0.5
+    for i, phase in enumerate(smetrics.HOST_PHASES):
+        assert host[phase] == pytest.approx(
+            1e3 * statistics.median(r[i] for r in rows))
+    assert stats["prefill_ms"] > 0
+    assert len(eng.metrics.prefills) == stats["prefill_runs"] == 20
+    assert all(3 <= tokens <= bucket
+               for _, tokens, bucket in eng.metrics.prefills)
+    itl = stats["itl_ms"]
+    assert itl["p95"] >= itl["p50"] > 0
+    # every token but a request's first closes a gap
+    assert len(eng.metrics.token_gaps) == stats["output_tokens"] - 20
+    # a prefill's token and the same step's first decode token are two
+    # stamps: no gap is zero
+    assert min(eng.metrics.token_gaps) > 0
+
+
+def test_chunked_prefill_fills_the_same_keys(llama):
+    eng = _engine(llama, chunked=True)
+    _submit(eng, 8, seed=4, new_tokens=10)
+    eng.run()
+    stats = eng.stats()
+    assert stats["prefill_chunks"] > 0
+    assert tuple(stats["host_ms"]) == smetrics.HOST_PHASES
+    assert all(v >= 0 for v in stats["host_ms"].values())
+    assert stats["host_ms"]["readback"] > 0
+    assert stats["recent_steps"] > 0
+    assert stats["itl_ms"]["p95"] >= stats["itl_ms"]["p50"] > 0
+    assert len(eng.metrics.token_gaps) == stats["output_tokens"] - 8
+    # no prefill of its own: the prompt rides the mixed step
+    assert stats["prefill_ms"] is None
+
+
+def test_rings_stay_at_their_caps(llama):
+    eng = _engine(llama, max_slots=1)
+    _submit(eng, 14, seed=5, new_tokens=40)
+    eng.run()
+    assert eng.metrics.decode_steps > smetrics.STEP_RING
+    assert len(eng.metrics.steps) == smetrics.STEP_RING
+    assert eng.stats()["recent_steps"] <= smetrics.STEP_RING
+    # an idle engine's polls leave no rows
+    last = eng.metrics.steps[-1]
+    for _ in range(3):
+        eng.step()
+    assert eng.metrics.steps[-1] is last
+    # the other two rings, fed as the engine feeds them
+    em = smetrics.EngineMetrics(max_slots=1)
+    for i in range(smetrics.GAP_RING + 100):
+        em.on_output_token(1e-3 * (i % 7 + 1))
+    for i in range(smetrics.PREFILL_RING + 100):
+        em.on_prefill_done(0.01, 8, 8)
+    assert len(em.token_gaps) == smetrics.GAP_RING
+    assert len(em.prefills) == smetrics.PREFILL_RING
+    assert em.output_tokens == smetrics.GAP_RING + 100
+    assert em.to_dict()["itl_ms"] == {"p50": 4.0, "p95": 7.0}
+
+
+def test_a_span_without_a_session_touches_no_native_code(monkeypatch):
+    from paddle_tpu.core import native
+
+    assert smetrics.span is jax.profiler.TraceAnnotation
+    monkeypatch.setattr(
+        native, "get_lib",
+        lambda: pytest.fail("a span touched the native library"))
+    with smetrics.span("serving.prefill", request=1, tokens=5, bucket=8):
+        with smetrics.span("serving.upload"):
+            pass

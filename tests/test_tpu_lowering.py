@@ -283,3 +283,60 @@ class TestFlashOnAMesh:
         # and "no mesh" did not conjure the all-devices default
         with pmesh.scoped_mesh(None):
             assert pmesh.current_mesh() is None
+
+
+class TestBlocksAreNamed:
+    """``models/llama.py`` wraps each decoder layer, its two halves and
+    the head in ``jax.named_scope``: every instruction's ``op_name``
+    says which block it belongs to, and a Mosaic kernel keeps its own
+    name as the path element before ``pallas_call``."""
+
+    SCOPES = ("layer_0/attn", "layer_0/mlp", "layer_1/attn",
+              "layer_1/mlp", "lm_head")
+
+    @pytest.fixture(scope="class")
+    def forward(self):
+        """(function of (state values, ids), state values) of a
+        two-layer model with heads of 128."""
+        import paddle_tpu as paddle
+        from paddle_tpu.core.dispatch import no_grad
+        from paddle_tpu.core.tensor import Tensor
+        from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+
+        paddle.seed(0)
+        model = LlamaForCausalLM(LlamaConfig(
+            vocab_size=256, hidden_size=256, intermediate_size=512,
+            num_hidden_layers=2, num_attention_heads=2,
+            num_key_value_heads=2, max_position_embeddings=256,
+            use_parallel=False))
+        names, values = model.functional_state()
+
+        def fwd(values, ids, labels):
+            with model.bind_state(names, list(values)), no_grad():
+                return model(Tensor(ids), Tensor(labels))._value
+
+        return fwd, values
+
+    def test_lowered_forward_carries_every_block(self, forward):
+        fwd, values = forward
+        ids = jnp.zeros((1, 128), I32)
+        text = jax.jit(fwd).lower(values, ids, ids).as_text(
+            debug_info=True)
+        for scope in self.SCOPES:
+            assert scope in text, scope
+
+    def test_mosaic_kernels_keep_their_names_inside_a_block(
+            self, v5e, forward, monkeypatch):
+        fwd, values = forward
+        # the attention dispatch asks the backend; here it is the CPU
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        avals = [jax.ShapeDtypeStruct(v.shape, BF16, sharding=v5e)
+                 for v in values]
+        ids = jax.ShapeDtypeStruct((1, 128), I32, sharding=v5e)
+        text = jax.jit(fwd).lower(avals, ids, ids).compile().as_text()
+        assert mosaic_kernels(text) == {"flash_fwd": 2}
+        for layer in (0, 1):
+            assert ('layer_%d/attn/flash_fwd/pallas_call"' % layer
+                    in text)
+        for scope in self.SCOPES:
+            assert scope in text, scope
