@@ -10,8 +10,10 @@ inference the same shape discipline under serving traffic:
   out-of-blocks signal (the vLLM/Ragged-Paged-Attention memory model,
   PAPERS.md arxiv 2604.15464).
 - ``kernels.paged_attention``: a Pallas ragged paged-attention decode
-  kernel (one query token per slot, K/V gathered through the block
-  table) with a jnp fallback that is exact against
+  kernel (one query token per slot; the pools stay in HBM and the
+  kernel fetches a slot's live pages through the block table, a group
+  of pages sized from the page's bytes per loop trip, the loop bounded
+  by the slot's length) with a jnp fallback that is exact against
   ``masked_decode_attention``.
 - ``scheduler`` / ``engine``: request lifecycle (queued → prefill →
   decoding → finished/preempted), FCFS admission control, slot reuse on

@@ -7,6 +7,7 @@ Kernel oracle: ``masked_decode_attention`` (the dense decode path) —
 the ragged paged-attention kernel gathers the same history through the
 block table and must match to fp32 tolerance in interpret mode.
 """
+import importlib
 import math
 
 import numpy as np
@@ -24,6 +25,10 @@ from paddle_tpu.serving.kernels.paged_attention import (
     paged_attention_reference,
 )
 from paddle_tpu.serving.kv_cache import BlockAllocator, PagedKVCache
+
+# the package re-exports a function under the module's name
+pa_module = importlib.import_module(
+    "paddle_tpu.serving.kernels.paged_attention")
 
 
 @pytest.fixture(scope="module")
@@ -137,6 +142,144 @@ class TestPagedAttentionKernel:
         noisy = np.asarray(paged_attention_kernel(
             q, kp2, vp2, bt, np.asarray(lens, np.int32), interpret=True))
         np.testing.assert_array_equal(base, noisy)
+
+
+    # -- the page group ----------------------------------------------------
+    # A loop trip handles G pages; G follows a VMEM budget, which the
+    # tests shrink so that a few tiny pages make a group.
+
+    BS, GROUP, D, NB = 4, 4, 16, 96          # G * bs = 16 tokens a trip
+
+    @pytest.fixture
+    def small_groups(self, monkeypatch):
+        """Make ``GROUP`` float32 pages of 2 kv heads one group."""
+        def pin(hkv, itemsize=4):
+            monkeypatch.setattr(
+                pa_module, "_KV_VMEM_BUDGET",
+                4 * self.GROUP * self.BS * hkv * self.D * itemsize)
+        return pin
+
+    def _grouped(self, rng, h, hkv, mb, lens, order, dtype=jnp.float32):
+        """Random pools with every page written (so anything read past a
+        length shows), and block tables in the given page order."""
+        bs, d, nb = self.BS, self.D, self.NB
+        s = len(lens)
+        q = jnp.asarray(rng.randn(s, h, d), dtype)
+        kp = jnp.asarray(rng.randn(nb, bs, hkv, d), dtype)
+        vp = jnp.asarray(rng.randn(nb, bs, hkv, d), dtype)
+        need = [-(-n // bs) for n in lens]
+        if order == "interleaved":       # slot i owns 1+i, 1+i+s, ...
+            owned = [[1 + i + j * s for j in range(n)]
+                     for i, n in enumerate(need)]
+        else:
+            owned, nxt = [], 1
+            for n in need:
+                owned.append(list(range(nxt, nxt + n)))
+                nxt += n
+            if order == "descending":
+                owned = [pages[::-1] for pages in owned]
+            elif order == "shuffled":
+                perm = rng.permutation(np.arange(1, nb))
+                owned = [[int(perm[p - 1]) for p in pages]
+                         for pages in owned]
+        bt = np.zeros((s, mb), np.int32)
+        for i, pages in enumerate(owned):
+            bt[i, :len(pages)] = pages
+        return q, kp, vp, bt, np.asarray(lens, np.int32), owned
+
+    # G*bs = 16 and mb*bs = 40: mb = 10 is not a multiple of G = 4
+    @pytest.mark.parametrize("h,hkv,mb,lens,order", [
+        (4, 4, 10, [0, 1, 0], "ascending"),
+        (4, 4, 10, [15, 16, 17], "ascending"),
+        (4, 4, 10, [40, 31, 32, 33], "ascending"),
+        (8, 2, 10, [0, 1, 15, 16, 17, 40], "ascending"),
+        (8, 2, 10, [15, 16, 17, 40], "descending"),
+        (8, 2, 10, [17, 40, 1, 16], "interleaved"),
+        (4, 4, 10, [33, 0, 40, 5], "shuffled"),
+        (8, 2, 8, [32, 31, 17, 0], "shuffled"),       # mb a multiple of G
+        (8, 2, 3, [12, 9, 1], "descending"),          # G capped at mb
+        (6, 3, 10, [16, 40, 7], "interleaved"),       # odd kv heads
+    ], ids=["len_0_1", "mha_around_one_group", "mha_last_partial_group",
+            "gqa_every_boundary", "gqa_descending_pages",
+            "gqa_interleaved_pages", "mha_shuffled_pages",
+            "gqa_mb_multiple_of_group", "gqa_group_capped_at_mb",
+            "gqa_three_kv_heads"])
+    def test_groups_match_reference(self, small_groups, h, hkv, mb, lens,
+                                    order):
+        """Lengths on both sides of a group's edge, a last group that is
+        partial, block tables in any order: the kernel equals the gather
+        reference to fp32 tolerance; idle slots are exact zeros."""
+        small_groups(hkv)
+        assert pa_module._pages_per_group(
+            self.BS, hkv, self.D, 4, mb) == min(self.GROUP, mb)
+        rng = np.random.RandomState(len(lens) * 131 + h + mb)
+        q, kp, vp, bt, ln, _ = self._grouped(rng, h, hkv, mb, lens, order)
+        got = np.asarray(paged_attention_kernel(q, kp, vp, bt, ln,
+                                                interpret=True))
+        want = np.asarray(paged_attention_reference(q, kp, vp, bt, ln))
+        live = ln > 0
+        np.testing.assert_allclose(got[live], want[live], atol=1e-5)
+        np.testing.assert_array_equal(got[~live], 0.0)
+
+    def test_nothing_past_the_length_reaches_the_result(self,
+                                                        small_groups):
+        """NaN in every page a slot does not own, in its own last page
+        past its length and in the trash page: that slot's output is
+        finite and bit-equal to the clean run's, and an idle slot is
+        still exactly zero."""
+        hkv, mb = 2, 10
+        small_groups(hkv)
+        lens = [17, 16, 5, 0, 40]
+        rng = np.random.RandomState(11)
+        q, kp, vp, bt, ln, owned = self._grouped(
+            rng, 8, hkv, mb, lens, "shuffled")
+        clean = np.asarray(paged_attention_kernel(q, kp, vp, bt, ln,
+                                                  interpret=True))
+        assert np.isfinite(clean).all()
+        for i, n in enumerate(lens):
+            keep = np.zeros((self.NB, self.BS), bool)
+            for j, page in enumerate(owned[i]):
+                keep[page, :max(0, min(self.BS, n - j * self.BS))] = True
+            assert keep.sum() == n and not keep[0].any()
+            poison = jnp.asarray(~keep)[:, :, None, None]
+            noisy = np.asarray(paged_attention_kernel(
+                q, jnp.where(poison, jnp.nan, kp),
+                jnp.where(poison, jnp.nan, vp), bt, ln, interpret=True))
+            assert np.isfinite(noisy[i]).all(), "slot %d" % i
+            np.testing.assert_array_equal(noisy[i], clean[i],
+                                          err_msg="slot %d" % i)
+
+    def test_bf16_pool_matches_reference(self, small_groups):
+        """A bf16 pool goes to the dots as stored, two kv heads to a
+        32-bit word: equal to the reference at chip_smoke.py's
+        tolerance."""
+        hkv, mb = 2, 10
+        small_groups(hkv, itemsize=2)
+        lens = [0, 1, 15, 16, 17, 40]
+        rng = np.random.RandomState(12)
+        q, kp, vp, bt, ln, _ = self._grouped(
+            rng, 8, hkv, mb, lens, "shuffled", dtype=jnp.bfloat16)
+        assert pa_module._heads_per_word(kp.dtype, hkv) == 2
+        got = np.asarray(paged_attention_kernel(
+            q, kp, vp, bt, ln, interpret=True), np.float32)
+        want = np.asarray(paged_attention_reference(q, kp, vp, bt, ln),
+                          np.float32)
+        live = ln > 0
+        np.testing.assert_allclose(got[live], want[live], atol=2e-2,
+                                   rtol=2e-2)
+        np.testing.assert_array_equal(got[~live], 0.0)
+
+    @pytest.mark.parametrize("bs,hkv,d,itemsize,mb,want", [
+        (16, 8, 128, 2, 160, 32),       # the chat-backlog cell: 512 tokens
+        (16, 8, 128, 2, 12, 12),        # never more than a slot has
+        (16, 16, 128, 4, 128, 8),       # fp32 MHA pages are 4x the bytes
+        (16, 8, 128, 1, 160, 64),       # int8 pages half of bf16's
+        (64, 64, 256, 4, 64, 1),        # a page over the budget: one
+    ], ids=["cell", "capped_at_mb", "f32_mha", "int8", "huge_page"])
+    def test_pages_per_group_follows_the_page_bytes(self, bs, hkv, d,
+                                                    itemsize, mb, want):
+        assert pa_module._pages_per_group(bs, hkv, d, itemsize,
+                                          mb) == want
 
 
 # ---------------------------------------------------------------------------

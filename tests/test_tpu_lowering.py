@@ -1,6 +1,7 @@
 """Every Pallas kernel, lowered and compiled for the TPU from the CPU.
 
-Two layers, both at the chip_smoke.py geometry (llama1b widths):
+Two layers, both at the chip_smoke.py geometry (llama1b widths), plus
+the paged decode kernel at the benchmark's serving cell's own:
 
 - ``TestCrossLowering``: ``jit(f).trace(...).lower(lowering_platforms=
   ("tpu",))`` with ``interpret=False``. This is Pallas's own TPU
@@ -39,6 +40,10 @@ BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 B, N, H, D = 8, 1024, 16, 128
 T, HID, V = B * N, 2048, 32000
 S, NB, BS, MB, C = 8, 512, 16, 128, 16
+# mistral7b-chat-backlog: 64 slots x 160 pages of a 10,000-page pool,
+# 32 heads over 8 kv heads (benchmark/traffic/chat-backlog.json)
+CELL = "paged_decode_bf16_gqa_cell"
+CELL_S, CELL_NB, CELL_MB, CELL_H, CELL_HKV = 64, 10000, 160, 32, 8
 
 
 def _flash(dtype, d, segmented=False):
@@ -72,15 +77,17 @@ def _cases():
             cases.append((name + "_fwd", fwd, args, {"flash_fwd"}))
         cases.append((name + "_bwd", bwd, args,
                       {"flash_fwd", "flash_dq", "flash_dkv"}))
-    for name, dtype, h, hkv in (("paged_decode_bf16_mha", BF16, 16, 16),
-                                ("paged_decode_f32_mha", F32, 16, 16),
-                                ("paged_decode_bf16_gqa", BF16, 32, 8)):
-        pool = ((NB, BS, hkv, D), dtype)
+    for name, dtype, h, hkv, s, nb, mb in (
+            ("paged_decode_bf16_mha", BF16, 16, 16, S, NB, MB),
+            ("paged_decode_f32_mha", F32, 16, 16, S, NB, MB),
+            ("paged_decode_bf16_gqa", BF16, 32, 8, S, NB, MB),
+            (CELL, BF16, CELL_H, CELL_HKV, CELL_S, CELL_NB, CELL_MB)):
+        pool = ((nb, BS, hkv, D), dtype)
         cases.append((
             name,
             lambda q, k, v, bt, ln: pa.paged_attention_kernel(
                 q, k, v, bt, ln, interpret=False),
-            [((S, h, D), dtype), pool, pool, ((S, MB), I32), ((S,), I32)],
+            [((s, h, D), dtype), pool, pool, ((s, mb), I32), ((s,), I32)],
             {"paged_decode"}))
     for name, h, hkv in (("paged_mixed_bf16_mha", 16, 16),
                          ("paged_mixed_bf16_gqa", 32, 8)):
@@ -155,7 +162,13 @@ class TestMosaicCompile:
         avals = [jax.ShapeDtypeStruct(s, d, sharding=v5e)
                  for s, d in args]
         compiled = jax.jit(fn).lower(*avals).compile()
-        assert kernels <= set(mosaic_kernels(compiled.as_text())), name
+        found = mosaic_kernels(compiled.as_text())
+        assert kernels <= set(found), name
+        if name == CELL:
+            # one call a layer by one name: no second kernel to combine
+            # partial sums, and 32 pages a group inside the VMEM limit
+            assert found == {"paged_decode": 1}
+            assert pa._pages_per_group(BS, CELL_HKV, D, 2, CELL_MB) == 32
 
 
 class TestInterpretNeverOnTPU:
