@@ -9,3 +9,7 @@ from .ernie import (  # noqa: F401
 )
 from .gpt import GPTModel  # noqa: F401
 from .llama import LlamaConfig, LlamaForCausalLM, LlamaModel  # noqa: F401
+from .qwen3_next import (  # noqa: F401
+    Qwen3NextConfig,
+    Qwen3NextForCausalLM,
+)

@@ -91,7 +91,7 @@ class GPTModel(GenerationMixin, Layer):
                  use_parallel=False, moe_experts=0, moe_every=2,
                  moe_top_k=2, moe_aux_coeff=0.01):
         """moe_experts > 0 turns every `moe_every`-th block into a
-        GShard-style MoE block (expert-parallel over the dp mesh axis)."""
+        dropless MoE block (parallel/moe.py; every expert held here)."""
         super().__init__()
         ffn_size = ffn_size or 4 * hidden_size
         Emb = VocabParallelEmbedding if use_parallel else Embedding
@@ -160,10 +160,11 @@ class GPTModel(GenerationMixin, Layer):
 
     def paged_cache_spec(self):
         """KV geometry for the serving engine's paged cache."""
-        return {"num_layers": len(self.blocks),
-                "num_kv_heads": self.blocks[0].heads,
-                "head_dim": self.blocks[0].head_dim,
-                "dtype": str(self.wte.weight._value.dtype)}
+        from ..serving.kv_cache import KVPages
+
+        return [KVPages(self.blocks[0].heads, self.blocks[0].head_dim,
+                        str(self.wte.weight._value.dtype))] * len(
+                            self.blocks)
 
     def init_decode_caches(self, batch, total_len):
         head_dim = self.blocks[0].head_dim

@@ -470,11 +470,12 @@ class LlamaForCausalLM(GenerationMixin, Layer):
     def paged_cache_spec(self):
         """KV geometry for the serving engine's paged cache (the engine
         owns the cache — serving/engine.py)."""
+        from ..serving.kv_cache import KVPages
+
         cfg = self.config
-        return {"num_layers": cfg.num_hidden_layers,
-                "num_kv_heads": cfg.num_key_value_heads,
-                "head_dim": cfg.hidden_size // cfg.num_attention_heads,
-                "dtype": cfg.dtype}
+        return [KVPages(cfg.num_key_value_heads,
+                        cfg.hidden_size // cfg.num_attention_heads,
+                        cfg.dtype)] * cfg.num_hidden_layers
 
     def init_decode_caches(self, batch, total_len):
         cfg = self.config

@@ -1,194 +1,194 @@
-"""Mixture-of-Experts with expert parallelism.
+"""Mixture-of-Experts: one dropless layer that is told which experts it
+holds.
 
 Parity: reference MoELayer
 (/root/reference/python/paddle/incubate/distributed/models/moe/moe_layer.py:260)
-with its gates (gate/gshard_gate.py, switch_gate.py, naive_gate.py) and the
-global_scatter/global_gather all-to-all ops
-(/root/reference/paddle/fluid/operators/collective/global_scatter_op.cc).
+with its gates (gate/gshard_gate.py, switch_gate.py, naive_gate.py).
 
-TPU-native design: instead of the reference's variable-size brpc/NCCL
-all-to-all (token counts exchanged first, then payloads), dispatch is
-capacity-based and dense — the GShard formulation. Tokens are routed into a
-fixed [experts, capacity, d_model] buffer with einsum one-hots; the expert
-dimension is sharded over a mesh axis (default the dp axis, matching the
-reference's moe_group spanning data-parallel ranks) so GSPMD lowers the
-dispatch/combine einsums into exactly one fused all-to-all pair over ICI.
-Experts are evaluated as ONE batched matmul over the stacked expert weights
-— MXU-friendly, no per-expert kernel launches. Over-capacity tokens are
-dropped (contribute zero), as in GShard/Switch; the reference's
-variable-length semantics cannot be expressed as a static XLA program.
+The layer routes every token over ALL ``num_experts`` (the published
+router width), keeps the pairs (token, expert) whose expert lies in
+``experts_held = range(lo, hi)``, sorts them by expert and runs the
+held experts as two grouped matmuls over the sorted rows (in, then
+out): the Mosaic kernel ``moe_gmm`` on a TPU, ``jax.lax.ragged_dot``
+elsewhere (kernels/moe_gmm.py). No capacity, so no token is dropped and
+no expert is padded; an expert that received no row costs nothing. What
+the experts held elsewhere would have added is LEFT OUT of the result:
+with ``experts_held`` the whole range that is nothing, with a share it
+is the partial sum a chip of an expert-parallel deployment computes
+before the exchange. The exchange itself (all-to-all between the chips
+that share a layer) is not here; nothing stands in for it.
 """
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from ..core.dispatch import primitive
+from ..kernels.moe_gmm import grouped_matmul
 from ..nn import initializer as I
 from ..nn.layer import Layer
 
 _A = jnp.asarray
+_ACTIVATIONS = {"gelu": jax.nn.gelu, "relu": jax.nn.relu,
+                "silu": jax.nn.silu}
 
 
-def _constrain(x, *spec):
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except Exception:
-        return x
+def route(x, gate_w, top_k, norm_topk_prob):
+    """Softmax router in float32 over every published expert.
+    x [T, D], gate_w [D, E] -> (weights [T, k], experts [T, k] int32,
+    aux): the top-k probabilities (renormalised to sum 1 when
+    ``norm_topk_prob``), their experts, and the load-balance loss
+    E * sum_e (mean probability of e) * (share of first choices e)."""
+    gate_w = _A(gate_w)
+    if x.dtype == jnp.float32 or gate_w.dtype == jnp.float32:
+        logits = jnp.dot(x.astype(jnp.float32), gate_w.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+    else:
+        # 16-bit operands: their products are exact in float32, so one
+        # native pass with a float32 accumulator IS the float32 matmul
+        logits = jnp.dot(x, gate_w, preferred_element_type=jnp.float32,
+                         precision=jax.lax.Precision.DEFAULT)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = jax.lax.top_k(probs, top_k)
+    if norm_topk_prob:
+        weights = weights / jnp.maximum(
+            jnp.sum(weights, axis=-1, keepdims=True), 1e-9)
+    e = probs.shape[-1]
+    first = jax.nn.one_hot(experts[:, 0], e, dtype=probs.dtype)
+    aux = e * jnp.sum(jnp.mean(probs, axis=0) * jnp.mean(first, axis=0))
+    return weights, experts.astype(jnp.int32), aux
 
 
-def _top1_dispatch(probs, capacity):
-    """Switch-style top-1 routing. probs [T, E] -> combine [T, E, C], aux."""
-    t, e = probs.shape
-    idx1 = jnp.argmax(probs, axis=-1)
-    mask1 = jax.nn.one_hot(idx1, e, dtype=probs.dtype)          # [T, E]
-    gates1 = jnp.sum(probs * mask1, axis=-1)                    # [T]
-    # load-balance loss: E * sum_e frac_tokens_e * mean_prob_e
-    me = jnp.mean(probs, axis=0)
-    ce = jnp.mean(mask1, axis=0)
-    aux = e * jnp.sum(me * ce)
-    pos1 = jnp.cumsum(mask1, axis=0) * mask1 - mask1            # [T, E] pos
-    pos1 = jnp.sum(pos1, axis=-1)                               # [T]
-    keep = (pos1 < capacity).astype(probs.dtype) * jnp.sum(mask1, -1)
-    combine = (gates1 * keep)[:, None, None] * (
-        mask1[:, :, None] *
-        jax.nn.one_hot(pos1.astype(jnp.int32), capacity,
-                       dtype=probs.dtype)[:, None, :])
-    return combine, aux
+def moe_forward(x, gate_w, w_in, b_in, w_out, b_out, *, top_k, lo=0,
+                activation="gelu", gated=False, norm_topk_prob=None):
+    """The dropless expert layer on raw arrays.
 
-
-def _top2_dispatch(probs, capacity):
-    """GShard-style top-2 routing with renormalized combine weights."""
-    t, e = probs.shape
-    idx1 = jnp.argmax(probs, axis=-1)
-    mask1 = jax.nn.one_hot(idx1, e, dtype=probs.dtype)
-    probs_wo1 = probs * (1.0 - mask1)
-    idx2 = jnp.argmax(probs_wo1, axis=-1)
-    mask2 = jax.nn.one_hot(idx2, e, dtype=probs.dtype)
-    g1 = jnp.sum(probs * mask1, axis=-1)
-    g2 = jnp.sum(probs * mask2, axis=-1)
-    denom = jnp.maximum(g1 + g2, 1e-9)
-    g1, g2 = g1 / denom, g2 / denom
-    # aux loss over first choice only (gshard_gate semantics)
-    me = jnp.mean(probs, axis=0)
-    ce = jnp.mean(mask1, axis=0)
-    aux = e * jnp.sum(me * ce)
-    pos1 = jnp.sum(jnp.cumsum(mask1, axis=0) * mask1 - mask1, axis=-1)
-    # second choice queues behind all first choices of the same expert
-    counts1 = jnp.sum(mask1, axis=0, keepdims=True)             # [1, E]
-    pos2 = jnp.sum(
-        (jnp.cumsum(mask2, axis=0) - 1 + counts1) * mask2, axis=-1)
-    keep1 = (pos1 < capacity).astype(probs.dtype) * jnp.sum(mask1, -1)
-    keep2 = (pos2 < capacity).astype(probs.dtype) * jnp.sum(mask2, -1)
-    oh = lambda pos: jax.nn.one_hot(pos.astype(jnp.int32), capacity,
-                                    dtype=probs.dtype)
-    combine = (g1 * keep1)[:, None, None] * (
-        mask1[:, :, None] * oh(pos1)[:, None, :])
-    combine = combine + (g2 * keep2)[:, None, None] * (
-        mask2[:, :, None] * oh(pos2)[:, None, :])
-    return combine, aux
+    x [T, D]; gate_w [D, E] routes over all E published experts; the
+    held experts are ``lo .. lo + H - 1`` with w_in [H, D, F] (or
+    [H, D, 2F] when ``gated``: gate and up side by side, out =
+    act(gate) * up), w_out [H, F, D]; b_in [H, F or 2F] / b_out [H, D]
+    or None. ``norm_topk_prob`` None means "when top_k > 1" (a single
+    choice keeps its raw probability, or the router would get no
+    gradient). -> (out [T, D], aux loss, stats int32 [3]): the share of
+    the result the held experts give; pairs routed here, held experts
+    that received a row, the largest load of one expert."""
+    x = _A(x)
+    w_in, w_out = _A(w_in), _A(w_out)
+    t, d = x.shape
+    held = w_in.shape[0]
+    if norm_topk_prob is None:
+        norm_topk_prob = top_k > 1
+    weights, experts, aux = route(x, gate_w, top_k, norm_topk_prob)
+    here = jnp.logical_and(experts >= lo, experts < lo + held)   # [T, k]
+    # pairs sorted by held expert; the pairs of experts held elsewhere
+    # sort to the end, past every group, where nothing is computed
+    key = jnp.where(here, experts - lo, held).reshape(-1)        # [T*k]
+    order = jnp.argsort(key, stable=True)
+    sorted_key = key[order]
+    # the keys are sorted: a group's rows lie between two searches
+    ends = jnp.searchsorted(sorted_key, jnp.arange(held + 1, dtype=key.dtype))
+    sizes = (ends[1:] - ends[:-1]).astype(jnp.int32)
+    rows = x[order // top_k]                                     # [T*k, D]
+    h = grouped_matmul(rows, w_in, sizes)
+    # a row's own expert, for the biases (rows past every group: any)
+    expert_of_row = jnp.minimum(sorted_key, held - 1)
+    if b_in is not None:
+        h = h + _A(b_in)[expert_of_row]
+    act = _ACTIVATIONS[activation]
+    if gated:
+        f = h.shape[-1] // 2
+        h = act(h[:, :f]) * h[:, f:]
+    else:
+        h = act(h)
+    y = grouped_matmul(h.astype(x.dtype), w_out, sizes)
+    if b_out is not None:
+        y = y + _A(b_out)[expert_of_row]
+    # back to pair order, one choice at a time: k gathers of [T, D]
+    # summed in float32 as they come, so the [T, k, D] block is never
+    # laid out (k is no multiple of a tile: that reshape is a copy).
+    # A row of no group is undefined, not zero.
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype)).reshape(t, top_k)
+    out = jnp.zeros((t, d), jnp.float32)
+    for j in range(top_k):
+        picked = y[back[:, j]].astype(jnp.float32) * weights[:, j, None]
+        out = out + jnp.where(here[:, j, None], picked, 0.0)
+    out = out.astype(x.dtype)
+    stats = jnp.stack([jnp.sum(here, dtype=jnp.int32),
+                       jnp.sum(sizes > 0, dtype=jnp.int32),
+                       jnp.max(sizes)])
+    return out, aux.astype(x.dtype), stats
 
 
 @primitive
-def moe_mlp(x, gate_w, w1, b1, w2, b2, *, top_k, capacity, ep_axis,
-            activation):
-    """Full MoE feed-forward: gate -> dispatch -> batched experts -> combine.
-
-    x [T, D]; gate_w [D, E]; w1 [E, D, H]; b1 [E, H]; w2 [E, H, D];
-    b2 [E, D]. Returns (out [T, D], aux_loss scalar).
-    """
-    x = _A(x)
-    xf = x.astype(jnp.float32)
-    logits = xf @ _A(gate_w).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    if top_k == 1:
-        combine, aux = _top1_dispatch(probs, capacity)
-    elif top_k == 2:
-        combine, aux = _top2_dispatch(probs, capacity)
-    else:
-        raise NotImplementedError("top_k must be 1 or 2")
-    combine = combine.astype(x.dtype)
-    dispatch = (combine > 0).astype(x.dtype)                   # [T, E, C]
-    # all-to-all boundary: expert dim sharded over ep_axis
-    expert_in = jnp.einsum("tec,td->ecd", dispatch, x)
-    expert_in = _constrain(expert_in, ep_axis, None, None)
-    h = jnp.einsum("ecd,edh->ech", expert_in, _A(w1)) + _A(b1)[:, None, :]
-    if activation == "gelu":
-        h = jax.nn.gelu(h)
-    elif activation == "relu":
-        h = jax.nn.relu(h)
-    elif activation == "silu":
-        h = jax.nn.silu(h)
-    y = jnp.einsum("ech,ehd->ecd", h, _A(w2)) + _A(b2)[:, None, :]
-    y = _constrain(y, ep_axis, None, None)
-    out = jnp.einsum("tec,ecd->td", combine, y)
-    return out, aux.astype(x.dtype)
+def moe_mlp(x, gate_w, w1, b1, w2, b2, *, top_k, lo=0, activation="gelu",
+            gated=False, norm_topk_prob=None):
+    """``moe_forward`` as an eager op: (out [T, D], aux_loss scalar)."""
+    out, aux, _ = moe_forward(
+        x, gate_w, w1, b1, w2, b2, top_k=top_k, lo=lo,
+        activation=activation, gated=gated, norm_topk_prob=norm_topk_prob)
+    return out, aux
 
 
 class MoELayer(Layer):
     """MoE feed-forward block (reference moe_layer.py:260 MoELayer).
 
-    Experts are a single stacked parameter set evaluated as batched einsum
-    (the reference keeps a python list of Expert sublayers and loops; on TPU
-    that serializes the MXU, so we stack). Expert weights are sharded over
-    `ep_axis` (a mesh axis name; defaults to "dp", mirroring the reference's
-    moe_group over data-parallel ranks).
+    ``num_experts`` is the router's width; ``experts_held`` (a range,
+    default all of them) says which of them live here, and only those
+    have weights: stacked [held, ...] parameters, not a list of Expert
+    sublayers. ``gate="switch"`` is top-1, as in the reference's gates.
+    ``gated`` makes each expert a SwiGLU-style pair (w1 holds gate and
+    up side by side); ``bias=False`` drops b1 / b2.
 
-    After forward, `self.aux_loss` holds the load-balancing loss tensor —
-    add `moe.aux_loss * coeff` to the training loss (the reference returns
-    it through its gate object the same way).
+    After forward, ``self.aux_loss`` holds the load-balancing loss
+    tensor: add ``moe.aux_loss * coeff`` to the training loss (the
+    reference returns it through its gate object the same way).
     """
 
     def __init__(self, d_model, d_hidden, num_experts, top_k=2,
-                 capacity_factor=1.25, gate="gshard", activation="gelu",
-                 ep_axis="dp", name=None):
+                 gate="gshard", activation="gelu", gated=False, bias=True,
+                 norm_topk_prob=None, experts_held=None, dtype=None,
+                 name=None):
         super().__init__()
         if gate == "switch":
             top_k = 1
-        elif gate == "naive":
-            capacity_factor = float(num_experts)  # no drops
+        held = range(num_experts) if experts_held is None else experts_held
+        if held.step != 1 or not 0 <= held.start < held.stop <= num_experts:
+            raise ValueError("experts_held must be a contiguous range "
+                             "inside range(%d), got %r"
+                             % (num_experts, held))
         self.d_model = d_model
         self.d_hidden = d_hidden
         self.num_experts = num_experts
+        self.experts_held = held
         self.top_k = top_k
-        self.capacity_factor = capacity_factor
         self.activation = activation
-        self.ep_axis = ep_axis
+        self.gated = gated
+        self.norm_topk_prob = norm_topk_prob
+        n, wide = len(held), d_hidden * (2 if gated else 1)
+        init = I.XavierNormal()
         self.gate_weight = self.create_parameter(
-            [d_model, num_experts], default_initializer=I.XavierNormal())
+            [d_model, num_experts], dtype=dtype, default_initializer=init)
         self.w1 = self.create_parameter(
-            [num_experts, d_model, d_hidden],
-            default_initializer=I.XavierNormal())
-        self.b1 = self.create_parameter([num_experts, d_hidden], is_bias=True)
+            [n, d_model, wide], dtype=dtype, default_initializer=init)
         self.w2 = self.create_parameter(
-            [num_experts, d_hidden, d_model],
-            default_initializer=I.XavierNormal())
-        self.b2 = self.create_parameter([num_experts, d_model], is_bias=True)
-        self.w1._sharding_spec = P(ep_axis, None, None)
-        self.b1._sharding_spec = P(ep_axis, None)
-        self.w2._sharding_spec = P(ep_axis, None, None)
-        self.b2._sharding_spec = P(ep_axis, None)
+            [n, d_hidden, d_model], dtype=dtype, default_initializer=init)
+        self.b1 = self.b2 = None
+        if bias:
+            self.b1 = self.create_parameter([n, wide], dtype=dtype,
+                                            is_bias=True)
+            self.b2 = self.create_parameter([n, d_model], dtype=dtype,
+                                            is_bias=True)
         self.aux_loss = None
-
-    def capacity(self, num_tokens):
-        return max(1, int(math.ceil(
-            self.capacity_factor * num_tokens * self.top_k
-            / self.num_experts)))
 
     def forward(self, x):
         shape = x.shape
-        d = shape[-1]
-        tokens = 1
-        for s in shape[:-1]:
-            tokens *= s
-        x2 = x.reshape([tokens, d])
+        x2 = x.reshape([-1, shape[-1]])
         out, aux = moe_mlp(
             x2, self.gate_weight, self.w1, self.b1, self.w2, self.b2,
-            top_k=self.top_k, capacity=self.capacity(tokens),
-            ep_axis=self.ep_axis, activation=self.activation)
+            top_k=self.top_k, lo=self.experts_held.start,
+            activation=self.activation, gated=self.gated,
+            norm_topk_prob=self.norm_topk_prob)
         self.aux_loss = aux
         return out.reshape(shape)
 
@@ -198,8 +198,9 @@ class MoELayer(Layer):
 # global_scatter/global_gather (operators/collective/global_scatter_op.cc).
 # TPU deviation: XLA all-to-all moves equal-size splits; the reference's
 # variable-count protocol (exchange counts, then ragged payloads) has no
-# static-shape analog. Equal per-expert capacity is therefore required —
-# which is how the dense MoE dispatch above lays tokens out anyway.
+# static-shape analog, so equal splits are required. The expert layer
+# above does not call these: it computes its own experts' share and
+# leaves the exchange between chips to a later PR (ROADMAP R1).
 # ---------------------------------------------------------------------------
 
 def global_scatter(x, group=None):

@@ -4,11 +4,15 @@ The training side of this framework compiles ONE XLA program per
 (model, config) and streams batches through it; this package gives
 inference the same shape discipline under serving traffic:
 
-- ``kv_cache``: a block-paged KV cache — a fixed pool of
-  ``[num_blocks, block_size, kv_heads, head_dim]`` pages per layer,
-  per-request block tables, and a host-side allocator with an explicit
-  out-of-blocks signal (the vLLM/Ragged-Paged-Attention memory model,
-  PAPERS.md arxiv 2604.15464).
+- ``kv_cache``: the cache, one kind a layer as the model's
+  ``paged_cache_spec()`` lists them. ``KVPages``: a block-paged KV
+  cache — a fixed pool of ``[num_blocks, block_size, kv_heads,
+  head_dim]`` pages per layer, per-request block tables, and a
+  host-side allocator with an explicit out-of-blocks signal (the
+  vLLM/Ragged-Paged-Attention memory model, PAPERS.md arxiv
+  2604.15464). ``SlotState``: fixed arrays indexed by slot (a recurrent
+  state, a convolution tail), reset by the prefill that takes the
+  slot.
 - ``kernels.paged_attention``: a Pallas ragged paged-attention decode
   kernel (one query token per slot; the pools stay in HBM and the
   kernel fetches a slot's live pages through the block table, a group
@@ -41,6 +45,11 @@ from .engine import (  # noqa: F401
     Engine,
     QueueFullError,
 )
-from .kv_cache import BlockAllocator, PagedKVCache  # noqa: F401
+from .kv_cache import (  # noqa: F401
+    BlockAllocator,
+    KVPages,
+    PagedKVCache,
+    SlotState,
+)
 from .prefix_cache import RadixPrefixCache  # noqa: F401
 from .scheduler import Request, RequestState, Scheduler  # noqa: F401

@@ -26,10 +26,11 @@ compile-once contract holds as ``decode_compiles == 1`` for the mixed
 step. Both off: every compiled function, shape and output below is
 bit-identical to the tier-1 engine (test-pinned).
 
-The engine OWNS the cache: models expose a per-layer external-cache
-attention hook (a cache object with ``update_and_attend``,
-serving/kv_cache.py views) and a ``paged_cache_spec()`` describing
-their KV geometry — the model never allocates or stores KV state.
+The engine OWNS the cache: a model's ``paged_cache_spec()`` lists, one
+entry a layer, what a slot holds there (serving/kv_cache.py: K/V pages
+or fixed slot-indexed state), and its layers take a per-layer hook
+object (``update_and_attend`` for pages, ``read``/``write`` for state)
+— the model never allocates or stores cache state.
 
 Greedy decoding (argmax, matching GenerationMixin.generate's
 ``do_sample=False`` semantics token-for-token) — the parity contract
@@ -49,12 +50,7 @@ import numpy as np
 from .. import monitor as _monitor
 from ..distributed import mesh as _mesh
 from ..resilience import faultinject as _fi
-from .kv_cache import (
-    PagedDecodeView,
-    PagedKVCache,
-    PagedMixedView,
-    PagedPrefillView,
-)
+from .kv_cache import KVBlockPool, PagedKVCache, PagedMixedView
 from . import replay as _replay
 from .metrics import EngineMetrics, now, span
 from .scheduler import Request, RequestState, Scheduler
@@ -172,15 +168,32 @@ class Engine:
         self.quant_weights = bool(
             _flags.flag("FLAGS_serving_quant_weights"))
         self.cache = PagedKVCache(
-            num_layers=spec["num_layers"], num_blocks=num_blocks,
-            block_size=block_size, num_kv_heads=spec["num_kv_heads"],
-            head_dim=spec["head_dim"], max_slots=max_slots,
-            max_blocks_per_slot=mb, dtype=spec.get("dtype", "float32"),
+            spec, num_blocks=num_blocks, block_size=block_size,
+            max_slots=max_slots, max_blocks_per_slot=mb,
             quantized=self.quant_kv)
-        # int8 bytes one page's k+v planes hold — the dequant-bytes
-        # accounting unit for serving_quant_dequant_bytes_total
-        self._quant_page_bytes = (2 * block_size * spec["num_kv_heads"]
-                                  * spec["head_dim"])
+        if self.cache.has_slot_state:
+            # a slot_state layer's row cannot be adopted from a shared
+            # prefix, fed a chunk at a time or quantized yet: refuse,
+            # rather than serve such a model wrongly
+            for flag in ("FLAGS_serving_prefix_cache",
+                         "FLAGS_serving_chunked_prefill",
+                         "FLAGS_serving_quant_kv"):
+                if _flags.flag(flag):
+                    raise ValueError(
+                        "%s with a slot_state cache layer (%s): a "
+                        "slot's recurrent state is rebuilt by a whole "
+                        "prefill, and cannot be adopted from a cached "
+                        "prefix, chunked or quantized yet"
+                        % (flag, type(model).__name__))
+        # int8 bytes one page's k+v planes hold over every layer — the
+        # dequant-bytes accounting unit for
+        # serving_quant_dequant_bytes_total
+        self._quant_page_bytes = sum(
+            2 * block_size * layer.num_kv_heads * layer.head_dim
+            for layer in spec if layer.kind == "kv_pages")
+        # expert layers whose step counters the model hands back with
+        # the tokens (models that declare them only)
+        self._moe_layers = int(getattr(model, "moe_layers", 0))
         self.prefix_cache = None
         if _flags.flag("FLAGS_serving_prefix_cache"):
             from .prefix_cache import RadixPrefixCache
@@ -194,6 +207,8 @@ class Engine:
         self.scheduler = Scheduler(max_slots, self.cache,
                                    self.prefix_cache)
         self.metrics = EngineMetrics(max_slots)
+        self.metrics.moe_experts_held = int(
+            getattr(model, "moe_experts_held", 0))
         # memory plane (monitor/memory.py, FLAGS_monitor_memory),
         # LATCHED HERE like the tier-2 flags: the step hot path only
         # ever checks the handle. None = flags-off, bit-identical.
@@ -302,6 +317,10 @@ class Engine:
             cache = s.cache
             entries = []
             for i, pool in enumerate(cache.pools):
+                if not isinstance(pool, KVBlockPool):
+                    entries.extend(("state_pool/layer%d/%s" % (i, name), a)
+                                   for name, a in pool.items())
+                    continue
                 entries.append(("kv_pool/layer%d/k" % i, pool.k))
                 entries.append(("kv_pool/layer%d/v" % i, pool.v))
                 if pool.k_scale is not None:
@@ -565,7 +584,10 @@ class Engine:
         return tid, phases
 
     def stats(self):
-        return self.metrics.to_dict()
+        out = self.metrics.to_dict()
+        out["state"] = (self.cache.state_stats()
+                        if self.cache.has_slot_state else None)
+        return out
 
     def request_status(self, rid):
         """Terminal-status view of one request: state + machine-readable
@@ -745,6 +767,11 @@ class Engine:
                     fn, self._state_vals, self.cache.pools, *args)
             self.cache.pools = new_pools
             with span("serving.readback"):
+                if self._moe_layers:
+                    tok = np.asarray(tok)
+                    self.metrics.on_moe_call(tok[1:], len(feed), P,
+                                             decode=False)
+                    tok = tok[0]
                 tok = int(tok)
             # the token is on the host: the prefill's end, and the
             # token's stamp
@@ -884,6 +911,9 @@ class Engine:
         with span("serving.accept"):
             self.metrics.on_decode_step(len(active))
             self._note_quant_step()
+            if self._moe_layers:
+                self.metrics.on_moe_call(out[self.max_slots:], len(active),
+                                         self.max_slots, decode=True)
             for slot, req in active:
                 # the input token's K/V row landed at position seq_len
                 self.cache.seq_lens[slot] += 1
@@ -955,7 +985,7 @@ class Engine:
                          for n in self.cache.seq_lens if n)
         self.metrics.on_quant_step(
             alloc.usable_blocks - alloc.free_blocks,
-            read_pages * self._quant_page_bytes * len(self.cache.pools))
+            read_pages * self._quant_page_bytes)
 
     def _on_decode_failure(self, active, exc):
         """A batched decode raised. With ONE active request the poison
@@ -1177,14 +1207,28 @@ class Engine:
         self.metrics.on_prefill_compile()       # trace-time counter
         with self.model.bind_state(self._names, list(state_vals)):
             with no_grad():
-                views = [PagedPrefillView(p, table_row, self.block_size)
-                         for p in pools]
+                views = self.cache.prefill_views(pools, table_row,
+                                                 true_len)
                 logits, views = self.model.generate_step(
                     Tensor(ids), views, 0)
         lv = logits._value if isinstance(logits, Tensor) else logits
         last = lv[0, true_len - 1].astype(jnp.float32)
         tok = jnp.argmax(last, axis=-1).astype(jnp.int32)
-        return tok, [v.pool for v in views]
+        return self._with_moe_counters(tok), [v.pool for v in views]
+
+    def _with_moe_counters(self, tokens):
+        """The step's tokens, and behind them for a model that declares
+        expert layers the counters of the step being traced, flat int32
+        [3 * layers] (pairs routed here, experts that received a row,
+        the largest expert load; a layer after a layer): they ride back
+        to the host in the step's one readback. A model that declares
+        none gets its tokens as they are, and its compiled steps are
+        what they were."""
+        if not self._moe_layers:
+            return tokens
+        return jnp.concatenate([
+            tokens.reshape(-1),
+            self.model.moe_step_stats().reshape(-1).astype(jnp.int32)])
 
     def _decode_fn(self, state_vals, pools, tokens, block_tables,
                    seq_lens):
@@ -1195,15 +1239,14 @@ class Engine:
         with self.model.bind_state(self._names,
                                    self._dequant_state(state_vals)):
             with no_grad():
-                views = [PagedDecodeView(p, block_tables, seq_lens,
-                                         self.block_size)
-                         for p in pools]
+                views = self.cache.decode_views(pools, block_tables,
+                                                seq_lens)
                 logits, views = self.model.generate_step(
                     Tensor(tokens[:, None]), views, seq_lens)
         lv = logits._value if isinstance(logits, Tensor) else logits
         nxt = jnp.argmax(lv[:, -1, :].astype(jnp.float32),
                          axis=-1).astype(jnp.int32)
-        return nxt, [v.pool for v in views]
+        return self._with_moe_counters(nxt), [v.pool for v in views]
 
     def _suffix_prefill_fn(self, state_vals, pools, ids, table_row,
                            hist, true_len):

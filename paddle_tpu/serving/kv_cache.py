@@ -1,4 +1,29 @@
-"""Block-paged KV cache: fixed page pools + per-request block tables.
+"""The serving cache: what every layer keeps for a slot, by layer kind.
+
+A model's ``paged_cache_spec()`` is a list with one entry a layer, and
+an entry's kind says what a slot holds there, how it grows, and what
+release and preemption do to it:
+
+``KVPages`` (kind ``kv_pages``)
+    K/V pages of ``block_size`` tokens at the layer's own
+    ``num_kv_heads`` x ``head_dim``. A slot holds the pages its block
+    table row names; it grows by a page every ``block_size`` tokens,
+    from the ONE allocator every kv_pages layer shares (a page id means
+    the same page in every such layer's pool); release returns the
+    pages; a preempted request re-prefills into new ones.
+``SlotState`` (kind ``slot_state``)
+    fixed arrays indexed by slot (a recurrent state, a convolution
+    tail). A slot holds row ``slot`` of each; it never grows and takes
+    nothing from the allocator; release does nothing, because the
+    prefill that next takes the slot overwrites its row (so does the
+    re-prefill of a preempted request: the state is rebuilt, never
+    saved). The prefix cache and the chunked mixed step cannot adopt or
+    chunk such a state yet, and the engine refuses them for a model
+    that has one.
+
+The rest of this docstring is the kv_pages kind.
+
+Block-paged KV cache: fixed page pools + per-request block tables.
 
 Memory model (Ragged Paged Attention / vLLM, PAPERS.md arxiv
 2604.15464): each layer owns a fixed pool of
@@ -45,6 +70,23 @@ import jax.numpy as jnp
 import numpy as np
 
 TRASH_BLOCK = 0
+
+
+class KVPages(NamedTuple):
+    """One layer's entry of a cache spec, kind ``kv_pages``."""
+
+    num_kv_heads: int
+    head_dim: int
+    dtype: str = "float32"
+    kind = "kv_pages"
+
+
+class SlotState(NamedTuple):
+    """One layer's entry of a cache spec, kind ``slot_state``:
+    ``arrays`` is ((name, shape of one slot's row, dtype), ...)."""
+
+    arrays: tuple
+    kind = "slot_state"
 
 
 class KVBlockPool(NamedTuple):
@@ -136,33 +178,77 @@ class BlockAllocator:
 
 
 class PagedKVCache:
-    """Pools for every layer + the host-side table/length bookkeeping."""
+    """One pool a layer (``KVBlockPool`` for a kv_pages layer, a dict
+    of [max_slots, ...] arrays for a slot_state layer) + the host-side
+    table/length bookkeeping + the one allocator."""
 
-    def __init__(self, num_layers, num_blocks, block_size, num_kv_heads,
-                 head_dim, max_slots, max_blocks_per_slot,
-                 dtype="float32", quantized=False):
-        dt = jnp.dtype("int8") if quantized else jnp.dtype(dtype)
+    def __init__(self, layers, num_blocks, block_size, max_slots,
+                 max_blocks_per_slot, quantized=False):
+        self.layers = list(layers)
         self.block_size = block_size
         self.max_slots = max_slots
         self.max_blocks_per_slot = max_blocks_per_slot
         self.quantized = bool(quantized)
-        page = (num_blocks, block_size, num_kv_heads, head_dim)
-        # zero scales x zero int8 pages dequantize to exact zeros, so
-        # trash/idle reads match the fp32 zero-init pools bit-for-bit
-        scale = ((num_blocks, block_size, num_kv_heads)
-                 if quantized else None)
-        self.pools = [
-            KVBlockPool(
-                jnp.zeros(page, dt), jnp.zeros(page, dt),
-                jnp.zeros(scale, jnp.float32) if quantized else None,
-                jnp.zeros(scale, jnp.float32) if quantized else None)
-            for _ in range(num_layers)]
+        self.has_slot_state = any(
+            spec.kind == "slot_state" for spec in self.layers)
+        self.num_blocks = num_blocks
+        self.pools = [self._new_pool(spec) for spec in self.layers]
         self.allocator = BlockAllocator(num_blocks)
-        self.block_tables = np.zeros((max_slots, max_blocks_per_slot),
-                                     np.int32)
+        # with a slot_state layer a row's last column is the slot's own
+        # index: a prefill is told its page-table row and nothing else,
+        # and that is how it learns which slot's state to write
+        self.block_tables = np.zeros(
+            (max_slots, max_blocks_per_slot + self.has_slot_state),
+            np.int32)
+        if self.has_slot_state:
+            self.block_tables[:, -1] = np.arange(max_slots)
         self.seq_lens = np.zeros((max_slots,), np.int32)
         self._slot_pages = [[] for _ in range(max_slots)]
         self.cow_clones = 0             # copy-on-write page splits
+
+    def _new_pool(self, spec):
+        if spec.kind == "slot_state":
+            return {name: jnp.zeros((self.max_slots,) + tuple(shape),
+                                    jnp.dtype(dtype))
+                    for name, shape, dtype in spec.arrays}
+        dt = jnp.dtype("int8") if self.quantized else jnp.dtype(spec.dtype)
+        page = (self.num_blocks, self.block_size, spec.num_kv_heads,
+                spec.head_dim)
+        # zero scales x zero int8 pages dequantize to exact zeros, so
+        # trash/idle reads match the fp32 zero-init pools bit-for-bit
+        scale = page[:3]
+        return KVBlockPool(
+            jnp.zeros(page, dt), jnp.zeros(page, dt),
+            jnp.zeros(scale, jnp.float32) if self.quantized else None,
+            jnp.zeros(scale, jnp.float32) if self.quantized else None)
+
+    def state_stats(self):
+        """The slot_state side, for ``Engine.stats()["state"]``."""
+        pool_bytes = sum(a.nbytes for p in self.pools
+                         if not isinstance(p, KVBlockPool)
+                         for a in p.values())
+        return {"slots": self.max_slots,
+                "layers": sum(spec.kind == "slot_state"
+                              for spec in self.layers),
+                "slot_bytes": pool_bytes // self.max_slots,
+                "pool_bytes": pool_bytes}
+
+    # -- the per-layer hooks of one compiled step (called in its trace)
+
+    def prefill_views(self, pools, table_row, true_len):
+        return [PagedPrefillView(p, table_row, self.block_size)
+                if spec.kind == "kv_pages"
+                else StatePrefillView(p, table_row[-1], true_len)
+                for spec, p in zip(self.layers, pools)]
+
+    def decode_views(self, pools, block_tables, seq_lens):
+        if self.has_slot_state:
+            # the page kernels take the page columns, not the slot's
+            block_tables = block_tables[:, :self.max_blocks_per_slot]
+        return [PagedDecodeView(p, block_tables, seq_lens, self.block_size)
+                if spec.kind == "kv_pages"
+                else StateDecodeView(p, seq_lens > 0)
+                for spec, p in zip(self.layers, pools)]
 
     def pages_needed(self, num_tokens):
         return -(-num_tokens // self.block_size)  # ceil
@@ -251,6 +337,7 @@ class PagedKVCache:
                     **({} if p.k_scale is None else {
                         "k_scale": p.k_scale.at[d].set(p.k_scale[s]),
                         "v_scale": p.v_scale.at[d].set(p.v_scale[s])}))
+                if isinstance(p, KVBlockPool) else p
                 for p in self.pools]
         return ok
 
@@ -262,7 +349,7 @@ class PagedKVCache:
         if self._slot_pages[slot]:
             self.allocator.free(self._slot_pages[slot])
         self._slot_pages[slot] = []
-        self.block_tables[slot, :] = TRASH_BLOCK
+        self.block_tables[slot, :self.max_blocks_per_slot] = TRASH_BLOCK
         self.seq_lens[slot] = 0
 
     def pools_alive(self):
@@ -271,9 +358,11 @@ class PagedKVCache:
         (``donate_argnums``), so a step that raises AFTER execution
         started leaves these arrays deleted — readable shape/dtype,
         unreadable data."""
+        import jax
+
         try:
-            return not any(p.k.is_deleted() or p.v.is_deleted()
-                           for p in self.pools)
+            return not any(a.is_deleted()
+                           for a in jax.tree_util.tree_leaves(self.pools))
         except AttributeError:      # non-jax pools (unit fixtures)
             return True
 
@@ -286,13 +375,12 @@ class PagedKVCache:
         re-prefills from host-side tokens, so nothing durable lived
         only in the pools) and then rebuilds the plane here. Shapes
         and dtypes survive a deleted jax array, so the new pools match
-        the compiled steps' signatures exactly — no retrace."""
-        self.pools = [
-            KVBlockPool(*[None if x is None
-                          else jnp.zeros(x.shape, x.dtype) for x in p])
-            for p in self.pools]
-        self.allocator = BlockAllocator(int(self.pools[0].k.shape[0]))
-        self.block_tables[:] = TRASH_BLOCK
+        the compiled steps' signatures exactly — no retrace. A
+        slot_state layer's rows are zeroed with the rest; the re-prefill
+        of each requeued slot writes its row anew."""
+        self.pools = [self._new_pool(spec) for spec in self.layers]
+        self.allocator = BlockAllocator(self.num_blocks)
+        self.block_tables[:, :self.max_blocks_per_slot] = TRASH_BLOCK
         self.seq_lens[:] = 0
         self._slot_pages = [[] for _ in range(self.max_slots)]
 
@@ -435,3 +523,52 @@ class PagedMixedView:
         return Tensor(out), PagedMixedView(
             new_pool, self.block_tables, self.hist_lens, self.q_lens,
             self.block_size)
+
+
+class StatePrefillView:
+    """A slot_state layer's hook for single-request prefill. The layer
+    starts from ``read()`` (a zero row: the prefill that takes a slot
+    resets it), runs its ``valid_len`` real tokens (the rows past them
+    are padding and must leave the state as it was), and hands the
+    arrays it ends with to ``write``, which stores them in row ``slot``
+    of the pool."""
+
+    def __init__(self, pool, slot, valid_len):
+        self.pool = pool                      # {name: [S, ...]}
+        self.slot = slot                      # traced int32 scalar
+        self.valid_len = valid_len            # traced int32 scalar
+
+    def read(self):
+        return {name: jnp.zeros((1,) + a.shape[1:], a.dtype)
+                for name, a in self.pool.items()}
+
+    def write(self, arrays):
+        pool = {name: a.at[self.slot].set(
+            arrays[name][0].astype(a.dtype))
+            for name, a in self.pool.items()}
+        return StatePrefillView(pool, self.slot, self.valid_len)
+
+
+class StateDecodeView:
+    """A slot_state layer's hook for the batched decode step: ``read()``
+    is every slot's row, ``write`` stores the rows of the ``active``
+    slots and leaves an idle slot's row as it was. ``valid_len`` is
+    None: every row is one real token."""
+
+    valid_len = None
+
+    def __init__(self, pool, active):
+        self.pool = pool                      # {name: [S, ...]}
+        self.active = active                  # [S] bool
+
+    def read(self):
+        return self.pool
+
+    def write(self, arrays):
+        def keep(new, old):
+            on = self.active.reshape((-1,) + (1,) * (old.ndim - 1))
+            return jnp.where(on, new.astype(old.dtype), old)
+
+        return StateDecodeView(
+            {name: keep(arrays[name], a) for name, a in self.pool.items()},
+            self.active)

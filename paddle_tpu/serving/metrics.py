@@ -41,7 +41,20 @@ here, ``to_dict()`` reduces them when asked):
       tokens of one request, each stamped when its step's tokens reached
       the host (a prefill's token and the same step's first decode token
       get their own two stamps)
-An empty ring gives ``None`` for its entry, never 0.
+  moe            for a model that declares expert layers
+      (``model.moe_layers``): the counters every compiled step hands
+      back behind its tokens, a layer at a time (pairs routed to the
+      experts held here, held experts that received a row, the largest
+      load of one expert). ``pairs``, ``experts_touched``, ``load_max``
+      and ``load_max_over_mean`` (largest load over pairs / experts
+      held) are means over the recent decode steps and their layers;
+      ``calls`` lists the recent programs, prefills included, newest
+      last, as [real token rows, rows the program ran (a prefill's
+      bucket, every slot of a decode step), 1 for a decode step, pairs
+      a layer, experts touched a layer] for the kernel's roofline
+      reader
+An empty ring gives ``None`` for its entry, never 0. (``state``, the
+slot_state side of the cache, is added by ``Engine.stats()``.)
 
 Spans: ``span(name, **meta)`` IS ``jax.profiler.TraceAnnotation`` — a
 span lands in the profiler's trace, on the device trace's clock, and
@@ -61,6 +74,7 @@ import statistics
 import time
 
 import jax
+import numpy as np
 
 from ..monitor import counter as _mcounter
 from ..monitor import gauge as _mgauge
@@ -179,6 +193,7 @@ HOST_PHASES = ("schedule", "upload", "dispatch", "readback", "accept")
 STEP_RING = 512
 PREFILL_RING = 512
 GAP_RING = 16384
+MOE_RING = 2048
 
 
 def _percentile(ordered, q):
@@ -352,6 +367,10 @@ class EngineMetrics:
         self.steps = collections.deque(maxlen=STEP_RING)
         self.prefills = collections.deque(maxlen=PREFILL_RING)
         self.token_gaps = collections.deque(maxlen=GAP_RING)
+        # one row a compiled step of a model with expert layers:
+        # (real rows, rows run, int32 [layers, 3] counters, decode step?)
+        self.moe_calls = collections.deque(maxlen=MOE_RING)
+        self.moe_experts_held = 0
 
     # -- engine hooks (mirror every sample into the shared registry) ---
 
@@ -454,6 +473,37 @@ class EngineMetrics:
 
     def on_prefill_done(self, seconds, tokens, bucket):
         self.prefills.append((seconds, tokens, bucket))
+
+    def on_moe_call(self, counters, rows, rows_run, decode):
+        """``counters``: the flat int32 [3 * layers] a step returned
+        behind its tokens; ``rows``: its real token rows; ``rows_run``:
+        the rows the program ran (a prefill's bucket, every slot of a
+        decode step)."""
+        self.moe_calls.append(
+            (int(rows), int(rows_run),
+             np.asarray(counters).reshape(-1, 3), bool(decode)))
+
+    def _moe_dict(self):
+        calls = list(self.moe_calls)
+        steps = [c for _, _, c, decode in calls if decode]
+        if not steps:
+            return None
+        held = max(self.moe_experts_held, 1)
+        per = np.stack(steps).astype(np.float64)        # [n, layers, 3]
+        pairs, touched, largest = per[..., 0], per[..., 1], per[..., 2]
+        return {
+            "layers": int(per.shape[1]),
+            "experts_held": self.moe_experts_held,
+            "pairs": float(pairs.mean()),
+            "experts_touched": float(touched.mean()),
+            "load_max": float(largest.mean()),
+            "load_max_over_mean": float(
+                (largest / np.maximum(pairs / held, 1e-9)).mean()),
+            "recent_steps": len(steps),
+            "calls": [[rows, rows_run, int(decode), c[:, 0].tolist(),
+                       c[:, 1].tolist()]
+                      for rows, rows_run, c, decode in calls],
+        }
 
     def on_decode_compile(self):
         self.decode_compiles += 1
@@ -571,4 +621,5 @@ class EngineMetrics:
             "itl_ms": ({"p50": 1e3 * _percentile(gaps, 0.50),
                         "p95": 1e3 * _percentile(gaps, 0.95)}
                        if gaps else None),
+            "moe": self._moe_dict(),
         }

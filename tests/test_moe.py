@@ -1,4 +1,4 @@
-"""MoE / expert-parallel tests.
+"""MoE tests: the dropless expert layer (parallel/moe.py).
 
 Oracle: explicit loop-over-experts numpy computation. Mirrors the
 reference's moe tests (unittests for moe_layer / global_scatter)."""
@@ -10,7 +10,7 @@ import jax.numpy as jnp
 
 import paddle_tpu as paddle
 from paddle_tpu.distributed import mesh as pmesh
-from paddle_tpu.parallel.moe import MoELayer, moe_mlp
+from paddle_tpu.parallel.moe import MoELayer, moe_forward, moe_mlp
 
 RNG = np.random.RandomState(3)
 
@@ -29,6 +29,25 @@ def _dense_moe_top1(x, gate_w, w1, b1, w2, b2, act=np.tanh):
     return out
 
 
+def _dense_moe_gated(x, gate_w, w1, w2, k, held):
+    """Renormalised top-k over all experts; only the experts in ``held``
+    contribute (their weights sit at index e - held[0])."""
+    logits = x @ gate_w
+    probs = np.exp(logits - logits.max(-1, keepdims=True))
+    probs /= probs.sum(-1, keepdims=True)
+    f = w2.shape[1]
+    out = np.zeros_like(x)
+    for t in range(x.shape[0]):
+        top = np.argsort(-probs[t], kind="stable")[:k]
+        for e in top:
+            if e not in held:
+                continue
+            a = x[t] @ w1[e]
+            hid = a[:f] / (1 + np.exp(-a[:f])) * a[f:]
+            out[t] += probs[t, e] / probs[t, top].sum() * (hid @ w2[e])
+    return out
+
+
 class TestMoEPrimitive:
     def test_top1_matches_dense_oracle(self):
         t, d, h, e = 32, 8, 16, 4
@@ -41,15 +60,14 @@ class TestMoEPrimitive:
         out, aux = moe_mlp(
             jnp.asarray(x), jnp.asarray(gate_w), jnp.asarray(w1),
             jnp.asarray(b1), jnp.asarray(w2), jnp.asarray(b2),
-            top_k=1, capacity=t, ep_axis="dp", activation="relu")
+            top_k=1, activation="relu")
         ref = _dense_moe_top1(x, gate_w, w1, b1, w2, b2)
         np.testing.assert_allclose(np.asarray(out._value), ref,
                                    rtol=1e-4, atol=1e-5)
         assert float(aux._value) > 0
 
     def test_top2_combine_weights_renormalized(self):
-        """With capacity >= tokens (no drops) the top-2 combine weights for
-        each token must sum to 1."""
+        """The top-2 combine weights of each token sum to 1."""
         t, d, h, e = 16, 8, 8, 4
         x = jnp.asarray(RNG.randn(t, d).astype(np.float32))
         gate_w = jnp.asarray(RNG.randn(d, e).astype(np.float32))
@@ -59,28 +77,139 @@ class TestMoEPrimitive:
         b1 = jnp.ones((e, h), jnp.float32)
         w2 = jnp.zeros((e, h, d), jnp.float32)
         b2 = jnp.ones((e, d), jnp.float32)
-        out, _ = moe_mlp(x, gate_w, w1, b1, w2, b2, top_k=2, capacity=2 * t,
-                         ep_axis="dp", activation="relu")
+        out, _ = moe_mlp(x, gate_w, w1, b1, w2, b2, top_k=2,
+                         activation="relu")
         # each expert outputs the all-ones vector, so out = (g1+g2) * ones
         np.testing.assert_allclose(np.asarray(out._value),
                                    np.ones((t, d), np.float32),
                                    rtol=1e-5, atol=1e-5)
 
-    def test_capacity_drops_tokens(self):
-        """capacity=1 forces drops: total output mass strictly less than
-        the no-drop case."""
-        t, d, h, e = 32, 8, 8, 2
-        x = jnp.asarray(RNG.randn(t, d).astype(np.float32))
-        gate_w = jnp.asarray(RNG.randn(d, e).astype(np.float32))
+    @pytest.mark.parametrize("top_k", [1, 2])
+    def test_no_token_is_dropped_whatever_the_skew(self, top_k):
+        """The capacity dispatch dropped what overflowed an expert's
+        buffer (this test used to pin that: capacity=1 lost output
+        mass). The dropless layer has no buffer: with a router that
+        sends EVERY token to expert 0 first, every token still gets its
+        whole combine weight, and expert 0's load is all the tokens."""
+        t, d, h, e = 32, 8, 8, 4
+        x = jnp.asarray(np.abs(RNG.randn(t, d)).astype(np.float32))
+        gate_w = jnp.zeros((d, e), jnp.float32).at[:, 0].set(4.0)
         w1 = jnp.zeros((e, d, h), jnp.float32)
         b1 = jnp.ones((e, h), jnp.float32)
         w2 = jnp.zeros((e, h, d), jnp.float32)
         b2 = jnp.ones((e, d), jnp.float32)
-        full, _ = moe_mlp(x, gate_w, w1, b1, w2, b2, top_k=1, capacity=t,
-                          ep_axis="dp", activation="relu")
-        capped, _ = moe_mlp(x, gate_w, w1, b1, w2, b2, top_k=1, capacity=1,
-                            ep_axis="dp", activation="relu")
-        assert float(jnp.sum(capped._value)) < float(jnp.sum(full._value))
+        out, _, stats = moe_forward(x, gate_w, w1, b1, w2, b2, top_k=top_k,
+                                    activation="relu",
+                                    norm_topk_prob=True)
+        # each expert outputs the all-ones vector: out = sum of weights
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.ones((t, d), np.float32),
+                                   rtol=1e-5, atol=1e-5)
+        pairs, touched, largest = (int(v) for v in stats)
+        assert pairs == t * top_k and largest == t
+        assert touched == top_k
+
+    def test_topk_gated_matches_dense_oracle(self):
+        """top-4 of 16 with renormalised weights, gated experts without
+        bias (the SwiGLU form), against a loop over experts."""
+        t, d, h, e, k = 24, 8, 16, 16, 4
+        x = RNG.randn(t, d).astype(np.float32)
+        gate_w = RNG.randn(d, e).astype(np.float32)
+        w1 = RNG.randn(e, d, 2 * h).astype(np.float32) * 0.3
+        w2 = RNG.randn(e, h, d).astype(np.float32) * 0.3
+        out, _, stats = moe_forward(
+            jnp.asarray(x), jnp.asarray(gate_w), jnp.asarray(w1), None,
+            jnp.asarray(w2), None, top_k=k, activation="silu", gated=True)
+        want = _dense_moe_gated(x, gate_w, w1, w2, k, range(e))
+        np.testing.assert_allclose(np.asarray(out), want, rtol=1e-4,
+                                   atol=1e-5)
+        assert int(stats[0]) == t * k
+
+    @pytest.mark.parametrize("cuts", [(0, 8, 16), (0, 4, 12, 16)])
+    def test_shares_add_up_to_the_whole_layer(self, cuts):
+        """Each share routes over all 16 experts and computes its own
+        experts' part; the parts add up to the uncut layer, and their
+        pair counts to tokens x top_k."""
+        t, d, h, e, k = 24, 8, 16, 16, 4
+        x = jnp.asarray(RNG.randn(t, d).astype(np.float32))
+        gate_w = jnp.asarray(RNG.randn(d, e).astype(np.float32))
+        w1 = jnp.asarray(RNG.randn(e, d, 2 * h).astype(np.float32) * 0.3)
+        w2 = jnp.asarray(RNG.randn(e, h, d).astype(np.float32) * 0.3)
+        whole, _, _ = moe_forward(x, gate_w, w1, None, w2, None, top_k=k,
+                                  activation="silu", gated=True)
+        total, pairs = 0.0, 0
+        for lo, hi in zip(cuts, cuts[1:]):
+            part, _, stats = moe_forward(
+                x, gate_w, w1[lo:hi], None, w2[lo:hi], None, top_k=k,
+                lo=lo, activation="silu", gated=True)
+            want = _dense_moe_gated(np.asarray(x), np.asarray(gate_w),
+                                    np.asarray(w1), np.asarray(w2), k,
+                                    range(lo, hi))
+            np.testing.assert_allclose(np.asarray(part), want, rtol=1e-4,
+                                       atol=1e-5)
+            total = total + part
+            pairs += int(stats[0])
+        np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
+                                   rtol=1e-4, atol=1e-5)
+        assert pairs == t * k
+
+
+class TestMoeGmmKernel:
+    """kernels/moe_gmm.py in interpret mode against jax.lax.ragged_dot
+    (the rows of no group are undefined and not compared)."""
+
+    @pytest.mark.parametrize("m,k,n,sizes", [
+        (96, 64, 128, [10, 0, 50, 20]),          # an empty group, a tail
+        (256, 32, 256, [0] * 8),                 # nothing routed here
+        (640, 64, 128, [100, 0, 3, 200, 1, 0, 136, 200]),    # 128-row tiles
+        (40, 16, 24, [5, 30, 5]),                # M padded to the tile
+        (64, 16, 128, [64]),                     # one group, whole tiles
+    ])
+    def test_kernel_matches_ragged_dot(self, m, k, n, sizes):
+        from paddle_tpu.kernels import moe_gmm as mg
+
+        lhs = jnp.asarray(RNG.randn(m, k).astype(np.float32))
+        rhs = jnp.asarray(RNG.randn(len(sizes), k, n).astype(np.float32))
+        gs = jnp.asarray(sizes, jnp.int32)
+        got = mg.moe_gmm(lhs, rhs, gs, interpret=True)
+        want = jax.lax.ragged_dot(lhs, rhs, gs)
+        total = sum(sizes)
+        assert got.shape == (m, n)
+        np.testing.assert_allclose(np.asarray(got[:total]),
+                                   np.asarray(want[:total]), rtol=1e-5,
+                                   atol=1e-5)
+
+    def test_visit_list_skips_empty_groups(self):
+        from paddle_tpu.kernels import moe_gmm as mg
+
+        sizes = jnp.asarray([40, 0, 0, 30, 0, 58], jnp.int32)
+        offsets, gid, tile, visits = mg.visit_list(sizes, 128, 32)
+        n = int(visits[0])
+        # rows 0-39 | 40-69 | 70-127 over tiles of 32: group 0 touches
+        # tiles 0, 1; group 3 tiles 1, 2; group 5 tiles 2, 3
+        assert n == 6
+        assert list(zip(gid[:n].tolist(), tile[:n].tolist())) == [
+            (0, 0), (0, 1), (3, 1), (3, 2), (5, 2), (5, 3)]
+        # the tail repeats the last visit: a grid step and no DMA
+        assert gid.shape == (128 // 32 + 6 - 1,)
+        assert set(zip(gid[n:].tolist(), tile[n:].tolist())) == {(5, 3)}
+        assert offsets.tolist() == [0, 40, 40, 40, 70, 70, 128]
+
+    def test_kernel_backward_is_ragged_dots(self):
+        from paddle_tpu.kernels import moe_gmm as mg
+
+        lhs = jnp.asarray(RNG.randn(96, 16).astype(np.float32))
+        rhs = jnp.asarray(RNG.randn(3, 16, 24).astype(np.float32))
+        gs = jnp.asarray([30, 0, 66], jnp.int32)
+
+        def loss(fn):
+            return lambda a, b: jnp.sum(jnp.sin(fn(a, b, gs)))
+
+        got = jax.grad(loss(mg._gmm_with_ragged_vjp), (0, 1))(lhs, rhs)
+        want = jax.grad(loss(jax.lax.ragged_dot), (0, 1))(lhs, rhs)
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-5)
 
 
 class TestMoELayer:
@@ -106,7 +235,7 @@ class TestMoELayer:
     def test_training_reduces_loss(self):
         paddle.seed(1)
         moe = MoELayer(d_model=8, d_hidden=16, num_experts=2, top_k=1,
-                       gate="switch", capacity_factor=2.0)
+                       gate="switch")
         opt = paddle.optimizer.Adam(learning_rate=5e-3,
                                     parameters=moe.parameters())
         x = paddle.to_tensor(RNG.randn(16, 8).astype(np.float32))
@@ -124,12 +253,13 @@ class TestMoELayer:
 
 class TestMoESharded:
     def test_expert_parallel_on_mesh(self):
-        """MoE inside a jit over the 8-device mesh: expert dim sharded on
-        dp; results must match the single-device run."""
+        """MoE inside a jit over the 8-device mesh: the layer computes
+        its experts where the rows are (no exchange between chips), and
+        the result matches the single-device run."""
         mesh = pmesh.build_hybrid_mesh(dp=8, mp=1)
         paddle.seed(0)
         moe = MoELayer(d_model=16, d_hidden=32, num_experts=8, top_k=1,
-                       gate="switch", capacity_factor=8.0, ep_axis="dp")
+                       gate="switch")
         x_np = RNG.randn(32, 16).astype(np.float32)
         out_eager = moe(paddle.to_tensor(x_np)).numpy()
 

@@ -15,7 +15,8 @@ import paddle_tpu as paddle
 from paddle_tpu import serving
 from paddle_tpu.core import flags as _flags
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-from paddle_tpu.serving.kv_cache import BlockAllocator, PagedKVCache
+from paddle_tpu.serving.kv_cache import (BlockAllocator, KVPages,
+                                         PagedKVCache)
 from paddle_tpu.serving.prefix_cache import RadixPrefixCache
 from paddle_tpu.serving.scheduler import RequestState
 
@@ -129,8 +130,8 @@ class TestBlockAllocator:
 # ---------------------------------------------------------------------------
 
 def _mini_cache(num_blocks=32, block_size=4):
-    return PagedKVCache(num_layers=1, num_blocks=num_blocks,
-                        block_size=block_size, num_kv_heads=1, head_dim=8,
+    return PagedKVCache([KVPages(num_kv_heads=1, head_dim=8)],
+                        num_blocks=num_blocks, block_size=block_size,
                         max_slots=2, max_blocks_per_slot=8)
 
 

@@ -28,7 +28,8 @@ from paddle_tpu.kernels.quant import (
     weight_block,
 )
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-from paddle_tpu.serving.kv_cache import BlockAllocator, PagedKVCache
+from paddle_tpu.serving.kv_cache import (BlockAllocator, KVPages,
+                                         PagedKVCache)
 
 QUANT_COMBOS = [
     pytest.param((False, False), id="quant_off"),
@@ -221,8 +222,8 @@ class TestQuantizedKernels:
 
 class TestScalePlanes:
     def test_quantized_cache_geometry(self):
-        c = PagedKVCache(num_layers=2, num_blocks=8, block_size=4,
-                         num_kv_heads=2, head_dim=8, max_slots=2,
+        c = PagedKVCache([KVPages(num_kv_heads=2, head_dim=8)] * 2,
+                         num_blocks=8, block_size=4, max_slots=2,
                          max_blocks_per_slot=4, quantized=True)
         assert c.quantized
         for p in c.pools:
@@ -233,8 +234,8 @@ class TestScalePlanes:
         assert c.pools[0].k_scale is not None
 
     def test_fp32_cache_has_no_scale_planes(self):
-        c = PagedKVCache(num_layers=1, num_blocks=8, block_size=4,
-                         num_kv_heads=2, head_dim=8, max_slots=2,
+        c = PagedKVCache([KVPages(num_kv_heads=2, head_dim=8)],
+                         num_blocks=8, block_size=4, max_slots=2,
                          max_blocks_per_slot=4)
         assert not c.quantized
         assert c.pools[0].k.dtype == jnp.float32

@@ -245,6 +245,80 @@ def test_rings_stay_at_their_caps(llama):
     assert em.to_dict()["itl_ms"] == {"p50": 4.0, "p95": 7.0}
 
 
+def test_a_dense_model_has_no_moe_and_no_state_block(llama):
+    eng = _engine(llama)
+    _submit(eng, 3, seed=6)
+    eng.run()
+    stats = eng.stats()
+    assert stats["moe"] is None and stats["state"] is None
+    assert len(eng.metrics.moe_calls) == 0
+
+
+@pytest.fixture(scope="module")
+def qwen():
+    from paddle_tpu.models.qwen3_next import (Qwen3NextConfig,
+                                              Qwen3NextForCausalLM)
+
+    paddle.seed(0)
+    return Qwen3NextForCausalLM(Qwen3NextConfig.tiny(vocab_size=64))
+
+
+def test_stats_moe_block_counts_what_the_steps_routed(qwen):
+    """Every compiled step hands back, a layer at a time, the pairs
+    routed to the experts held here, the experts touched and the largest
+    load; ``stats()["moe"]`` reduces the recent decode steps."""
+    eng = _engine(qwen, max_slots=4, max_model_len=64)
+    assert eng.stats()["moe"] is None               # empty ring
+    _submit(eng, 6, seed=7, new_tokens=8)
+    eng.run()
+    stats = eng.stats()
+    moe = stats["moe"]
+    assert moe["layers"] == 4 and moe["experts_held"] == 8
+    assert moe["recent_steps"] == stats["decode_steps"]
+    # 4 slots x top-4 of 16 experts: at most 16 pairs a layer, on at most
+    # 8 experts held here; the largest load is at least the mean
+    assert 0 < moe["pairs"] <= 16
+    assert 0 < moe["experts_touched"] <= min(8, moe["pairs"])
+    assert 1 <= moe["load_max"] <= 4
+    assert moe["load_max_over_mean"] >= 1.0
+    # one row a program, prefills included, newest last
+    calls = moe["calls"]
+    assert len(calls) == stats["decode_steps"] + stats["prefill_runs"]
+    prefills = [c for c in calls if not c[2]]
+    assert len(prefills) == stats["prefill_runs"] == 6
+    for rows, rows_run, _, pairs, touched in prefills:
+        assert 3 <= rows <= rows_run and rows_run in (8, 16)
+        assert len(pairs) == len(touched) == 4
+        assert all(0 <= t <= 8 and t <= p <= rows_run * 4
+                   for p, t in zip(pairs, touched))
+    for rows, rows_run, decode, pairs, _ in calls:
+        if decode:
+            assert 1 <= rows <= rows_run == 4 and max(pairs) <= 16
+
+
+def test_stats_state_block_sizes_the_slot_state(qwen):
+    eng = _engine(qwen, max_slots=4, max_model_len=64)
+    state = eng.stats()["state"]
+    # per Gated DeltaNet layer: 4 heads x 8 x 8 float32 and a 3-row tail
+    # of 2 * 2 * 8 + 4 * 8 = 64 channels in float32
+    assert state == {"slots": 4, "layers": 3,
+                     "slot_bytes": 3 * (4 * 8 * 8 * 4 + 3 * 64 * 4),
+                     "pool_bytes": 4 * 3 * (4 * 8 * 8 * 4 + 3 * 64 * 4)}
+
+
+def test_moe_ring_stays_at_its_cap():
+    em = smetrics.EngineMetrics(max_slots=2)
+    em.moe_experts_held = 4
+    for i in range(smetrics.MOE_RING + 10):
+        em.on_moe_call(np.asarray([2, 2, 1, 2, 1, 2]), 2, 2, decode=True)
+    assert len(em.moe_calls) == smetrics.MOE_RING
+    moe = em.to_dict()["moe"]
+    assert moe["layers"] == 2 and moe["pairs"] == 2.0
+    assert moe["experts_touched"] == 1.5 and moe["load_max"] == 1.5
+    # (1 / (2 / 4) + 2 / (2 / 4)) / 2
+    assert moe["load_max_over_mean"] == 3.0
+
+
 def test_a_span_without_a_session_touches_no_native_code(monkeypatch):
     from paddle_tpu.core import native
 

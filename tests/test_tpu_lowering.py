@@ -29,6 +29,7 @@ import pytest
 from paddle_tpu.analysis.graph.hlo import mosaic_kernels
 from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import fused_ce as fc
+from paddle_tpu.kernels import moe_gmm as mg
 
 # the package re-exports a function under the module's name
 pa = importlib.import_module("paddle_tpu.serving.kernels.paged_attention")
@@ -44,6 +45,12 @@ S, NB, BS, MB, C = 8, 512, 16, 128, 16
 # 32 heads over 8 kv heads (benchmark/traffic/chat-backlog.json)
 CELL = "paged_decode_bf16_gqa_cell"
 CELL_S, CELL_NB, CELL_MB, CELL_H, CELL_HKV = 64, 10000, 160, 32, 8
+# qwen3next-longdoc-backlog: one paged layer at head_dim 256 with 16
+# query heads over 2 kv heads, 128 slots x 576 pages of a 72,000-page
+# pool; prefill buckets up to 8192; 256 experts held of 2048 x 512, ten
+# pairs a token (benchmark/traffic/longdoc-backlog.json)
+QWEN_S, QWEN_NB, QWEN_MB, QWEN_H, QWEN_HKV, QWEN_D = 128, 72000, 576, 16, 2, 256
+QWEN_EXPERTS, QWEN_HID, QWEN_WIDTH, QWEN_TOPK = 256, 2048, 512, 10
 
 
 def _flash(dtype, d, segmented=False):
@@ -89,6 +96,37 @@ def _cases():
                 q, k, v, bt, ln, interpret=False),
             [((s, h, D), dtype), pool, pool, ((s, mb), I32), ((s,), I32)],
             {"paged_decode"}))
+    pool = ((QWEN_NB, BS, QWEN_HKV, QWEN_D), BF16)
+    cases.append((
+        "paged_decode_bf16_d256_qwen_cell",
+        lambda q, k, v, bt, ln: pa.paged_attention_kernel(
+            q, k, v, bt, ln, interpret=False),
+        [((QWEN_S, QWEN_H, QWEN_D), BF16), pool, pool,
+         ((QWEN_S, QWEN_MB), I32), ((QWEN_S,), I32)],
+        {"paged_decode"}))
+    for tokens in (512, 8192):
+        shape = ((1, tokens, QWEN_H, QWEN_D), BF16)
+        cases.append((
+            "flash_bf16_d256_fwd_%d" % tokens,
+            lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
+                                               interpret=False),
+            [shape, shape, shape], {"flash_fwd"}))
+
+    def experts(x, w1, w2, sizes):
+        # one expert layer's two calls: gate/up, then down
+        h = mg.moe_gmm(x, w1, sizes, interpret=False)
+        h = jax.nn.silu(h[:, :QWEN_WIDTH]) * h[:, QWEN_WIDTH:]
+        return mg.moe_gmm(h, w2, sizes, interpret=False)
+
+    for name, tokens in (("moe_gmm_bf16_decode", QWEN_S),
+                         ("moe_gmm_bf16_prefill", 8192)):
+        cases.append((
+            name, experts,
+            [((tokens * QWEN_TOPK, QWEN_HID), BF16),
+             ((QWEN_EXPERTS, QWEN_HID, 2 * QWEN_WIDTH), BF16),
+             ((QWEN_EXPERTS, QWEN_WIDTH, QWEN_HID), BF16),
+             ((QWEN_EXPERTS,), I32)],
+            {"moe_gmm"}))
     for name, h, hkv in (("paged_mixed_bf16_mha", 16, 16),
                          ("paged_mixed_bf16_gqa", 32, 8)):
         pool = ((NB, BS, hkv, D), BF16)
@@ -169,6 +207,9 @@ class TestMosaicCompile:
             # partial sums, and 32 pages a group inside the VMEM limit
             assert found == {"paged_decode": 1}
             assert pa._pages_per_group(BS, CELL_HKV, D, 2, CELL_MB) == 32
+        if name.startswith("moe_gmm"):
+            # the kernel body is jitted: both calls are one kernel name
+            assert found == {"moe_gmm": 2}
 
 
 class TestInterpretNeverOnTPU:

@@ -31,10 +31,10 @@ NA_BY_DESIGN = {
     "transfer_layout": "XLA layout assignment",
     "data_transform": "jit boundary handles dtype/layout",
     # fluid legacy / infrastructure ops
-    "assign_pos": "MoE dispatch is jnp.take-based (parallel/moe.py)",
-    "number_count": "MoE capacity math is vectorized in parallel/moe.py",
-    "limit_by_capacity": "parallel/moe.py capacity mask",
-    "prune_gate_by_capacity": "parallel/moe.py capacity mask",
+    "assign_pos": "MoE rows are sorted by expert (parallel/moe.py)",
+    "number_count": "group sizes of the sorted rows (parallel/moe.py)",
+    "limit_by_capacity": "no capacity: parallel/moe.py is dropless",
+    "prune_gate_by_capacity": "no capacity: parallel/moe.py is dropless",
     "random_routing": "parallel/moe.py gates",
     "seed": "framework.random key system",
     "ftrl": "CPU PS-era optimizer; not in paddle.optimizer public API",
