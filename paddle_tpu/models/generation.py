@@ -12,7 +12,9 @@ DenseTensor caches, top_k_top_p sampling ops). TPU-first shape instead:
   while-loop with eos early-exit.
 
 A model opts in by providing:
-  generate_step(input_ids, caches, position_offset) -> (logits, caches)
+  generate_step(input_ids, caches, position_offset, logits_at=None)
+      -> (logits, caches); a caller that reads one row a sequence names
+      it in `logits_at` (int32 [B]) and gets logits [B, 1, vocab]
   init_decode_caches(batch, total_len) -> list[DecodeCache]
   functional_state() / bind_state(...)  (nn.Layer already has these)
 """
@@ -69,6 +71,23 @@ def masked_decode_attention(q, k, v, mask):
                                               _warn_rect_causal=False)
     return F.scaled_dot_product_attention(
         q, k, v, attn_mask=mask[None, None], is_causal=False)
+
+
+def rows_at(hidden, logits_at):
+    """The rows of ``hidden`` [B, T, H] that the caller will read:
+    ``logits_at`` is int32 [B] (may be traced), one row index a
+    sequence, and the result is [B, 1, H]; ``None`` reads every row.
+    A model's ``generate_step`` calls this BEFORE its final norm and
+    head, so that a prefill over a padded bucket computes them for the
+    one row whose token it takes, not for all T."""
+    from ..core.tensor import Tensor
+
+    if logits_at is None:
+        return hidden
+    hv = hidden._value if isinstance(hidden, Tensor) else hidden
+    idx = jnp.asarray(logits_at, jnp.int32).reshape(-1, 1, 1)
+    out = jnp.take_along_axis(hv, idx, axis=1)
+    return Tensor(out) if isinstance(hidden, Tensor) else out
 
 
 class GenerationMixin:

@@ -12,6 +12,7 @@ from .generation import (
     cache_update,
     decode_mask as _decode_mask,
     masked_decode_attention,
+    rows_at,
 )
 from ..nn.layer import Layer
 from ..nn.layers.common import Dropout, Embedding, Linear
@@ -118,7 +119,7 @@ class GPTModel(GenerationMixin, Layer):
         return total
 
     def forward(self, input_ids, labels=None, caches=None,
-                position_offset=0):
+                position_offset=0, logits_at=None):
         import paddle_tpu as P
 
         b, s = input_ids.shape
@@ -137,7 +138,7 @@ class GPTModel(GenerationMixin, Layer):
                 new_caches.append(c)
             else:
                 x = blk(x)
-        x = self.ln_f(x)
+        x = self.ln_f(rows_at(x, logits_at))
         logits = P.matmul(x, self.wte.weight, transpose_y=True)
         if caches is not None:
             return logits, new_caches
@@ -150,10 +151,14 @@ class GPTModel(GenerationMixin, Layer):
             return loss
         return logits
 
-    def generate_step(self, input_ids, caches, position_offset):
-        """Single decode step with functional cache (GenerationMixin)."""
+    def generate_step(self, input_ids, caches, position_offset,
+                      logits_at=None):
+        """Single decode step with functional cache (GenerationMixin);
+        ``logits_at`` (generation.rows_at) names the one row a sequence
+        to norm and project."""
         return self.forward(input_ids, caches=caches,
-                            position_offset=position_offset)
+                            position_offset=position_offset,
+                            logits_at=logits_at)
 
     def max_decode_len(self):
         return self.wpe.num_embeddings

@@ -33,6 +33,7 @@ from .generation import (
     cache_update,
     decode_mask as _decode_mask,
     masked_decode_attention,
+    rows_at,
 )
 from ..parallel.mp_layers import (
     ColumnParallelLinear,
@@ -370,7 +371,8 @@ class LlamaModel(Layer):
                       if a in mesh.axis_names and mesh.shape[a] > 1)
         return (batch if batch else None, "sep", None)
 
-    def forward(self, input_ids, caches=None, position_offset=0):
+    def forward(self, input_ids, caches=None, position_offset=0,
+                logits_at=None):
         x = self.embed_tokens(input_ids)
         # dp on batch, sep on sequence when those axes exist
         spec = self._sep_spec() if caches is None else None
@@ -387,7 +389,7 @@ class LlamaModel(Layer):
                     x = _remat_layer(layer, x)
                 else:
                     x = layer(x)
-        x = self.norm(x)
+        x = self.norm(rows_at(x, logits_at))
         if caches is not None:
             return x, new_caches
         return x
@@ -457,9 +459,13 @@ class LlamaForCausalLM(GenerationMixin, Layer):
                 return loss
             return logits
 
-    def generate_step(self, input_ids, caches, position_offset):
-        """Single decode step with functional cache."""
-        h, caches = self.llama(input_ids, caches, position_offset)
+    def generate_step(self, input_ids, caches, position_offset,
+                      logits_at=None):
+        """Single decode step with functional cache; ``logits_at``
+        (generation.rows_at) names the one row a sequence to norm and
+        project."""
+        h, caches = self.llama(input_ids, caches, position_offset,
+                               logits_at)
         with jax.named_scope("lm_head"):
             logits = self.lm_head(h)
         return logits, caches

@@ -44,6 +44,7 @@ from ..nn import initializer as I
 from ..nn.layer import Layer
 from ..nn.layers.container import LayerList
 from ..parallel.moe import MoELayer, moe_forward
+from .generation import rows_at
 from .llama import rope_apply
 
 GDN_CHUNK = 64
@@ -490,7 +491,7 @@ class Qwen3NextForCausalLM(Layer):
         self.moe_layers = config.num_hidden_layers
         self.moe_experts_held = len(config.experts_held)
 
-    def _run(self, input_ids, caches, position_offset):
+    def _run(self, input_ids, caches, position_offset, logits_at=None):
         c = self.config
         ids = _val(input_ids)
         x = jnp.take(self.model.embed_tokens._value, ids, axis=0)
@@ -508,7 +509,8 @@ class Qwen3NextForCausalLM(Layer):
                 x, cache = layer(x, caches[i], position_offset)
             new_caches.append(cache)
         with jax.named_scope("lm_head"):
-            x = rms_norm_zero_centred(x, self.model.norm._value,
+            x = rms_norm_zero_centred(rows_at(x, logits_at),
+                                      self.model.norm._value,
                                       c.rms_norm_eps)
             logits = jnp.matmul(x, self.lm_head._value)
         return Tensor(logits), new_caches
@@ -517,10 +519,13 @@ class Qwen3NextForCausalLM(Layer):
         """Logits [B, T, vocab] of whole sequences, nothing kept."""
         return self._run(input_ids, None, 0)[0]
 
-    def generate_step(self, input_ids, caches, position_offset):
+    def generate_step(self, input_ids, caches, position_offset,
+                      logits_at=None):
         """One compiled step of the serving engine: ``caches`` is one
-        hook a layer (serving/kv_cache.py)."""
-        return self._run(input_ids, caches, position_offset)
+        hook a layer (serving/kv_cache.py); ``logits_at``
+        (generation.rows_at) names the one row a sequence to norm and
+        project."""
+        return self._run(input_ids, caches, position_offset, logits_at)
 
     def moe_step_stats(self):
         """int32 [layers, 3] of the step just traced: pairs routed to

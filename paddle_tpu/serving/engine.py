@@ -1210,11 +1210,29 @@ class Engine:
                 views = self.cache.prefill_views(pools, table_row,
                                                  true_len)
                 logits, views = self.model.generate_step(
-                    Tensor(ids), views, 0)
-        lv = logits._value if isinstance(logits, Tensor) else logits
-        last = lv[0, true_len - 1].astype(jnp.float32)
-        tok = jnp.argmax(last, axis=-1).astype(jnp.int32)
+                    Tensor(ids), views, 0, self._last_row(true_len))
+        tok = self._greedy(logits)[0]
         return self._with_moe_counters(tok), [v.pool for v in views]
+
+    @staticmethod
+    def _last_row(q_lens):
+        """``logits_at`` of a prefill or mixed step: the one row a
+        sequence whose token the engine takes (its last valid position;
+        an idle row of the mixed step clamps to 0 and is ignored on the
+        host), int32 [B]. The model then norms and projects that row
+        alone instead of the whole padded bucket."""
+        return jnp.maximum(
+            jnp.reshape(q_lens, (-1,)).astype(jnp.int32) - 1, 0)
+
+    @staticmethod
+    def _greedy(logits):
+        """int32 [B]: argmax in float32 of the one row a sequence that
+        ``logits_at`` left in ``logits`` [B, 1, vocab]."""
+        from ..core.tensor import Tensor
+
+        lv = logits._value if isinstance(logits, Tensor) else logits
+        return jnp.argmax(lv[:, 0, :].astype(jnp.float32),
+                          axis=-1).astype(jnp.int32)
 
     def _with_moe_counters(self, tokens):
         """The step's tokens, and behind them for a model that declares
@@ -1267,11 +1285,8 @@ class Engine:
                                         qlen_v, self.block_size)
                          for p in pools]
                 logits, views = self.model.generate_step(
-                    Tensor(ids), views, hist_v)
-        lv = logits._value if isinstance(logits, Tensor) else logits
-        last = lv[0, true_len - 1].astype(jnp.float32)
-        tok = jnp.argmax(last, axis=-1).astype(jnp.int32)
-        return tok, [v.pool for v in views]
+                    Tensor(ids), views, hist_v, self._last_row(qlen_v))
+        return self._greedy(logits)[0], [v.pool for v in views]
 
     def _mixed_fn(self, state_vals, pools, tokens, block_tables,
                   seq_lens, q_lens):
@@ -1292,12 +1307,6 @@ class Engine:
                                         q_lens, self.block_size)
                          for p in pools]
                 logits, views = self.model.generate_step(
-                    Tensor(tokens), views, seq_lens)
-        lv = logits._value if isinstance(logits, Tensor) else logits
-        # each row's next token comes from its LAST VALID position's
-        # logits (q_len-1; idle rows clamp to 0 and are ignored host-side)
-        last = jnp.take_along_axis(
-            lv.astype(jnp.float32),
-            jnp.maximum(q_lens - 1, 0)[:, None, None], axis=1)[:, 0]
-        nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
-        return nxt, [v.pool for v in views]
+                    Tensor(tokens), views, seq_lens,
+                    self._last_row(q_lens))
+        return self._greedy(logits), [v.pool for v in views]

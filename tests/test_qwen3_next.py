@@ -163,6 +163,21 @@ def test_prefill_then_decode_match_the_full_forward(family, tiny,
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
 
+@pytest.mark.parametrize("prompt_len", [32, 21])
+def test_prefill_token_is_the_argmax_of_the_full_logits_row(tiny,
+                                                            prompt_len):
+    """``Engine._prefill_fn`` asks the model for the one row it reads
+    (``logits_at``); ``logits_through_cache`` asks for none and keeps
+    that row of the bucket's full logits. A prompt that fills its
+    bucket and one that does not."""
+    model, _ = tiny
+    prompt = _ids(prompt_len, seed=prompt_len + 1)
+    rows, _ = logits_through_cache(_engine(model), prompt + [0], 1)
+    eng = _engine(model)
+    rid = eng.add_request(prompt, max_new_tokens=1)
+    assert eng.run()[rid] == [int(np.argmax(rows[0]))]
+
+
 def test_the_program_routes_as_the_reference_does(family, tiny):
     """tools/serving_parity.py's count of differing top-k selections:
     in float32 at this size there are none."""

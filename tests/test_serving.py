@@ -594,3 +594,196 @@ class TestDonatedPoolRecovery:
         finally:
             paddle.set_flags({"FLAGS_serving_prefix_cache": False,
                               "FLAGS_serving_chunked_prefill": False})
+
+
+# ---------------------------------------------------------------------------
+# generate_step(..., logits_at=): the head runs on the rows that are read
+# ---------------------------------------------------------------------------
+
+# sizes no two of which are equal, so a shape names what it holds
+HEAD_VOCAB, HEAD_HIDDEN, HEAD_BUCKET = 80, 32, 16
+
+
+def _head_model(kind):
+    paddle.seed(3)
+    if kind == "llama":
+        return LlamaForCausalLM(LlamaConfig(
+            vocab_size=HEAD_VOCAB, hidden_size=HEAD_HIDDEN,
+            intermediate_size=48, num_hidden_layers=2,
+            num_attention_heads=4, max_position_embeddings=64,
+            use_parallel=False))
+    if kind == "gpt":
+        from paddle_tpu.models.gpt import GPTModel
+
+        return GPTModel(vocab_size=HEAD_VOCAB, hidden_size=HEAD_HIDDEN,
+                        num_layers=2, num_heads=4, max_seq_len=64)
+    from paddle_tpu.models import qwen3_next as qn
+
+    return qn.Qwen3NextForCausalLM(qn.Qwen3NextConfig.tiny(
+        vocab_size=HEAD_VOCAB))
+
+
+@pytest.fixture(scope="module", params=["llama", "gpt", "qwen3_next"])
+def head_engine(request):
+    return serving.Engine(_head_model(request.param), max_slots=3,
+                          num_blocks=32, block_size=4, max_model_len=64)
+
+
+class TestLogitsAt:
+    """The protocol: ``logits_at`` [B] names one row a sequence and the
+    model returns logits [B, 1, vocab] of those rows, the full logits'
+    own to float32 rounding (a one-row matmul may accumulate in another
+    order): within 1e-6 of the largest |logit|."""
+
+    @staticmethod
+    def _same(one, full_rows):
+        np.testing.assert_allclose(
+            one, full_rows, rtol=0,
+            atol=1e-6 * float(np.abs(full_rows).max()))
+
+    def _logits(self, eng, views_of, ids, logits_at):
+        from paddle_tpu.core.dispatch import no_grad
+        from paddle_tpu.core.tensor import Tensor
+
+        model = eng.model
+
+        def step(vals, pools, ids, logits_at):
+            with model.bind_state(eng._names, list(vals)), no_grad():
+                logits, _ = model.generate_step(
+                    Tensor(ids), views_of(pools), 0, logits_at)
+            return logits._value
+
+        return np.asarray(eng._run_eval(
+            jax.jit(step), eng._state_vals, eng.cache.pools,
+            jnp.asarray(ids), logits_at))
+
+    @pytest.mark.parametrize("true_len", [HEAD_BUCKET, 11],
+                             ids=["fills_its_bucket", "short_of_it"])
+    def test_a_prefill_reads_its_last_real_row(self, head_engine,
+                                               true_len):
+        eng = head_engine
+        assert eng._bucket(true_len) == HEAD_BUCKET
+        assert eng.cache.ensure_capacity(1, HEAD_BUCKET)
+        ids = np.zeros((1, HEAD_BUCKET), np.int32)
+        ids[0, :true_len] = np.random.RandomState(true_len).randint(
+            1, HEAD_VOCAB, (true_len,))
+        row = jnp.asarray(eng.cache.block_tables[1])
+        n = jnp.asarray(true_len, jnp.int32)
+
+        def views_of(pools):
+            return eng.cache.prefill_views(pools, row, n)
+
+        full = self._logits(eng, views_of, ids, None)
+        one = self._logits(eng, views_of, ids,
+                           jnp.asarray([true_len - 1], jnp.int32))
+        assert full.shape == (1, HEAD_BUCKET, HEAD_VOCAB)
+        assert one.shape == (1, 1, HEAD_VOCAB)
+        self._same(one[:, 0], full[:, true_len - 1])
+
+    def test_each_sequence_of_a_batch_reads_its_own_row(self,
+                                                        head_engine):
+        """The mixed step's case: B > 1, another row a sequence (over
+        the model's own dense caches; a model that keeps none between
+        steps takes ``None``)."""
+        eng = head_engine
+        rows = np.asarray([HEAD_BUCKET - 1, 4, 0], np.int32)
+        ids = np.random.RandomState(5).randint(
+            1, HEAD_VOCAB, (3, HEAD_BUCKET)).astype(np.int32)
+        init = getattr(eng.model, "init_decode_caches", None)
+
+        def views_of(_pools):
+            return None if init is None else init(3, HEAD_BUCKET)
+
+        full = self._logits(eng, views_of, ids, None)
+        one = self._logits(eng, views_of, ids, jnp.asarray(rows))
+        assert one.shape == (3, 1, HEAD_VOCAB)
+        self._same(one[:, 0], full[np.arange(3), rows])
+
+
+def _avals(jaxpr):
+    """Every (primitive name, input shapes, output shapes) of a jaxpr,
+    the bodies of its loops, branches and calls included."""
+    for eqn in jaxpr.eqns:
+        yield (eqn.primitive.name,
+               [getattr(v.aval, "shape", ()) for v in eqn.invars],
+               [getattr(v.aval, "shape", ()) for v in eqn.outvars])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _avals(sub)
+
+
+def _wide_rows(eqns, vocab, seqs):
+    """Shapes [..., vocab] of more rows than the ``seqs`` sequences of
+    the step: [P, vocab], [1, P, vocab], [S, C, vocab] alike."""
+    return sorted({s for _, _, outs in eqns for s in outs
+                   if len(s) >= 2 and s[-1] == vocab
+                   and int(np.prod(s[:-1])) > seqs})
+
+
+class TestHeadRunsOnTheRowsRead:
+    """No compiled prefill program holds a [P, vocab] (or [S, C, vocab])
+    array; the decode step, which reads every row it computes, is the
+    program it was."""
+
+    S, C = 3, 8
+
+    def _traced(self, flags, pick):
+        model = _head_model("llama")
+        paddle.set_flags(dict.fromkeys(flags, True))
+        try:
+            eng = serving.Engine(model, max_slots=self.S, num_blocks=32,
+                                 block_size=4, max_model_len=64,
+                                 prefill_chunk=self.C)
+            fn, args = pick(eng)
+            return list(_avals(eng._run_eval(
+                jax.make_jaxpr(fn), *args).jaxpr))
+        finally:
+            paddle.set_flags(dict.fromkeys(flags, False))
+
+    @staticmethod
+    def _prefill_args(eng, *more):
+        return (eng._state_vals, eng.cache.pools,
+                jnp.zeros((1, HEAD_BUCKET), jnp.int32),
+                jnp.asarray(eng.cache.block_tables[0])) + more
+
+    def test_prefill(self):
+        n = jnp.asarray(HEAD_BUCKET - 3, jnp.int32)
+        eqns = self._traced((), lambda eng: (
+            eng._prefill_fn, self._prefill_args(eng, n)))
+        assert _wide_rows(eqns, HEAD_VOCAB, 1) == []
+        assert any((1, 1, HEAD_VOCAB) in outs for _, _, outs in eqns)
+
+    def test_suffix_prefill(self):
+        n = jnp.asarray(HEAD_BUCKET - 3, jnp.int32)
+        eqns = self._traced(
+            ("FLAGS_serving_prefix_cache",), lambda eng: (
+                eng._suffix_prefill_fn,
+                self._prefill_args(eng, jnp.asarray(4, jnp.int32), n)))
+        assert _wide_rows(eqns, HEAD_VOCAB, 1) == []
+        assert any((1, 1, HEAD_VOCAB) in outs for _, _, outs in eqns)
+
+    def test_mixed_step(self):
+        def pick(eng):
+            name, _, fn, args = eng._hot_step()
+            assert name == "mixed" and args[2].shape == (self.S, self.C)
+            return fn, args
+
+        eqns = self._traced(("FLAGS_serving_chunked_prefill",), pick)
+        assert _wide_rows(eqns, HEAD_VOCAB, self.S) == []
+        assert any((self.S, 1, HEAD_VOCAB) in outs
+                   for _, _, outs in eqns)
+
+    def test_decode_step_is_untouched(self):
+        def pick(eng):
+            name, _, fn, args = eng._hot_step()
+            assert name == "decode"
+            return fn, args
+
+        eqns = self._traced((), pick)
+        # its [S, 1, vocab] logits, every row of them read
+        assert any((self.S, 1, HEAD_VOCAB) in outs
+                   for _, _, outs in eqns)
+        assert _wide_rows(eqns, HEAD_VOCAB, self.S) == []
+        # and no row picked out of the hidden state: it passes no
+        # logits_at
+        assert not [ins for name, ins, _ in eqns if name == "gather"
+                    and ins[0] == (self.S, 1, HEAD_HIDDEN)]

@@ -394,3 +394,66 @@ class TestBlocksAreNamed:
                     in text)
         for scope in self.SCOPES:
             assert scope in text, scope
+
+
+class TestPrefillHeadOnOneRow:
+    """``mistral7b-chat-backlog``'s largest prefill (bucket 2048, the
+    published widths, one layer) compiled for the v5e: the engine names
+    the row it reads (``logits_at``), so the program holds no
+    [2048, 32768] logits (134 MB), which the same prefill with the head
+    on every row does. (``memory_analysis()`` does not show it: the
+    compiler's peak of temporaries is its scheduler's choice, and with
+    the logits gone it overlaps more; 162.0 -> 153.6 MB at one layer,
+    153.8 -> 164.3 at two.)"""
+
+    P, VOCAB = 2048, 32768
+
+    def test_no_logits_of_the_whole_bucket(self, v5e, monkeypatch):
+        from paddle_tpu import serving
+        from paddle_tpu.core.dispatch import no_grad
+        from paddle_tpu.core.tensor import Parameter, Tensor
+        from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+        from paddle_tpu.nn import initializer
+
+        # nothing runs: every parameter is bf16 zeros, not a seeded draw
+        monkeypatch.setattr(
+            initializer.Initializer, "create",
+            lambda self, shape, dtype=None, name=None: Parameter(
+                jnp.zeros(tuple(int(s) for s in shape), BF16), name=name))
+        # the attention dispatch asks the backend; here it is the CPU
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        model = LlamaForCausalLM(LlamaConfig(
+            vocab_size=self.VOCAB, hidden_size=4096,
+            intermediate_size=14336, num_hidden_layers=1,
+            num_attention_heads=32, num_key_value_heads=8,
+            max_position_embeddings=32768, rope_theta=1e6,
+            use_parallel=False, dtype="bfloat16"))
+        eng = serving.Engine(model, max_slots=2, num_blocks=512,
+                             block_size=16, max_model_len=2560)
+        assert eng._bucket(1100) == self.P
+        args = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(
+                jnp.shape(a), jnp.result_type(a), sharding=v5e),
+            (eng._state_vals, eng.cache.pools,
+             jnp.zeros((1, self.P), I32),
+             jnp.asarray(eng.cache.block_tables[0]),
+             jnp.asarray(self.P, I32)))
+
+        def every_row(state_vals, pools, ids, table_row, true_len):
+            with model.bind_state(eng._names, list(state_vals)), \
+                    no_grad():
+                views = eng.cache.prefill_views(pools, table_row,
+                                                true_len)
+                logits, views = model.generate_step(Tensor(ids), views, 0)
+            return (jnp.argmax(logits._value[0, true_len - 1].astype(F32)),
+                    [v.pool for v in views])
+
+        def compiled(fn):
+            return eng._run_eval(
+                jax.jit(fn, donate_argnums=(1,)).lower, *args).compile()
+
+        one, every = compiled(eng._prefill_fn), compiled(every_row)
+        logits = "bf16[%d,%d]" % (self.P, self.VOCAB)
+        assert logits in every.as_text()
+        assert "%d,%d]" % (self.P, self.VOCAB) not in one.as_text()
+        assert mosaic_kernels(one.as_text()) == {"flash_fwd": 1}
