@@ -46,7 +46,7 @@ Geometry = collections.namedtuple("Geometry", [
     "shared_prefix", "suffix_lens",         # serve_mixed
 ])
 
-# tools/serving_benchmark.py PRESETS["llama1b"]; 953M parameters.
+# llama1b width (tools/serving_router.py PRESETS["llama1b"]).
 # prompt_lens land in three prefill buckets — 64 (< 128: reference SDPA),
 # 256 and 512 (flash) — and prompt_lens[parity_request] + 8 == 256, so
 # the dense forward it is checked against runs the flash kernel too.

@@ -1,8 +1,8 @@
 """Where the persistent XLA compile cache lives — decided from outside.
 
-Every entry point that compiles on the chip (chip_smoke.py, bench.py,
-tools/serving_benchmark.py, the serving replica launcher,
-incubate.autotune) calls ``configure()`` before its first compile:
+Every entry point that compiles on the chip (chip_smoke.py,
+benchmark/run.py, the serving replica launcher, incubate.autotune)
+calls ``configure()`` before its first compile:
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; this module
   touches no cache setting, so whoever runs the program owns the

@@ -143,16 +143,6 @@ def _local_peaks():
     return kind, spec
 
 
-def device_fields():
-    """Where a number came from, as JAX reports it — the fields every
-    benchmark row carries (bench.py, tools/serving_benchmark.py)."""
-    import jax
-
-    dev = jax.devices()[0]
-    return {"platform": dev.platform, "device_kind": dev.device_kind,
-            "device_count": len(jax.devices())}
-
-
 def machine_spec():
     """Per-chip peak numbers of the LOCAL device — the denominator of
     every MFU in this module. Raises UnknownDeviceKindError when the
@@ -225,8 +215,8 @@ def bench_fields(analysis, tokens_per_s=None, tokens_per_step=None,
                  peak_flops=None):
     """Bench-row JSON fields from an ``executable_analysis`` dict:
     ``mfu`` / ``model_flops_per_step`` / ``hbm_peak_bytes`` — the
-    hardware-normalized form of a raw tokens/s number (bench.py and
-    tools/model_benchmark.py emit these)."""
+    hardware-normalized form of a raw tokens/s number
+    (tools/perf_report.py's smoke row carries these)."""
     out = {}
     if not analysis:
         return out

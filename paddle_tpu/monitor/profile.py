@@ -53,8 +53,8 @@ the division of labor: **profile = where the time measurably went**):
    falsifiable, and the exposed-comm residual (measured step − analytic
    compute) is the scoreboard ROADMAP item 4 starts from. The serving
    engine additionally feeds per-phase host timers
-   (``note_phase("prefill"|"decode", dt)``) for the
-   ``serving_benchmark --profile`` rows.
+   (``note_phase("prefill"|"decode", dt)``), shown as ``phases`` in
+   the job's ``/debugz/profile`` row.
 
 Discipline (the PR-2/5/6/12 contract, test-pinned): default OFF via
 ``FLAGS_monitor_profile``. Engines latch ``step_hook(job)`` ONCE at
@@ -728,8 +728,8 @@ class StepProfiler:
 
     def note_phase(self, phase, seconds):
         """Accumulate one sub-phase's measured host seconds (the
-        serving engine feeds prefill/decode; serving_benchmark
-        --profile reports the totals)."""
+        serving engine feeds prefill/decode; ``/debugz/profile`` and
+        tools/profile_snapshot.py report the totals)."""
         with _state.lock:
             tot = _state.jobs.setdefault(self.job, {
                 "steps": 0, "dispatch_s": 0.0, "blocked_s": 0.0,

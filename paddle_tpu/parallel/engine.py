@@ -48,8 +48,8 @@ from ..distributed import mesh as _mesh
 from ..distributed.collective import shard_map as _shard_map
 
 # training telemetry on the same registry as serving (monitor/):
-# step time, token throughput, trace counts, device memory — the
-# north-star numbers bench.py reads, live on /metrics.
+# step time, token throughput, trace counts, device memory, live on
+# /metrics.
 _STEP_TIME = _monitor.histogram(
     "train_step_seconds",
     "host wall time of one compiled train-step call (dispatch + any "

@@ -74,7 +74,7 @@ _TERMINAL = ("finished", "expired", "shed", "failed")
 # once leak each other's tracers (UnexpectedTracerError poisons every
 # in-flight request). Steps therefore serialize on a process-wide
 # lock: uncontended in the deployment shape (one engine per process,
-# e.g. serving_benchmark --fleet forks), and correctness-over-overlap
+# tools/serving_router.py --replica), and correctness-over-overlap
 # for in-process fleets (tests, single-host dev).
 _STEP_LOCK = threading.Lock()
 

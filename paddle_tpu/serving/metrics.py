@@ -1,7 +1,7 @@
 """Serving metrics: per-request latency breakdown + engine counters.
 
-Schema (all plain dicts, json-ready — tools/serving_benchmark.py dumps
-them verbatim):
+Schema (all plain dicts, json-ready): this is what ``Engine.stats()``
+returns, and what ``benchmark/layer_metrics/`` reads.
 
 per-request (``RequestMetrics.to_dict()``):
   queue_time_s     arrival -> first admission
@@ -24,8 +24,7 @@ engine (``EngineMetrics.to_dict()``):
 Every sample also flows through the framework-wide registry
 (paddle_tpu.monitor): counters/gauges under ``serving_*`` plus
 TTFT/TPOT/queue/e2e histograms, so serving shows up on the same
-/metrics endpoint and JSON snapshots as training telemetry. The dict
-API above stays — it is the benchmark-artifact schema.
+/metrics endpoint and JSON snapshots as training telemetry.
 
 The engine's own account of a step (always on; ``Engine.step()`` takes
 one ``now()`` at each phase boundary and keeps the rows in bounded rings

@@ -36,5 +36,5 @@ def pytest_configure(config):
     # tier-1 verify runs `-m 'not slow'`; register the marker so strict
     # runs don't warn and the expression always resolves
     config.addinivalue_line(
-        "markers", "slow: long-running gates (live 7B plan compile, "
-        "serving benchmark) excluded from the tier-1 sweep")
+        "markers", "slow: long-running gates (the live 7B plan compile) "
+        "excluded from the tier-1 sweep")

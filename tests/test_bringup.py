@@ -3,7 +3,6 @@
 - the compile cache is placed from outside (core/compile_cache.py);
 - an accelerator place never resolves to a CPU device or wraps its id;
 - an MFU has a denominator only for a device kind with a sourced row;
-- bench.py measures on a TPU or not at all;
 - a launcher never starts several backend-owning processes on a TPU
   (one process per chip);
 - the native library is rebuilt from csrc/ by source hash, not mtime;
@@ -12,8 +11,6 @@
 from __future__ import annotations
 
 import os
-import subprocess
-import sys
 
 import jax
 import numpy as np
@@ -167,20 +164,6 @@ class TestMachineSpecNeedsAKnownKind:
         assert payload["machine"] == {}
 
 
-class TestBenchNeedsTheChip:
-    def test_no_tpu_no_row_no_file(self, tmp_path):
-        before = sorted(os.listdir(REPO))
-        r = subprocess.run(
-            [sys.executable, os.path.join(REPO, "bench.py")],
-            cwd=str(tmp_path), capture_output=True, text=True,
-            env=dict(os.environ, JAX_PLATFORMS="cpu"), timeout=300)
-        assert r.returncode == 2, r.stdout + r.stderr
-        assert r.stdout == ""
-        assert "'cpu', not 'tpu'" in r.stderr
-        assert os.listdir(str(tmp_path)) == []
-        assert sorted(os.listdir(REPO)) == before
-
-
 class TestOneProcessPerChip:
     def test_spawn_refuses_several_processes_on_a_tpu(self, monkeypatch):
         import paddle_tpu.distributed as dist
@@ -237,24 +220,6 @@ class TestOneProcessPerChip:
         c.cfg = Cfg()
         with pytest.raises(RuntimeError, match="launch --nproc_per_node 2"):
             c.build_pod()
-
-    def test_benchmark_fleet_mode_refuses_on_a_tpu(self, monkeypatch):
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "t_serving_benchmark",
-            os.path.join(REPO, "tools", "serving_benchmark.py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-
-        class Args:
-            fleet = 3
-
-        with pytest.raises(RuntimeError,
-                           match="serving_benchmark --fleet 3"):
-            mod.run_fleet(Args())
 
 
 class TestNativeBuildBySourceHash:

@@ -154,38 +154,3 @@ class TestCoverageReport:
         assert len(unguarded) == 36
         assert any("guards %d of %d" % (len(base["ops"]), len(measured))
                    in l for l in lines)
-
-
-class TestModelBenchmarkHarness:
-    """tools/model_benchmark.py north-star rows execute end to end
-    (reference ci_model_benchmark.sh analog). Fast rows only — the
-    resnet/ernie compiles are covered by their own model tests."""
-
-    def test_widedeep_and_allreduce_rows(self):
-        import json
-        import os
-        import subprocess
-        import sys
-
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        env = dict(os.environ)
-        env["JAX_PLATFORMS"] = "cpu"
-        if "host_platform_device_count" not in env.get("XLA_FLAGS", ""):
-            env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                                + " --xla_force_host_platform_device_count=8"
-                                ).strip()
-        out = []
-        for sub in ("widedeep", "allreduce"):
-            proc = subprocess.run(
-                [sys.executable, "tools/model_benchmark.py", sub,
-                 "--iters", "2"],
-                cwd=repo, env=env, capture_output=True, text=True,
-                timeout=300)
-            assert proc.returncode == 0, proc.stderr[-2000:]
-            recs = [json.loads(l) for l in proc.stdout.splitlines()
-                    if l.startswith("{")]
-            assert recs and ("value" in recs[0] or "skipped" in recs[0]), \
-                proc.stdout
-            out += recs
-        assert any(r.get("metric") == "widedeep_ps_examples_per_sec"
-                   and r["value"] > 0 for r in out)

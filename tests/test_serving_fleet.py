@@ -280,8 +280,7 @@ class TestFleetEndToEnd:
                 if r["rank"] is not None)
             # kill it NOW — its accepted-but-unfinished requests must
             # move. deregister deletes the lease: immediate death for
-            # the router's view, no ttl wait (the SIGKILL analog is
-            # exercised by tools/serving_benchmark.py --kill-replica-at)
+            # the router's view, no ttl wait
             replicas[victim].stop(deregister=True)
             assert router.wait_all(timeout_s=180)
             reqs = [router.request(n) for n in nonces]
@@ -570,71 +569,6 @@ class TestFleetTracing:
             for rep in replicas:
                 rep.stop()
             router.close()
-
-
-@pytest.mark.slow
-class TestFleetBenchmarkTracing:
-    def test_benchmark_kill_run_emits_merged_reroute_timeline(
-            self, tmp_path):
-        """The ISSUE-17 acceptance row, subprocess-for-real: a
-        3-replica --fleet --kill-replica-at run loses nothing, and the
-        merged clock-aligned timeline shows >=1 rerouted request whose
-        chain reads attempt 1 on the victim, a reroute span naming the
-        reason, attempt 2 on a survivor — under ONE trace id."""
-        import os
-        import subprocess
-        import sys
-
-        repo = os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__)))
-        out = str(tmp_path / "snap.json")
-        trace_out = str(tmp_path / "fleet_trace.json")
-        p = subprocess.run(
-            [sys.executable,
-             os.path.join(repo, "tools", "serving_benchmark.py"),
-             "--fleet", "3", "--kill-replica-at", "0.3",
-             "--requests", "16", "--rate", "30",
-             "--max-new", "12", "24", "--preset", "tiny",
-             "--max-slots", "2", "--num-blocks", "64",
-             "--out", out, "--fleet-trace-out", trace_out,
-             "--watchdog", "540"],
-            capture_output=True, text=True, timeout=560,
-            env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=repo)
-        assert p.returncode == 0, (p.stdout[-2000:], p.stderr[-2000:])
-        report = json.load(open(out))
-        assert report["lost_requests"] == []
-        assert report["trace"]["enabled"] is True
-        doc = json.load(open(trace_out))
-        assert doc["kind"] == "fleet_trace"
-        assert doc["metadata"]["router_cid"]
-        rerouted = {tid: row for tid, row in doc["requests"].items()
-                    if row["reroutes"]}
-        assert rerouted, "kill run produced no rerouted request"
-        killed = report["kill"]["killed_rank"]
-        for tid, row in rerouted.items():
-            accepted = [d for d in row["dispatches"]
-                        if d["outcome"] == "accepted"]
-            assert accepted[0]["replica"] == killed
-            assert accepted[-1]["replica"] != killed
-            assert row["reroutes"][0]["reason"] in (
-                "lease-evicted", "404", "shed", "drain")
-            assert row["reroutes"][0]["from_rank"] == killed
-            assert accepted[0]["t_start"] \
-                <= row["reroutes"][0]["t_start"] \
-                <= accepted[-1]["t_start"]
-        # the requests_detail rows agree with the merged artifact
-        detail = {r["trace_id"]: r
-                  for r in report["kill"]["requests_detail"]}
-        for tid, row in rerouted.items():
-            r = detail[tid]
-            assert r["state"] == "finished"
-            assert r["attempt_ranks"][0] == killed
-            assert r["attempt_ranks"][-1] != killed
-            assert len(r["hops"]["dispatch_attempts"]) >= 2
-        # surviving replicas' journals merged in (the victim's died
-        # with the SIGKILL; its evidence lives in the router spans)
-        ranks = doc["metadata"]["replica_ranks"]
-        assert killed not in ranks and len(ranks) >= 1
 
 
 import urllib.error  # noqa: E402  (used by the 404 pin above)

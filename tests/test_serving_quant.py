@@ -72,6 +72,24 @@ def _greedy_ref(model, prompt, max_new_tokens, eos_token_id=None):
     return toks
 
 
+def _assert_equal_up_to_a_near_tie(model, prompt, got, want, ctx):
+    """Greedy ids are compared as the chip checks compare them: where
+    two runs first part, the dense float32 logits of the two tokens
+    must lie within what int8 pages can resolve (a block's values are
+    rounded to 1/127 of its largest: 2^-7 of the largest |logit|).
+    Past that point the runs decode different sequences."""
+    assert len(got) == len(want), ctx
+    if got == want:
+        return
+    i = next(k for k, (a, b) in enumerate(zip(got, want)) if a != b)
+    ids = np.asarray([prompt + want[:i]], np.int32)
+    logits = np.asarray(model(paddle.to_tensor(ids))._value,
+                        np.float32)[0, -1]
+    gap = abs(float(logits[got[i]] - logits[want[i]]))
+    assert gap <= 2.0 ** -7 * float(np.abs(logits).max()), \
+        (ctx, i, got, want, gap)
+
+
 # ---------------------------------------------------------------------------
 # quant primitives (no model): page and weight codecs
 # ---------------------------------------------------------------------------
@@ -359,7 +377,14 @@ class TestQuantFlagMatrix:
                 assert st["quant_dequant_bytes"] > 0
         base = got[(False, False)]
         for combo, outs in got.items():
-            assert outs == base, (quant, combo)
+            if not qkv:
+                assert outs == base, (quant, combo)
+                continue
+            # a prefill over adopted int8 history reads rounded K/V
+            # where a whole prefill reads its own exact ones
+            for prompt, out, want in zip(prompts, outs, base):
+                _assert_equal_up_to_a_near_tie(m, prompt, out, want,
+                                               (quant, combo))
 
 
 # ---------------------------------------------------------------------------

@@ -19,6 +19,7 @@ chip_smoke.py's job.
 from __future__ import annotations
 
 import importlib
+import importlib.util
 import os
 
 import jax
@@ -394,6 +395,31 @@ class TestBlocksAreNamed:
                     in text)
         for scope in self.SCOPES:
             assert scope in text, scope
+
+    def test_the_benchmarks_kernel_finder_agrees_on_a_compiled_step(
+            self, v5e, forward, monkeypatch):
+        """``benchmark/`` stands alone, so ``trace_reduce.py`` keeps a
+        finder of its own beside ``mosaic_kernels``; what the benchmark
+        books device time under and what chip_smoke.py asserts on must
+        be the same names, on a forward and backward with the autodiff
+        wrappers around them."""
+        spec = importlib.util.spec_from_file_location(
+            "benchmark_trace_reduce", os.path.join(
+                os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                "benchmark", "trace_reduce.py"))
+        trace_reduce = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(trace_reduce)
+        fwd, values = forward
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        avals = [jax.ShapeDtypeStruct(v.shape, BF16, sharding=v5e)
+                 for v in values]
+        ids = jax.ShapeDtypeStruct((1, 128), I32, sharding=v5e)
+        text = jax.jit(jax.grad(
+            lambda values, ids, labels: fwd(values, ids, labels)
+            .astype(F32).sum())).lower(avals, ids, ids).compile().as_text()
+        found = mosaic_kernels(text)
+        assert found == {"flash_fwd": 2, "flash_dq": 2, "flash_dkv": 2}
+        assert trace_reduce.kernel_counts(text) == found
 
 
 class TestPrefillHeadOnOneRow:

@@ -8,7 +8,7 @@ quantized int8+scales format — reporting seconds/op, actual wire bytes
 per op (from the ``comm_bytes_total`` registry counters, the same
 series the acceptance gate asserts on), compression ratio, and max
 relative error of the compressed reduction. One JSON row per (size,
-format), ``serving_benchmark``-style.
+format).
 
 Backend note: the store transport is host-side TCP — numbers are
 transport numbers and mean the same thing whatever the JAX backend.

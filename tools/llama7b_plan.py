@@ -21,8 +21,8 @@ permits:
      sharding lowers to the intended communication pattern.
   4. Projects tokens/s/chip analytically from the measured sustained
      model-FLOPs throughput of this framework's largest on-chip run
-     (953M at 99.3 TF/s, 50.4% MFU — MODEL_BENCH_r04.json) — labeled a
-     PROJECTION, not a measurement.
+     (953M at 99.3 TF/s, 50.4% MFU — a pre-ledger reading) — labeled
+     a PROJECTION, not a measurement.
 
 No real weights are materialized for the heavy configs: parameters are
 built zero-initialized (jax.random patched for construction speed),
@@ -332,7 +332,7 @@ def projection(n_params, seq, layers, hidden):
     # 12*L*s*h per token (fwd+bwd of the s x s score/APV matmuls)
     attn = 12 * layers * seq * hidden
     flops_per_token = 6 * n_params + attn
-    measured_tf = 99.3e12  # 953M run, MODEL_BENCH_r04.json, 50.4% MFU
+    measured_tf = 99.3e12  # 953M run, pre-ledger, 50.4% MFU
     tok_chip = measured_tf / flops_per_token
     return {
         "method": "PROJECTION from measured 953M sustained throughput "

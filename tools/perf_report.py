@@ -3,10 +3,9 @@
 Renders the monitor/perf.py attribution surface as a run report, from
 one of three sources:
 
-  # smoke: build the bench-family decoder, run a few compiled steps
-  # with perf attribution + the time-series ring on, report (the
-  # default; CPU-safe — a tiny config off-chip, the 110M bench config
-  # on the real backend)
+  # smoke: build a llama decoder, run a few compiled steps with perf
+  # attribution + the time-series ring on, report (the default;
+  # CPU-safe — a tiny config off-chip, a 134M one on the real backend)
   python tools/perf_report.py [--steps N] [--json] [--out FILE]
 
   # live: GET /debugz/perf from a running rank's fleet KV HTTP server
@@ -14,11 +13,6 @@ one of three sources:
 
   # artifact: render a previously-written payload JSON
   python tools/perf_report.py --in perf_report.json
-
-``--baseline ROW.json`` diffs the measured MFU / HBM peak against
-a bench artifact's fields (bench.py emits ``mfu`` / ``hbm_peak_bytes``
-as of this round); a baseline from before the perf round is reported
-as such, never silently treated as zero.
 """
 from __future__ import annotations
 
@@ -67,8 +61,7 @@ def smoke(steps=5):
     pmesh.build_hybrid_mesh(dp=1, devices=jax.devices()[:1])
     paddle.seed(0)
     if on_tpu:
-        # the flagship bench config (bench.py): the MFU this prints IS
-        # the hardware-normalized form of the headline tokens/s
+        # a 134M decoder: large enough that the flash kernels engage
         cfg = LlamaConfig(vocab_size=32000, hidden_size=768,
                           intermediate_size=2048, num_hidden_layers=12,
                           num_attention_heads=6,
@@ -287,42 +280,6 @@ def render_graph(graph_path, out=sys.stdout):
     w("\n")
 
 
-def diff_baseline(payload, baseline_path, out=sys.stdout):
-    w = out.write
-    try:
-        with open(baseline_path) as f:
-            base = json.load(f)
-    except (OSError, ValueError) as e:
-        w("== baseline %s unreadable: %s ==\n" % (baseline_path, e))
-        return
-    if isinstance(base, list):    # model_benchmark --out artifacts
-        base = next((r for r in base if "mfu" in r), base[0] if base
-                    else {})
-    if isinstance(base, dict) and isinstance(base.get("parsed"), dict):
-        # BENCH_r*.json driver wrapper: the measurement record rides
-        # under "parsed" (next to the raw child tail)
-        base = base["parsed"]
-    row = payload.get("smoke") or {}
-    train = (payload.get("jobs") or {}).get("train") or {}
-    cur_mfu = row.get("mfu", train.get("mfu"))
-    cur_hbm = row.get("hbm_peak_bytes", train.get("hbm_peak_bytes"))
-    w("== vs baseline %s ==\n" % os.path.basename(baseline_path))
-    if "mfu" not in base:
-        w("  baseline has no mfu field (pre-perf-round artifact; "
-          "measured_at=%s) — this run seeds the MFU trajectory\n"
-          % base.get("measured_at"))
-    elif cur_mfu:
-        delta = (cur_mfu / base["mfu"] - 1.0) * 100 if base["mfu"] else 0
-        w("  mfu        %.5f -> %.5f  (%+.1f%%)\n"
-          % (base["mfu"], cur_mfu, delta))
-    if "hbm_peak_bytes" in base and cur_hbm:
-        w("  hbm peak   %s -> %s\n"
-          % (_fmt_bytes(base["hbm_peak_bytes"]), _fmt_bytes(cur_hbm)))
-    for k in ("value", "measured_at", "backend"):
-        if k in base:
-            w("  baseline %-12s %s\n" % (k, base[k]))
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     src = ap.add_mutually_exclusive_group()
@@ -336,8 +293,6 @@ def main(argv=None):
     ap.add_argument("--json", action="store_true",
                     help="print the payload JSON instead of the report")
     ap.add_argument("--out", help="also write the payload JSON here")
-    ap.add_argument("--baseline",
-                    help="BENCH_*.json to diff mfu/hbm against")
     ap.add_argument("--graph", default=None,
                     help="pthlo artifact for the collective/donation "
                          "columns (default: tools/graph_report.json "
@@ -361,8 +316,6 @@ def main(argv=None):
         print(json.dumps(payload, default=str))
     else:
         render(payload)
-    if a.baseline:
-        diff_baseline(payload, a.baseline)
     graph_path = a.graph
     if graph_path is None:
         default = os.path.join(os.path.dirname(os.path.abspath(

@@ -69,16 +69,15 @@ def _first_divergence(a, b):
 
 
 def _build_model(model_meta):
-    """The benchmark's model ctor path: seed, then config kwargs. The
-    seed reset makes weight init bit-reproducible — replay's whole
+    """The recording side's model ctor path: seed, then config kwargs.
+    The seed reset makes weight init bit-reproducible — replay's whole
     premise."""
     import paddle_tpu as paddle
     from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 
     if not model_meta or "config" not in model_meta:
         raise SystemExit(
-            "journal carries no model meta (record with "
-            "serving_benchmark --record-out, or note_model() a "
+            "journal carries no model meta (note_model() a "
             "{'config': {...}, 'seed': N} block before write_journal)")
     paddle.seed(int(model_meta.get("seed", 0)))
     cfg = LlamaConfig(use_parallel=False, **model_meta["config"])

@@ -12,9 +12,9 @@ its own MetricsServer —
     GET  /debugz/router          replica + affinity + request counters
     GET  /debugz/router/replicas per-replica table
 
-Replica mode (``--replica``): the worker process the benchmark forks
-(and a multi-host launcher runs one-per-host; one process per chip —
-two replicas cannot share a TPU host yet). Builds the preset model
+Replica mode (``--replica``): the worker process a multi-host launcher
+runs one-per-host (one process per chip — two replicas cannot share a
+TPU host yet). Builds the preset model
 + ``serving.Engine``, wraps it in ``fleet.Replica`` — which announces
 the endpoint in the store, heartbeats the liveness lease, and serves
 the enqueue/result/load protocol until SIGTERM (handled as a graceful
@@ -40,7 +40,15 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(
     os.path.abspath(__file__)), ".."))
 
-from serving_benchmark import PRESETS  # noqa: E402
+PRESETS = {
+    # geometry only: the weights are random
+    "tiny": dict(hidden_size=64, intermediate_size=128,
+                 num_hidden_layers=2, num_attention_heads=4,
+                 vocab_size=256, max_position_embeddings=256),
+    "llama1b": dict(hidden_size=2048, intermediate_size=5504,
+                    num_hidden_layers=22, num_attention_heads=16,
+                    vocab_size=32000, max_position_embeddings=2048),
+}
 
 
 def _store_from(spec, timeout_s=10.0):
@@ -62,8 +70,8 @@ def run_replica(args):
 
     # One process per chip: this replica takes every chip JAX shows it
     # and serves from device 0. A second replica on the same TPU host
-    # cannot start until something pins each to its own chip (the
-    # benchmark's --fleet parent refuses there; ROADMAP D5/R2).
+    # cannot start until something pins each to its own chip (ROADMAP
+    # D5/R2).
     compile_cache.configure()
     sys.stderr.write("replica %d: device 0 of %d %s device(s)\n"
                      % (args.rank, len(jax.devices()),
@@ -88,8 +96,8 @@ def run_replica(args):
     signal.signal(signal.SIGTERM, _term)
     signal.signal(signal.SIGINT, _term)
     rep.start()
-    # announce on stdout for the forking parent (benchmark): one JSON
-    # line, then serve until a signal lands
+    # announce on stdout for a forking parent: one JSON line, then
+    # serve until a signal lands
     print(json.dumps({"rank": rep.rank, "url": rep.url,
                       "generation": rep.generation,
                       "pid": os.getpid()}), flush=True)
