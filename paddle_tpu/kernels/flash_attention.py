@@ -24,6 +24,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -428,32 +429,59 @@ def _reference_attention(q, k, v, scale, causal, segs=None):
     return jnp.einsum("bnm,bmd->bnd", p.astype(v.dtype), v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash_core(q, k, v, segs, scale, causal, block_q, block_k,
-                interpret):
-    out, _ = _flash_fwd_bhnd(q, k, v, scale, causal, block_q, block_k,
-                             interpret, segs=segs)
+# checkpoint names of the forward kernel's two outputs: what a recompute
+# policy keeps (models/llama.py _remat_layer) so that a checkpointed layer
+# does not run the kernel again in its backward pass
+FLASH_SAVED_NAMES = ("flash_out", "flash_lse")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+def _flash_carry(q, k, v, segs, out, lse, scale, causal, block_q, block_k,
+                 interpret):
+    """`out`, with the blocked backward as its VJP in q, k, v. The forward
+    kernel has already run (_flash_core); this only carries its outputs to
+    the backward kernels as residuals."""
     return out
 
 
-def _flash_core_fwd(q, k, v, segs, scale, causal, block_q, block_k,
-                    interpret):
-    out, lse = _flash_fwd_bhnd(q, k, v, scale, causal, block_q, block_k,
-                               interpret, segs=segs)
+def _flash_carry_fwd(q, k, v, segs, out, lse, scale, causal, block_q,
+                     block_k, interpret):
     return out, (q, k, v, segs, out, lse)
 
 
-def _flash_core_bwd(scale, causal, block_q, block_k, interpret, res, g):
+def _flash_carry_bwd(scale, causal, block_q, block_k, interpret, res, g):
     q, k, v, segs, out, lse = res
     # Pallas blocked backward: O(N) memory, never materializes [N, N]
     dq, dk, dv = _flash_bwd_bhnd(q, k, v, out, lse, g, scale, causal,
                                  block_q, block_k, interpret, segs=segs)
     dsegs = (None if segs is None
              else jnp.zeros(segs.shape, jax.dtypes.float0))
-    return dq, dk, dv, dsegs
+    # out and lse came from stop_gradient-ed operands: no cotangent (None)
+    # for them, all of the gradient flows through dq, dk, dv
+    return dq, dk, dv, dsegs, None, None
 
 
-_flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
+_flash_carry.defvjp(_flash_carry_fwd, _flash_carry_bwd)
+
+
+def _flash_core(q, k, v, segs, scale, causal, block_q, block_k,
+                interpret):
+    """[BH, N, D] attention: one forward-kernel call, then _flash_carry.
+
+    The forward kernel sits outside the custom_vjp on purpose: values born
+    inside a custom_vjp's forward rule are invisible to a `jax.checkpoint`
+    policy, and out here `out` and `lse` carry names (FLASH_SAVED_NAMES)
+    that `save_only_these_names` can keep. With no enclosing checkpoint
+    the names are identities and the residuals are q, k, v, segs, out,
+    lse; with no gradient the whole function is the one `flash_fwd` call."""
+    out, lse = _flash_fwd_bhnd(
+        jax.lax.stop_gradient(q), jax.lax.stop_gradient(k),
+        jax.lax.stop_gradient(v), scale, causal, block_q, block_k,
+        interpret, segs=segs)
+    out = checkpoint_name(out, FLASH_SAVED_NAMES[0])
+    lse = checkpoint_name(lse, FLASH_SAVED_NAMES[1])
+    return _flash_carry(q, k, v, segs, out, lse, scale, causal, block_q,
+                        block_k, interpret)
 
 
 def flash_attention(q, k, v, causal=False, scale=None,

@@ -76,9 +76,11 @@ class LlamaConfig:
         # reference snapshot lacks (SURVEY §5)
         self.sequence_parallel = sequence_parallel
         # activation recompute per decoder layer (reference fleet
-        # recompute / --recompute flag): trades ~1/3 extra FLOPs for
-        # O(layers * B*S*H) activation memory — required to train ~1B+
-        # params on one 16GB v5e chip
+        # recompute / --recompute flag): a layer keeps its input and its
+        # attention output, two [B, S, H] tensors, and the backward pass
+        # runs the rest of its forward again — ~1/3 extra FLOPs less the
+        # attention kernel's, for O(layers * B*S*H) activation memory;
+        # required to train ~1B+ params on one 16GB v5e chip
         self.recompute = recompute
 
     @classmethod
@@ -324,12 +326,18 @@ class LlamaDecoderLayer(Layer):
 
 
 def _remat_layer(layer, x):
-    """Per-layer activation recompute. Two engines, one policy (same split
-    as static/__init__.py RecomputeContext vs fleet/recompute.py):
+    """Per-layer activation recompute. Two engines (same split as
+    static/__init__.py RecomputeContext vs fleet/recompute.py):
     - compiled path (CompiledTrainStep traces under no_grad + jax.grad):
-      wrap the layer body in jax.checkpoint so XLA rematerializes its
-      activations during the backward schedule;
-    - eager-tape path: route through the autograd engine's recompute().
+      wrap the layer body in jax.checkpoint. A recomputed layer keeps its
+      input and its attention output (the flash kernel's `out` and `lse`,
+      kernels/flash_attention.py FLASH_SAVED_NAMES): the backward pass
+      rebuilds norms, projections, rope and the MLP, but does not run the
+      forward attention kernel a second time only to hand its output to
+      the backward kernels. Where attention takes the XLA fallback there
+      is nothing under those names and only the input is kept;
+    - eager-tape path: route through the autograd engine's recompute(),
+      which keeps the input alone.
     """
     from ..core.dispatch import tape_enabled
 
@@ -340,11 +348,13 @@ def _remat_layer(layer, x):
     import jax
 
     from ..core.tensor import Tensor
+    from ..kernels.flash_attention import FLASH_SAVED_NAMES
 
     def body(xv, _l=layer):
         return _l(Tensor(xv))._value
 
-    return Tensor(jax.checkpoint(body)(x._value))
+    keep = jax.checkpoint_policies.save_only_these_names(*FLASH_SAVED_NAMES)
+    return Tensor(jax.checkpoint(body, policy=keep)(x._value))
 
 
 class LlamaModel(Layer):

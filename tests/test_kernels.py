@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import paddle_tpu as paddle
 from paddle_tpu.distributed import mesh as pmesh
 from paddle_tpu.kernels.flash_attention import (
+    FLASH_SAVED_NAMES,
     _reference_attention,
     flash_attention,
 )
@@ -250,6 +251,145 @@ class TestFlashPallasBackward:
         for mine, ref in zip(vjp(g), ref_vjp(g)):
             np.testing.assert_allclose(np.asarray(mine), np.asarray(ref),
                                        rtol=5e-3, atol=5e-4)
+
+
+class TestForwardKernelOutsideTheVjp:
+    """``_flash_core`` runs the forward kernel outside its custom_vjp
+    and names ``out`` and ``lse`` (FLASH_SAVED_NAMES), so a checkpoint
+    policy can keep them. Value and VJP are what they were: held to
+    ``_reference_attention`` on each form the kernel takes."""
+
+    @pytest.mark.parametrize("form", ["causal", "segmented",
+                                      "kv_len_differs"])
+    def test_value_and_vjp_match_the_reference(self, form):
+        b, n, h, d = 1, 256, 2, 128
+        kv_n = 384 if form == "kv_len_differs" else n
+        q, k, v = map(jnp.asarray, _qkv(b, n, h, d, kv_n=kv_n))
+        g = jnp.asarray(RNG.rand(b, n, h, d).astype(np.float32))
+        segment_ids = None
+        if form == "segmented":
+            segment_ids = jnp.asarray(
+                np.repeat([[0, 1, 2]], [100, 28, 128], axis=1), jnp.int32)
+
+        def fold(x):
+            return jnp.swapaxes(x, 1, 2).reshape(b * h, x.shape[1], d)
+
+        def kernel(q_, k_, v_):
+            return flash_attention(q_, k_, v_, causal=True, block_q=128,
+                                   block_k=128, interpret=True,
+                                   segment_ids=segment_ids)
+
+        def reference(q_, k_, v_):
+            segs = None
+            if segment_ids is not None:
+                segs = jnp.broadcast_to(segment_ids[:, None, :],
+                                        (b, h, n)).reshape(b * h, n)
+            out = _reference_attention(fold(q_), fold(k_), fold(v_),
+                                       1.0 / np.sqrt(d), True, segs=segs)
+            return jnp.swapaxes(out.reshape(b, h, n, d), 1, 2)
+
+        out, vjp = jax.vjp(kernel, q, k, v)
+        ref_out, ref_vjp = jax.vjp(reference, q, k, v)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
+                                   rtol=2e-4, atol=2e-5)
+        for mine, ref in zip(vjp(g), ref_vjp(g)):
+            np.testing.assert_allclose(np.asarray(mine), np.asarray(ref),
+                                       rtol=5e-4, atol=5e-5)
+
+    def test_no_gradient_is_one_forward_call(self):
+        q, k, v = map(jnp.asarray, _qkv(1, 128, 2, 128))
+        text = str(jax.make_jaxpr(lambda *a: flash_attention(
+            *a, causal=True, interpret=True))(q, k, v))
+        assert text.count("name=flash_fwd") == 1
+        assert "name=flash_dq" not in text
+
+    def test_without_a_checkpoint_the_residuals_are_what_they_were(self):
+        """q, k, v, out, lse and nothing else: one forward call in the
+        gradient, its two outputs named."""
+        q, k, v = map(jnp.asarray, _qkv(1, 128, 2, 128))
+        text = str(jax.make_jaxpr(jax.grad(lambda *a: flash_attention(
+            *a, causal=True, interpret=True).sum(), argnums=(0, 1, 2)))(
+                q, k, v))
+        assert text.count("name=flash_fwd") == 1
+        assert text.count("name=flash_dq") == 1
+        assert text.count("name=flash_dkv") == 1
+        for name in FLASH_SAVED_NAMES:
+            assert "name=%s" % name in text
+
+
+class TestRecomputedLayerKeepsAttentionOutput:
+    """``models/llama.py`` ``_remat_layer``: a checkpointed decoder layer
+    keeps its input and the flash kernel's ``out`` and ``lse``, so the
+    backward pass holds one forward-kernel call a layer (two under a
+    bare ``jax.checkpoint``), and the arithmetic is the same."""
+
+    LAYERS = 2
+
+    @pytest.fixture
+    def grads(self, monkeypatch):
+        """recompute -> (gradient function of (state values, ids), state
+        values) of a two-layer llama with heads of 128, whose attention
+        takes the Pallas path in the interpreter."""
+        from paddle_tpu.core.dispatch import no_grad
+        from paddle_tpu.core.tensor import Tensor
+        from paddle_tpu.kernels import flash_attention as fa
+        from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+
+        # the attention dispatch asks the backend before it takes the
+        # kernel, and the kernel asks it again to pick Mosaic
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(fa, "resolve_interpret", lambda _: True)
+
+        def build(recompute):
+            paddle.seed(0)
+            model = LlamaForCausalLM(LlamaConfig(
+                vocab_size=256, hidden_size=256, intermediate_size=512,
+                num_hidden_layers=self.LAYERS, num_attention_heads=2,
+                num_key_value_heads=1, max_position_embeddings=256,
+                use_parallel=False, recompute=recompute))
+            names, values = model.functional_state()
+
+            def loss(values, ids, labels):
+                with model.bind_state(names, list(values)), no_grad():
+                    return model(Tensor(ids), Tensor(labels))._value
+
+            return jax.value_and_grad(loss), values
+
+        return build
+
+    def test_one_forward_kernel_a_layer_and_the_same_gradients(self, grads):
+        ids = jnp.asarray(RNG.randint(0, 256, (2, 128)), jnp.int32)
+        labels = jnp.roll(ids, -1, axis=1)
+        results = {}
+        for recompute in (False, True):
+            fn, values = grads(recompute)
+            text = str(jax.make_jaxpr(fn)(values, ids, labels))
+            assert text.count("name=flash_fwd") == self.LAYERS, recompute
+            assert text.count("name=flash_dq") == self.LAYERS
+            assert text.count("name=flash_dkv") == self.LAYERS
+            assert ("prevent_cse=" in text) == recompute   # a remat
+            # primitive by primitive: under jit XLA's CPU backend fuses
+            # the recomputed forward differently (1e-9 apart in float32)
+            with jax.disable_jit():
+                results[recompute] = fn(values, ids, labels)
+        (loss, grad), (loss_r, grad_r) = results[False], results[True]
+        assert float(loss) == float(loss_r)
+        assert len(grad) == len(grad_r) > 0
+        for plain, remat in zip(grad, grad_r):
+            np.testing.assert_array_equal(np.asarray(plain),
+                                          np.asarray(remat))
+
+    def test_a_bare_checkpoint_runs_the_kernel_twice(self, grads,
+                                                     monkeypatch):
+        """What the policy is for: with no names kept the same model's
+        gradient holds two forward-kernel calls a layer."""
+        monkeypatch.setattr(
+            jax.checkpoint_policies, "save_only_these_names",
+            lambda *names: jax.checkpoint_policies.nothing_saveable)
+        fn, values = grads(True)
+        ids = jnp.zeros((2, 128), jnp.int32)
+        text = str(jax.make_jaxpr(fn)(values, ids, ids))
+        assert text.count("name=flash_fwd") == 2 * self.LAYERS
 
 
 class TestFlashMinHeadDimFlag:

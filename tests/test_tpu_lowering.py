@@ -422,6 +422,80 @@ class TestBlocksAreNamed:
         assert trace_reduce.kernel_counts(text) == found
 
 
+def _bf16_zeros(self, shape, dtype=None, name=None):
+    """``Initializer.create`` for a model that is only compiled: nothing
+    runs, so every parameter is bf16 zeros and not a seeded draw."""
+    from paddle_tpu.core.tensor import Parameter
+
+    return Parameter(jnp.zeros(tuple(int(s) for s in shape), BF16),
+                     name=name)
+
+
+class TestRecomputedStepRunsFlashFwdOnce:
+    """``mistral7b-pretrain-4k``'s forward and backward (the published
+    widths, two layers, recompute on, 2 x 4096 tokens) compiled for the
+    v5e: a checkpointed layer keeps the flash kernel's output
+    (``models/llama.py`` ``_remat_layer``), so the program holds one
+    ``flash_fwd`` a layer beside one ``flash_dq`` and one ``flash_dkv``;
+    under a bare ``jax.checkpoint`` it held two. The same model with no
+    gradient is one ``flash_fwd`` a layer, as every prefill is."""
+
+    LAYERS, BATCH, SEQ = 2, 2, 4096
+
+    @pytest.fixture(scope="class")
+    def programs(self, v5e):
+        """{"train" | "forward": compiled HLO text}."""
+        from paddle_tpu.core.dispatch import no_grad
+        from paddle_tpu.core.tensor import Tensor
+        from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+        from paddle_tpu.nn import initializer
+
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(initializer.Initializer, "create", _bf16_zeros)
+            # the attention dispatch asks the backend; here it is the CPU
+            patch.setattr(jax, "default_backend", lambda: "tpu")
+            model = LlamaForCausalLM(LlamaConfig(
+                vocab_size=32768, hidden_size=4096,
+                intermediate_size=14336, num_hidden_layers=self.LAYERS,
+                num_attention_heads=32, num_key_value_heads=8,
+                max_position_embeddings=32768, rope_theta=1e6,
+                use_parallel=False, dtype="bfloat16", recompute=True))
+            names, values = model.functional_state()
+
+            def loss(values, ids, labels):
+                with model.bind_state(names, list(values)), no_grad():
+                    return model(Tensor(ids), Tensor(labels))._value
+
+            def logits(values, ids):
+                with model.bind_state(names, list(values)), no_grad():
+                    return model(Tensor(ids))._value
+
+            avals = [jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=v5e)
+                     for v in values]
+            ids = jax.ShapeDtypeStruct((self.BATCH, self.SEQ), I32,
+                                       sharding=v5e)
+            return {
+                "train": jax.jit(jax.value_and_grad(loss)).lower(
+                    avals, ids, ids).compile().as_text(),
+                "forward": jax.jit(logits).lower(
+                    avals, ids).compile().as_text(),
+            }
+
+    def test_one_forward_kernel_a_layer_under_recompute(self, programs):
+        assert mosaic_kernels(programs["train"]) == {
+            "flash_fwd": self.LAYERS, "flash_dq": self.LAYERS,
+            "flash_dkv": self.LAYERS}
+        for layer in range(self.LAYERS):
+            assert ('/jvp(layer_%d)/attn/flash_fwd/pallas_call"' % layer
+                    in programs["train"])
+        # none of them is a backward pass's recomputation
+        assert 'checkpoint/attn/flash_fwd' not in programs["train"]
+
+    def test_no_gradient_is_one_forward_kernel_a_layer(self, programs):
+        assert mosaic_kernels(programs["forward"]) == {
+            "flash_fwd": self.LAYERS}
+
+
 class TestPrefillHeadOnOneRow:
     """``mistral7b-chat-backlog``'s largest prefill (bucket 2048, the
     published widths, one layer) compiled for the v5e: the engine names
@@ -437,15 +511,11 @@ class TestPrefillHeadOnOneRow:
     def test_no_logits_of_the_whole_bucket(self, v5e, monkeypatch):
         from paddle_tpu import serving
         from paddle_tpu.core.dispatch import no_grad
-        from paddle_tpu.core.tensor import Parameter, Tensor
+        from paddle_tpu.core.tensor import Tensor
         from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
         from paddle_tpu.nn import initializer
 
-        # nothing runs: every parameter is bf16 zeros, not a seeded draw
-        monkeypatch.setattr(
-            initializer.Initializer, "create",
-            lambda self, shape, dtype=None, name=None: Parameter(
-                jnp.zeros(tuple(int(s) for s in shape), BF16), name=name))
+        monkeypatch.setattr(initializer.Initializer, "create", _bf16_zeros)
         # the attention dispatch asks the backend; here it is the CPU
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         model = LlamaForCausalLM(LlamaConfig(
