@@ -11,6 +11,8 @@ of the llama1b geometry (hidden 2048, 22 layers, 16 heads x 128, vocab
   train_fused_ce  the same step under FLAGS_fused_lm_head_ce, 2 steps
   serve           serving.Engine, 8 greedy requests, split prefill/decode
   serve_mixed     serving.Engine under prefix cache + chunked prefill
+  serve_mla       serving.Engine over a latent page cache: the tiny
+                  DeepSeek-V2 preset through mla_decode
   train4          dp=2 x mp=2 on four chips (skipped below four)
 
 Each phase checks what came out by the repo's own means — finite
@@ -345,22 +347,31 @@ def compare_paged_kernel(eng, geom, mixed):
         want = paged_attention_reference(q, pool.k, pool.v, bt,
                                          jnp.asarray(lens))
         valid = (lens > 0)[:, None, None]
+    check_kernel_output("mixed" if mixed else "decode", got, want, valid,
+                        lens)
+
+
+def check_kernel_output(name, got, want, valid, lens):
+    """A paged kernel's output against its jnp reference where ``valid``
+    (broadcastable to both): finite, and within ATTN_ATOL + ATTN_RTOL."""
+    import numpy as np
+
     got = np.asarray(got, np.float32)
     want = np.asarray(want, np.float32)
     excess = np.where(valid, np.abs(got - want)
                       - (ATTN_ATOL + ATTN_RTOL * np.abs(want)), -1.0)
     log("  %s kernel vs reference on live layer-0 pool (lens %s): max "
         "|diff| %.3e, max |ref| %.3e (atol %.0e + rtol %.0e)"
-        % ("mixed" if mixed else "decode", lens.tolist(),
+        % (name, lens.tolist(),
            float(np.where(valid, np.abs(got - want), 0).max()),
            float(np.where(valid, np.abs(want), 0).max()),
            ATTN_ATOL, ATTN_RTOL))
     if not np.isfinite(got[np.broadcast_to(valid, got.shape)]).all():
-        raise AssertionError("paged kernel produced non-finite values")
+        raise AssertionError("%s kernel produced non-finite values" % name)
     if excess.max() > 0:
         raise AssertionError(
-            "paged kernel disagrees with the reference by %.3e beyond "
-            "tolerance" % float(excess.max()))
+            "%s kernel disagrees with the reference by %.3e beyond "
+            "tolerance" % (name, float(excess.max())))
 
 
 def check_greedy_parity(model, prompt, generated):
@@ -477,6 +488,76 @@ def phase_serve(geom, mixed=False, on_chip=True):
                         "mixed step" if mixed else "decode step")
 
 
+def phase_serve_mla(on_chip=True, dtype="bfloat16"):
+    """serving.Engine on device 0 over a latent page cache: the tiny
+    DeepSeek-V2 preset (``DeepseekV2Config.tiny``: a latent of 128 + 16
+    values a token for 8 heads, tileable as it stands), prefill over
+    expanded heads, decode through ``mla_decode``; the kernel (the
+    interpreter off-chip) against the jnp reference on layer 0's live
+    pool, and the engine's tokens against the model's dense forward."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu import serving
+    from paddle_tpu.models.deepseek_v2 import (DeepseekV2Config,
+                                               DeepseekV2ForCausalLM)
+    from paddle_tpu.serving.kernels.mla_attention import (
+        mla_attention_kernel,
+        mla_attention_reference,
+    )
+    from paddle_tpu.serving.scheduler import RequestState
+
+    paddle.seed(SEED)
+    cfg = DeepseekV2Config.tiny(dtype=dtype)
+    model = DeepseekV2ForCausalLM(cfg)
+    model.eval()
+    eng = serving.Engine(model, max_slots=4, num_blocks=64, block_size=16,
+                         max_model_len=256)
+    rng = np.random.RandomState(SEED + 2)
+    prompts = [rng.randint(0, cfg.vocab_size, n).tolist()
+               for n in (5, 40, 100)]         # a slot stays idle
+    rids = [eng.add_request(p, 12) for p in prompts]
+    compared = False
+    while eng.has_work():
+        eng.step()
+        if not compared and all(
+                eng.requests[r].state is RequestState.DECODING
+                for r in rids):
+            plane = eng.cache.pools[0].rows
+            lens = np.array(eng.cache.seq_lens)
+            q = jax.random.normal(
+                jax.random.PRNGKey(SEED + 7),
+                (eng.max_slots, cfg.num_attention_heads, plane.shape[2]),
+                plane.dtype)
+            args = (q, plane, jnp.asarray(eng.cache.block_tables),
+                    jnp.asarray(lens))
+            kw = dict(scale=0.1, rank=cfg.kv_lora_rank)
+            got = np.asarray(mla_attention_kernel(*args, **kw), np.float32)
+            live = (lens > 0)[:, None, None]
+            check_kernel_output("mla_decode", got,
+                                mla_attention_reference(*args, **kw), live,
+                                lens)
+            if got[lens == 0].any():
+                raise AssertionError("mla_decode: an idle slot's output "
+                                     "is not zero")
+            compared = True
+    if not compared:
+        raise AssertionError("never saw every request decoding at once")
+    stats = eng.stats()
+    log("  %d requests; decode_compiles %d, decode_steps %d; latent %s"
+        % (len(rids), stats["decode_compiles"], stats["decode_steps"],
+           stats["latent"]))
+    if stats["decode_compiles"] != 1:
+        raise AssertionError(
+            "decode_compiles == %d, not 1" % stats["decode_compiles"])
+    check_greedy_parity(model, prompts[2], eng.output(rids[2]))
+    if on_chip:    # after the stats: lowering traces once more
+        require_kernels(eng.hot_step_hlo(), ["mla_decode", "moe_gmm"],
+                        "latent decode step")
+
+
 # -- driver ------------------------------------------------------------------
 
 class Phases:
@@ -540,6 +621,7 @@ def main():
         phases.blocked("train_fused_ce", "needs the train phase's loss")
     phases.run("serve", phase_serve, geom)
     phases.run("serve_mixed", phase_serve, geom, mixed=True)
+    phases.run("serve_mla", phase_serve_mla)
     if not four:
         log("train4: skipped (device_count=%d)" % len(devices))
     elif losses:

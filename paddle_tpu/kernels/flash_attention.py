@@ -6,7 +6,10 @@ fmha wrappers): blocked online-softmax attention that never materializes the
 [N, N] score matrix in HBM. The forward is a Pallas kernel with a
 (batch*head, q_block, kv_block) grid — K/V are streamed one (block_k, d)
 tile at a time with the running max/denominator/accumulator held in VMEM
-scratch, so context length is bounded by HBM, not VMEM. The backward is
+scratch, so context length is bounded by HBM, not VMEM. v may have a
+head dim of its own (q and k [.., D], v and the output [.., Dv]: latent
+attention's expanded heads are 192 wide in q/k and 128 in v); nothing is
+padded. The backward is
 also Pallas (FlashAttention-2-style): the forward saves the softmax
 log-sum-exp, and two blocked kernels produce dq (q-major grid) and dk/dv
 (kv-major grid) with fp32 VMEM accumulators — O(N) memory end to end; the
@@ -79,11 +82,11 @@ def _dot(a, b, dims, batch=((), ())):
 def _fa_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_k,
                segmented):
     """One (bh, q_block, kv_block) program. Refs: q [1, bq, d];
-    k/v [1, block_k, d]; optional segment-id refs sq [1, 1, bq], sk
-    [1, 1, block_k] (ragged/packed sequences: tokens attend only within
-    their segment — the serving varlen path); o [1, bq, d]; lse [1, bq]
-    (softmax log-sum-exp, saved for the Pallas backward); scratch m/l
-    [bq, 128], acc [bq, d]."""
+    k [1, block_k, d]; v [1, block_k, dv]; optional segment-id refs sq
+    [1, 1, bq], sk [1, 1, block_k] (ragged/packed sequences: tokens
+    attend only within their segment — the serving varlen path); o
+    [1, bq, dv]; lse [1, bq] (softmax log-sum-exp, saved for the Pallas
+    backward); scratch m/l [bq, 128], acc [bq, dv]."""
     if segmented:
         sq_ref, sk_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -144,10 +147,12 @@ def _fa_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_k,
 
 def _flash_fwd_bhnd(q, k, v, scale, causal, block_q, block_k, interpret,
                     segs=None):
-    """q,k,v: [BH, N, D] (heads folded into batch); segs: optional
-    [BH, N] int32 segment ids (ragged/packed attention)."""
+    """q,k: [BH, N, D], v: [BH, N, Dv] (heads folded into batch); segs:
+    optional [BH, N] int32 segment ids (ragged/packed attention).
+    -> (out [BH, N, Dv], lse [BH, 1, N])."""
     bh, n, d = q.shape
     kv_len = k.shape[1]
+    dv = v.shape[2]
     grid = (bh, n // block_q, kv_len // block_k)
     segmented = segs is not None
     kernel = functools.partial(
@@ -158,7 +163,7 @@ def _flash_fwd_bhnd(q, k, v, scale, causal, block_q, block_k, interpret,
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0),
+        pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, 0),
                      memory_space=pltpu.VMEM),
     ]
     args = [q, k, v]
@@ -176,13 +181,13 @@ def _flash_fwd_bhnd(q, k, v, scale, causal, block_q, block_k, interpret,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, n, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, n, dv), q.dtype),
             # lse as [bh, 1, n]: the singleton axis keeps the (1, block_q)
             # tail of the block equal-to-array-dim / lane-aligned (Mosaic
             # tiling rule)
@@ -191,7 +196,7 @@ def _flash_fwd_bhnd(q, k, v, scale, causal, block_q, block_k, interpret,
         scratch_shapes=[
             pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
             pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -202,7 +207,8 @@ def _flash_fwd_bhnd(q, k, v, scale, causal, block_q, block_k, interpret,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *rest,
                scale, causal, block_k, segmented):
-    """dq pass: grid (bh, q_block, kv_block); dq accumulated in VMEM.
+    """dq pass: grid (bh, q_block, kv_block); dq accumulated in VMEM
+    (v and do are [.., dv], q, k and dq [.., d]).
     ds = p * (dout.v^T - delta); dq = scale * ds @ k (FlashAttention-2
     backward, arXiv:2307.08691 alg. 4 — public algorithm, fresh code)."""
     if segmented:
@@ -254,7 +260,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *rest,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *rest,
                 scale, causal, block_q, segmented):
-    """dk/dv pass: grid (bh, kv_block, q_block); dk/dv accumulated in VMEM.
+    """dk/dv pass: grid (bh, kv_block, q_block); dk [.., d] and dv
+    [.., dv] accumulated in VMEM.
     dv = p^T @ dout; dk = scale * ds^T @ q."""
     if segmented:
         sq_ref, sk_ref, dk_ref, dv_ref, dk_scr, dv_scr = rest
@@ -311,9 +318,11 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *rest,
 
 def _flash_bwd_bhnd(q, k, v, out, lse, g, scale, causal, block_q, block_k,
                     interpret, segs=None):
-    """Pallas backward: returns (dq, dk, dv), all [BH, N, D]."""
+    """Pallas backward: returns (dq, dk [BH, N, D], dv [BH, N, Dv]);
+    ``out`` and ``g`` are [BH, N, Dv]."""
     bh, n, d = q.shape
     kv_len = k.shape[1]
+    dv_dim = v.shape[2]
     segmented = segs is not None
     # delta[b, i] = sum_d dout * out — one fused XLA reduction
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
@@ -323,9 +332,9 @@ def _flash_bwd_bhnd(q, k, v, out, lse, g, scale, causal, block_q, block_k,
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0),
+        pl.BlockSpec((1, block_k, dv_dim), lambda b, i, j: (b, j, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
+        pl.BlockSpec((1, block_q, dv_dim), lambda b, i, j: (b, i, 0),
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i),
                      memory_space=pltpu.VMEM),
@@ -361,9 +370,9 @@ def _flash_bwd_bhnd(q, k, v, out, lse, g, scale, causal, block_q, block_k,
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0),
+        pl.BlockSpec((1, block_k, dv_dim), lambda b, j, i: (b, j, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0),
+        pl.BlockSpec((1, block_q, dv_dim), lambda b, j, i: (b, i, 0),
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i),
                      memory_space=pltpu.VMEM),
@@ -388,16 +397,16 @@ def _flash_bwd_bhnd(q, k, v, out, lse, g, scale, causal, block_q, block_k,
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0),
+            pl.BlockSpec((1, block_k, dv_dim), lambda b, j, i: (b, j, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, kv_len, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, kv_len, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, kv_len, dv_dim), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv_dim), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -408,7 +417,8 @@ def _flash_bwd_bhnd(q, k, v, out, lse, g, scale, causal, block_q, block_k,
 
 
 def _reference_attention(q, k, v, scale, causal, segs=None):
-    """[BH, N, D] fp32-statistics attention — the VJP recompute form.
+    """[BH, N, D] (v and the result [BH, N, Dv]) fp32-statistics
+    attention — the VJP recompute form.
 
     Uses the same start-aligned causal mask (and segment mask) as the
     Pallas kernel so forward and backward agree for any kv_len.
@@ -487,13 +497,15 @@ def _flash_core(q, k, v, segs, scale, causal, block_q, block_k,
 def flash_attention(q, k, v, causal=False, scale=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
                     interpret=None, segment_ids=None):
-    """q,k,v: [B, N, H, D] jax arrays. Returns [B, N, H, D].
+    """q,k: [B, N, H, D], v: [B, N, H, Dv] jax arrays (Dv is D unless v
+    has a head dim of its own). Returns [B, N, H, Dv].
 
     segment_ids: optional [B, N] int32 — ragged/packed attention
     (serving varlen batching): tokens attend only within their segment,
     composable with `causal` (packed causal LM)."""
     b, n, h, d = q.shape
     kv_n = k.shape[1]
+    dv = v.shape[3]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     interpret = resolve_interpret(interpret)
@@ -521,12 +533,12 @@ def flash_attention(q, k, v, causal=False, scale=None,
             _reference_attention(
                 jnp.swapaxes(q, 1, 2).reshape(b * h, n, d),
                 jnp.swapaxes(k, 1, 2).reshape(b * h, kv_n, d),
-                jnp.swapaxes(v, 1, 2).reshape(b * h, kv_n, d),
-                scale, causal, segs=segs).reshape(b, h, n, d), 1, 2)
+                jnp.swapaxes(v, 1, 2).reshape(b * h, kv_n, dv),
+                scale, causal, segs=segs).reshape(b, h, n, dv), 1, 2)
 
     def fold(x):
-        return jnp.swapaxes(x, 1, 2).reshape(b * h, x.shape[1], d)
+        return jnp.swapaxes(x, 1, 2).reshape(b * h, x.shape[1], x.shape[3])
 
     out = _flash_core(fold(q), fold(k), fold(v), segs, scale, causal,
                       block_q, block_k, interpret)
-    return jnp.swapaxes(out.reshape(b, h, n, d), 1, 2)
+    return jnp.swapaxes(out.reshape(b, h, n, dv), 1, 2)

@@ -122,11 +122,12 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         jax.default_backend() == "tpu"
         and attn_mask is None
         and dropout_p == 0.0
-        # validated head_dims only: 128-multiples (run on the chip) and
+        # validated head_dims only: 128-multiples (run on the chip),
         # exactly 64 (kernel-exact and Mosaic-compiled, flag-gated until
-        # a ledger row decides it) — NOT every 64-multiple (192/320 are
-        # untested lane layouts)
-        and (q.shape[-1] % 128 == 0 or q.shape[-1] == 64)
+        # a ledger row decides it) and 192 (latent attention's expanded
+        # q/k beside a 128-wide v, run on the chip) — NOT every
+        # 64-multiple (320 is an untested lane layout)
+        and (q.shape[-1] % 128 == 0 or q.shape[-1] in (64, 192))
         and q.shape[-1] >= min_d
         and q.shape[1] % 128 == 0
         and k.shape[1] % 128 == 0

@@ -33,12 +33,19 @@ _ACTIVATIONS = {"gelu": jax.nn.gelu, "relu": jax.nn.relu,
                 "silu": jax.nn.silu}
 
 
-def route(x, gate_w, top_k, norm_topk_prob):
+def route(x, gate_w, top_k, norm_topk_prob, n_group=1, topk_group=1,
+          routed_scaling_factor=1.0):
     """Softmax router in float32 over every published expert.
     x [T, D], gate_w [D, E] -> (weights [T, k], experts [T, k] int32,
     aux): the top-k probabilities (renormalised to sum 1 when
-    ``norm_topk_prob``), their experts, and the load-balance loss
-    E * sum_e (mean probability of e) * (share of first choices e)."""
+    ``norm_topk_prob``, then times ``routed_scaling_factor``), their
+    experts, and the load-balance loss
+    E * sum_e (mean probability of e) * (share of first choices e).
+    With ``n_group`` > 1 the choice is group-limited: the experts lie in
+    ``n_group`` equal contiguous groups, a group scores its largest
+    probability, and the top-k is taken inside the ``topk_group`` best
+    groups only (so a token's experts lie on at most ``topk_group`` of
+    the devices that hold a group each)."""
     gate_w = _A(gate_w)
     if x.dtype == jnp.float32 or gate_w.dtype == jnp.float32:
         logits = jnp.dot(x.astype(jnp.float32), gate_w.astype(jnp.float32),
@@ -49,18 +56,31 @@ def route(x, gate_w, top_k, norm_topk_prob):
         logits = jnp.dot(x, gate_w, preferred_element_type=jnp.float32,
                          precision=jax.lax.Precision.DEFAULT)
     probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = jax.lax.top_k(probs, top_k)
+    e = probs.shape[-1]
+    candidates = probs
+    if n_group > 1:
+        if e % n_group or not 0 < topk_group <= n_group:
+            raise ValueError("route: %d experts in %d groups, top %d of "
+                             "them" % (e, n_group, topk_group))
+        grouped = probs.reshape(-1, n_group, e // n_group)
+        _, best = jax.lax.top_k(jnp.max(grouped, axis=-1), topk_group)
+        kept = jnp.any(best[:, :, None] == jnp.arange(n_group), axis=1)
+        candidates = jnp.where(kept[:, :, None], grouped,
+                               0.0).reshape(probs.shape)
+    weights, experts = jax.lax.top_k(candidates, top_k)
     if norm_topk_prob:
         weights = weights / jnp.maximum(
             jnp.sum(weights, axis=-1, keepdims=True), 1e-9)
-    e = probs.shape[-1]
+    if routed_scaling_factor != 1.0:
+        weights = weights * routed_scaling_factor
     first = jax.nn.one_hot(experts[:, 0], e, dtype=probs.dtype)
     aux = e * jnp.sum(jnp.mean(probs, axis=0) * jnp.mean(first, axis=0))
     return weights, experts.astype(jnp.int32), aux
 
 
 def moe_forward(x, gate_w, w_in, b_in, w_out, b_out, *, top_k, lo=0,
-                activation="gelu", gated=False, norm_topk_prob=None):
+                activation="gelu", gated=False, norm_topk_prob=None,
+                n_group=1, topk_group=1, routed_scaling_factor=1.0):
     """The dropless expert layer on raw arrays.
 
     x [T, D]; gate_w [D, E] routes over all E published experts; the
@@ -69,7 +89,8 @@ def moe_forward(x, gate_w, w_in, b_in, w_out, b_out, *, top_k, lo=0,
     act(gate) * up), w_out [H, F, D]; b_in [H, F or 2F] / b_out [H, D]
     or None. ``norm_topk_prob`` None means "when top_k > 1" (a single
     choice keeps its raw probability, or the router would get no
-    gradient). -> (out [T, D], aux loss, stats int32 [3]): the share of
+    gradient); ``n_group``, ``topk_group`` and ``routed_scaling_factor``
+    are ``route``'s. -> (out [T, D], aux loss, stats int32 [3]): the share of
     the result the held experts give; pairs routed here, held experts
     that received a row, the largest load of one expert."""
     x = _A(x)
@@ -78,7 +99,9 @@ def moe_forward(x, gate_w, w_in, b_in, w_out, b_out, *, top_k, lo=0,
     held = w_in.shape[0]
     if norm_topk_prob is None:
         norm_topk_prob = top_k > 1
-    weights, experts, aux = route(x, gate_w, top_k, norm_topk_prob)
+    weights, experts, aux = route(x, gate_w, top_k, norm_topk_prob,
+                                  n_group, topk_group,
+                                  routed_scaling_factor)
     here = jnp.logical_and(experts >= lo, experts < lo + held)   # [T, k]
     # pairs sorted by held expert; the pairs of experts held elsewhere
     # sort to the end, past every group, where nothing is computed

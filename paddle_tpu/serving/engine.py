@@ -50,7 +50,8 @@ import numpy as np
 from .. import monitor as _monitor
 from ..distributed import mesh as _mesh
 from ..resilience import faultinject as _fi
-from .kv_cache import KVBlockPool, PagedKVCache, PagedMixedView
+from .kv_cache import (KVBlockPool, LatentPool, PagedKVCache,
+                       PagedMixedView)
 from . import replay as _replay
 from .metrics import EngineMetrics, now, span
 from .scheduler import Request, RequestState, Scheduler
@@ -171,20 +172,26 @@ class Engine:
             spec, num_blocks=num_blocks, block_size=block_size,
             max_slots=max_slots, max_blocks_per_slot=mb,
             quantized=self.quant_kv)
-        if self.cache.has_slot_state:
-            # a slot_state layer's row cannot be adopted from a shared
-            # prefix, fed a chunk at a time or quantized yet: refuse,
-            # rather than serve such a model wrongly
+        # a slot_state layer's row cannot be adopted from a shared
+        # prefix, fed a chunk at a time or quantized yet, and the prefix
+        # cache, the mixed step and the int8 planes are written for
+        # (k, v) pools, not latent rows: refuse, rather than serve such
+        # a model wrongly
+        for has, kind, why in (
+                (self.cache.has_slot_state, "slot_state",
+                 "a slot's recurrent state is rebuilt by a whole prefill"),
+                (self.cache.has_latent, "latent_pages",
+                 "a latent page is written by a whole prefill and a "
+                 "decode step")):
             for flag in ("FLAGS_serving_prefix_cache",
                          "FLAGS_serving_chunked_prefill",
                          "FLAGS_serving_quant_kv"):
-                if _flags.flag(flag):
+                if has and _flags.flag(flag):
                     raise ValueError(
-                        "%s with a slot_state cache layer (%s): a "
-                        "slot's recurrent state is rebuilt by a whole "
-                        "prefill, and cannot be adopted from a cached "
-                        "prefix, chunked or quantized yet"
-                        % (flag, type(model).__name__))
+                        "%s with a %s cache layer (%s): %s, and cannot "
+                        "be adopted from a cached prefix, chunked or "
+                        "quantized yet"
+                        % (flag, kind, type(model).__name__, why))
         # int8 bytes one page's k+v planes hold over every layer — the
         # dequant-bytes accounting unit for
         # serving_quant_dequant_bytes_total
@@ -317,6 +324,9 @@ class Engine:
             cache = s.cache
             entries = []
             for i, pool in enumerate(cache.pools):
+                if isinstance(pool, LatentPool):
+                    entries.append(("latent_pool/layer%d" % i, pool.rows))
+                    continue
                 if not isinstance(pool, KVBlockPool):
                     entries.extend(("state_pool/layer%d/%s" % (i, name), a)
                                    for name, a in pool.items())
@@ -587,6 +597,11 @@ class Engine:
         out = self.metrics.to_dict()
         out["state"] = (self.cache.state_stats()
                         if self.cache.has_slot_state else None)
+        out["latent"] = None
+        if self.cache.has_latent:
+            out["latent"] = dict(
+                self.cache.latent_stats(),
+                cached_tokens=self.metrics.live_tokens_mean())
         return out
 
     def request_status(self, rid):
@@ -909,7 +924,9 @@ class Engine:
             return
         out, t = done
         with span("serving.accept"):
-            self.metrics.on_decode_step(len(active))
+            self.metrics.on_decode_step(
+                len(active), int(self.cache.seq_lens.sum())
+                if self.cache.has_latent else 0)
             self._note_quant_step()
             if self._moe_layers:
                 self.metrics.on_moe_call(out[self.max_slots:], len(active),

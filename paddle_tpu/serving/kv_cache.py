@@ -20,6 +20,22 @@ release and preemption do to it:
     saved). The prefix cache and the chunked mixed step cannot adopt or
     chunk such a state yet, and the engine refuses them for a model
     that has one.
+``LatentPages`` (kind ``latent_pages``)
+    pages of ``block_size`` tokens, ONE row of ``width`` values a token
+    (latent attention's normed compressed key/value followed by the
+    roped key every head shares): one plane, no V plane, no head axis.
+    Page ids come from the same allocator and mean the same page as in
+    every other paged layer, so such a layer grows, releases and is
+    re-prefilled exactly as a kv_pages layer is. The plane's rows are
+    ``width`` rounded up to whole 128-lane tiles, the rest zero: the
+    chip's memory tiles a row that way whatever its declared width, and
+    a kernel can only cut a page out of the pool along whole tiles. A
+    prefill writes the rows and attends over the fresh expanded heads,
+    never the pool; a decode step writes one row a slot and attends
+    over the pool with every up-projection absorbed into the query
+    (serving/kernels/mla_attention.py). Prefix adoption, the chunked
+    mixed step and int8 pages have no latent form yet, and the engine
+    refuses them for a model that has such a layer.
 
 The rest of this docstring is the kv_pages kind.
 
@@ -87,6 +103,22 @@ class SlotState(NamedTuple):
 
     arrays: tuple
     kind = "slot_state"
+
+
+class LatentPages(NamedTuple):
+    """One layer's entry of a cache spec, kind ``latent_pages``: a token
+    is one row of ``width`` values."""
+
+    width: int
+    dtype: str = "float32"
+    kind = "latent_pages"
+
+
+class LatentPool(NamedTuple):
+    """One latent layer's page pool: rows [num_blocks, block_size,
+    width rounded up to 128]."""
+
+    rows: "object"
 
 
 class KVBlockPool(NamedTuple):
@@ -178,9 +210,10 @@ class BlockAllocator:
 
 
 class PagedKVCache:
-    """One pool a layer (``KVBlockPool`` for a kv_pages layer, a dict
-    of [max_slots, ...] arrays for a slot_state layer) + the host-side
-    table/length bookkeeping + the one allocator."""
+    """One pool a layer (``KVBlockPool`` for a kv_pages layer,
+    ``LatentPool`` for a latent_pages layer, a dict of [max_slots, ...]
+    arrays for a slot_state layer) + the host-side table/length
+    bookkeeping + the one allocator."""
 
     def __init__(self, layers, num_blocks, block_size, max_slots,
                  max_blocks_per_slot, quantized=False):
@@ -191,6 +224,8 @@ class PagedKVCache:
         self.quantized = bool(quantized)
         self.has_slot_state = any(
             spec.kind == "slot_state" for spec in self.layers)
+        self.has_latent = any(
+            spec.kind == "latent_pages" for spec in self.layers)
         self.num_blocks = num_blocks
         self.pools = [self._new_pool(spec) for spec in self.layers]
         self.allocator = BlockAllocator(num_blocks)
@@ -211,6 +246,10 @@ class PagedKVCache:
             return {name: jnp.zeros((self.max_slots,) + tuple(shape),
                                     jnp.dtype(dtype))
                     for name, shape, dtype in spec.arrays}
+        if spec.kind == "latent_pages":
+            return LatentPool(jnp.zeros(
+                (self.num_blocks, self.block_size,
+                 -(-spec.width // 128) * 128), jnp.dtype(spec.dtype)))
         dt = jnp.dtype("int8") if self.quantized else jnp.dtype(spec.dtype)
         page = (self.num_blocks, self.block_size, spec.num_kv_heads,
                 spec.head_dim)
@@ -225,30 +264,48 @@ class PagedKVCache:
     def state_stats(self):
         """The slot_state side, for ``Engine.stats()["state"]``."""
         pool_bytes = sum(a.nbytes for p in self.pools
-                         if not isinstance(p, KVBlockPool)
-                         for a in p.values())
+                         if isinstance(p, dict) for a in p.values())
         return {"slots": self.max_slots,
                 "layers": sum(spec.kind == "slot_state"
                               for spec in self.layers),
                 "slot_bytes": pool_bytes // self.max_slots,
                 "pool_bytes": pool_bytes}
 
+    def latent_stats(self):
+        """The latent_pages side, for ``Engine.stats()["latent"]``:
+        ``row_bytes`` is what a token takes in one layer's pool."""
+        pools = [p.rows for p in self.pools if isinstance(p, LatentPool)]
+        return {"layers": len(pools),
+                "row_bytes": pools[0].shape[2] * pools[0].dtype.itemsize,
+                "pool_bytes": sum(a.nbytes for a in pools)}
+
     # -- the per-layer hooks of one compiled step (called in its trace)
 
     def prefill_views(self, pools, table_row, true_len):
-        return [PagedPrefillView(p, table_row, self.block_size)
-                if spec.kind == "kv_pages"
-                else StatePrefillView(p, table_row[-1], true_len)
-                for spec, p in zip(self.layers, pools)]
+        def view(spec, p):
+            if spec.kind == "kv_pages":
+                return PagedPrefillView(p, table_row, self.block_size)
+            if spec.kind == "latent_pages":
+                return LatentPrefillView(p, table_row, self.block_size)
+            return StatePrefillView(p, table_row[-1], true_len)
+
+        return [view(spec, p) for spec, p in zip(self.layers, pools)]
 
     def decode_views(self, pools, block_tables, seq_lens):
         if self.has_slot_state:
             # the page kernels take the page columns, not the slot's
             block_tables = block_tables[:, :self.max_blocks_per_slot]
-        return [PagedDecodeView(p, block_tables, seq_lens, self.block_size)
-                if spec.kind == "kv_pages"
-                else StateDecodeView(p, seq_lens > 0)
-                for spec, p in zip(self.layers, pools)]
+
+        def view(spec, p):
+            if spec.kind == "kv_pages":
+                return PagedDecodeView(p, block_tables, seq_lens,
+                                       self.block_size)
+            if spec.kind == "latent_pages":
+                return LatentDecodeView(p, block_tables, seq_lens,
+                                        self.block_size)
+            return StateDecodeView(p, seq_lens > 0)
+
+        return [view(spec, p) for spec, p in zip(self.layers, pools)]
 
     def pages_needed(self, num_tokens):
         return -(-num_tokens // self.block_size)  # ceil
@@ -475,6 +532,99 @@ class PagedDecodeView:
                               v_scale=new_pool.v_scale)
         return Tensor(out[:, None]), PagedDecodeView(
             new_pool, self.block_tables, lens, self.block_size)
+
+
+def _lane_padded(x, width):
+    """``x`` [..., w] with zero columns up to ``width``."""
+    pad = width - x.shape[-1]
+    if not pad:
+        return x
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),))
+
+
+class LatentPrefillView:
+    """A latent_pages layer's hook for single-request prefill ([1, P]
+    right-padded prompt): writes every position's row through the
+    (trash-padded) block-table row in one scatter, then runs dense
+    causal attention over the fresh expanded heads the layer hands it,
+    never the pool: ``update`` then ``attend``. ``absorbed`` tells the
+    layer which form of its attention this hook takes."""
+
+    absorbed = False
+
+    def __init__(self, pool, table_row, block_size):
+        self.pool = pool
+        self.table_row = table_row            # [MB] int32, trash-padded
+        self.block_size = block_size
+
+    def update(self, rows):
+        """rows [1, P, width]: what the cache keeps of each token. ->
+        the view after the write."""
+        rv = _raw(rows)
+        pos = jnp.arange(rv.shape[1])
+        pages = self.table_row[pos // self.block_size]
+        plane = self.pool.rows
+        return LatentPrefillView(
+            LatentPool(plane.at[pages, pos % self.block_size].set(
+                _lane_padded(rv[0], plane.shape[2]).astype(plane.dtype))),
+            self.table_row, self.block_size)
+
+    def attend(self, q, k, v, scale):
+        """q, k [1, P, H, Dq] and v [1, P, H, Dv], the expanded heads
+        (all of them or some: the layer may call once a group of heads).
+        -> context [1, P, H, Dv]; rows past the true length attend only
+        forward of real tokens, so real rows are exactly the unpadded
+        computation."""
+        from ..nn import functional as F
+
+        return F.scaled_dot_product_attention(
+            _raw(q), _raw(k), _raw(v), is_causal=True, scale=scale,
+            _warn_rect_causal=False)
+
+
+class LatentDecodeView:
+    """A latent_pages layer's hook for the batched decode step ([S, 1]
+    tokens, one a slot): scatters each slot's new row into page
+    ``table[slot, len // bs]`` at offset ``len % bs`` (idle slots write
+    trash), then attends over the paged rows including the new one
+    (effective length ``len + 1``) with the absorbed query, through the
+    ``mla_decode`` kernel or its fallback: ``update`` then ``attend``."""
+
+    absorbed = True
+
+    def __init__(self, pool, block_tables, seq_lens, block_size):
+        self.pool = pool
+        self.block_tables = block_tables      # [S, MB] int32
+        self.seq_lens = seq_lens              # [S] int32
+        self.block_size = block_size
+
+    def update(self, rows):
+        """rows [S, 1, width]. -> the view after the write, its lengths
+        counting the new row."""
+        rv = _raw(rows)
+        lens = self.seq_lens
+        plane = self.pool.rows
+        pages = self.block_tables[jnp.arange(rv.shape[0]),
+                                  lens // self.block_size]
+        return LatentDecodeView(
+            LatentPool(plane.at[pages, lens % self.block_size].set(
+                _lane_padded(rv[:, 0], plane.shape[2]).astype(plane.dtype))),
+            self.block_tables, lens + 1, self.block_size)
+
+    def attend(self, q_lat, scale, rank):
+        """q_lat [S, 1, H, width], every head's query against a row; the
+        values are a row's first ``rank`` columns.
+        -> context [S, 1, H, rank]."""
+        from ..core.tensor import Tensor
+        from .kernels.mla_attention import mla_attention
+
+        plane = self.pool.rows
+        out = mla_attention(
+            _lane_padded(_raw(q_lat)[:, 0],
+                         plane.shape[2]).astype(plane.dtype),
+            plane, self.block_tables, self.seq_lens, scale=scale,
+            rank=rank)
+        return Tensor(out[:, None])
 
 
 class PagedMixedView:

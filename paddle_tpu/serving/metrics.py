@@ -52,8 +52,11 @@ here, ``to_dict()`` reduces them when asked):
       bucket, every slot of a decode step), 1 for a decode step, pairs
       a layer, experts touched a layer] for the kernel's roofline
       reader
-An empty ring gives ``None`` for its entry, never 0. (``state``, the
-slot_state side of the cache, is added by ``Engine.stats()``.)
+An empty ring gives ``None`` for its entry, never 0. (``state`` and
+``latent``, the slot_state and latent_pages sides of the cache, are
+added by ``Engine.stats()``; ``latent.cached_tokens`` is the mean over
+the recent decode steps of the tokens the cache held for the decoding
+slots, from the same ring of step rows.)
 
 Spans: ``span(name, **meta)`` IS ``jax.profiler.TraceAnnotation`` — a
 span lands in the profiler's trace, on the device trace's clock, and
@@ -460,6 +463,7 @@ class EngineMetrics:
         self.phase_s = dict.fromkeys(HOST_PHASES, 0.0)
         self._prefills_before = self.prefill_runs
         self._rows = 0
+        self._live_tokens = 0
 
     def on_step_end(self):
         """Close the open step's row. A step that found nothing to do
@@ -468,7 +472,15 @@ class EngineMetrics:
         prefills = self.prefill_runs - self._prefills_before
         if self._rows or prefills:
             self.steps.append(
-                (*self.phase_s.values(), prefills, self._rows))
+                (*self.phase_s.values(), self._live_tokens, prefills,
+                 self._rows))
+
+    def live_tokens_mean(self):
+        """Mean, over the recent steps in which a decode ran, of the
+        tokens the cache held for the decoding slots when it ran (the
+        engine counts them for a latent cache only); None while empty."""
+        live = [r[-3] for r in list(self.steps) if r[-1]]
+        return statistics.fmean(live) if live else None
 
     def on_prefill_done(self, seconds, tokens, bucket):
         self.prefills.append((seconds, tokens, bucket))
@@ -512,9 +524,10 @@ class EngineMetrics:
         self.prefill_compiles += 1
         _COMPILES.labels(fn="prefill").inc()
 
-    def on_decode_step(self, active_slots):
+    def on_decode_step(self, active_slots, live_tokens=0):
         self.decode_steps += 1
         self._rows = active_slots
+        self._live_tokens = live_tokens
         self._occupancy_sum += active_slots
         _DECODE_STEPS.inc()
         self._active_gauge.set(active_slots)

@@ -253,6 +253,56 @@ class TestFlashPallasBackward:
                                        rtol=5e-3, atol=5e-4)
 
 
+class TestFlashValueHeadDimOfItsOwn:
+    """q and k [.., D], v and the output [.., Dv] (latent attention's
+    expanded heads): ``flash_fwd`` and the backward kernels against
+    ``_reference_attention``, nothing padded."""
+
+    @pytest.mark.parametrize("d,dv,causal", [(48, 32, True),
+                                             (48, 32, False),
+                                             (32, 64, True)])
+    def test_forward_and_grads_match_the_reference(self, d, dv, causal):
+        from paddle_tpu.kernels.flash_attention import (
+            _flash_core, _reference_attention)
+
+        ks = jax.random.split(jax.random.PRNGKey(d + dv), 4)
+        bh, n = 2, 256
+        q = jax.random.normal(ks[0], (bh, n, d), jnp.float32)
+        k = jax.random.normal(ks[1], (bh, n, d), jnp.float32)
+        v = jax.random.normal(ks[2], (bh, n, dv), jnp.float32)
+        g = jax.random.normal(ks[3], (bh, n, dv), jnp.float32)
+        sc = 1.0 / np.sqrt(d)
+        out, vjp = jax.vjp(
+            lambda a, b_, c: _flash_core(a, b_, c, None, sc, causal, 64, 128,
+                                         True), q, k, v)
+        ref_out, ref_vjp = jax.vjp(
+            lambda a, b_, c: _reference_attention(a, b_, c, sc, causal),
+            q, k, v)
+        assert out.shape == (bh, n, dv)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
+                                   rtol=1e-4, atol=1e-5)
+        for mine, ref in zip(vjp(g), ref_vjp(g)):
+            assert mine.shape == ref.shape
+            np.testing.assert_allclose(np.asarray(mine), np.asarray(ref),
+                                       rtol=5e-3, atol=5e-4)
+
+    def test_public_entry_folds_each_operand_by_its_own_dim(self):
+        from paddle_tpu.kernels.flash_attention import flash_attention
+
+        ks = jax.random.split(jax.random.PRNGKey(5), 3)
+        q = jax.random.normal(ks[0], (2, 128, 3, 24), jnp.float32)
+        k = jax.random.normal(ks[1], (2, 128, 3, 24), jnp.float32)
+        v = jax.random.normal(ks[2], (2, 128, 3, 16), jnp.float32)
+        got = flash_attention(q, k, v, causal=True, scale=0.3,
+                              interpret=True)
+        # 100 rows is no multiple of a block: the fallback, same result
+        short = flash_attention(q[:, :100], k[:, :100], v[:, :100],
+                                causal=True, scale=0.3, interpret=True)
+        assert got.shape == (2, 128, 3, 16)
+        np.testing.assert_allclose(np.asarray(got[:, :100]),
+                                   np.asarray(short), rtol=1e-4, atol=1e-5)
+
+
 class TestForwardKernelOutsideTheVjp:
     """``_flash_core`` runs the forward kernel outside its custom_vjp
     and names ``out`` and ``lse`` (FLASH_SAVED_NAMES), so a checkpoint

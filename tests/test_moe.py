@@ -10,7 +10,7 @@ import jax.numpy as jnp
 
 import paddle_tpu as paddle
 from paddle_tpu.distributed import mesh as pmesh
-from paddle_tpu.parallel.moe import MoELayer, moe_forward, moe_mlp
+from paddle_tpu.parallel.moe import MoELayer, moe_forward, moe_mlp, route
 
 RNG = np.random.RandomState(3)
 
@@ -151,6 +151,90 @@ class TestMoEPrimitive:
             pairs += int(stats[0])
         np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
                                    rtol=1e-4, atol=1e-5)
+        assert pairs == t * k
+
+
+def _loop_group_router(x, gate_w, k, n_group, topk_group, scaling,
+                       renormalise):
+    """Group-limited greedy routing, one token and one group at a time:
+    {expert: weight} a token."""
+    logits = x @ gate_w
+    probs = np.exp(logits - logits.max(-1, keepdims=True))
+    probs /= probs.sum(-1, keepdims=True)
+    size = probs.shape[1] // n_group
+    out = []
+    for p in probs:
+        scores = [max(p[g * size:(g + 1) * size]) for g in range(n_group)]
+        groups = sorted(range(n_group), key=lambda g: -scores[g])[
+            :topk_group]
+        allowed = [e for g in groups for e in range(g * size,
+                                                    (g + 1) * size)]
+        chosen = sorted(allowed, key=lambda e: -p[e])[:k]
+        total = sum(p[e] for e in chosen) if renormalise else 1.0
+        out.append({e: p[e] / total * scaling for e in chosen})
+    return out
+
+
+class TestGroupLimitedRouting:
+    @pytest.mark.parametrize("n_group,topk_group,k,scaling,renormalise", [
+        (8, 3, 6, 16.0, False),     # DeepSeek-V2's
+        (4, 2, 3, 1.0, True),
+        (4, 4, 5, 2.5, False),      # every group kept: plain top-k
+        (2, 1, 4, 1.0, False),
+    ])
+    def test_route_matches_a_loop_written_router(self, n_group, topk_group,
+                                                 k, scaling, renormalise):
+        rng = np.random.RandomState(n_group * 10 + k)
+        x = rng.randn(50, 12).astype(np.float32)
+        gate_w = rng.randn(12, 32).astype(np.float32)
+        weights, experts, _ = route(
+            jnp.asarray(x), jnp.asarray(gate_w), k, renormalise, n_group,
+            topk_group, scaling)
+        want = _loop_group_router(x, gate_w, k, n_group, topk_group,
+                                  scaling, renormalise)
+        for w_row, e_row, ref in zip(np.asarray(weights),
+                                     np.asarray(experts), want):
+            assert sorted(e_row.tolist()) == sorted(ref)
+            np.testing.assert_allclose(
+                w_row, [ref[e] for e in e_row.tolist()], rtol=1e-5)
+            assert len({e // (32 // n_group) for e in e_row}) <= topk_group
+
+    def test_defaults_are_the_plain_router(self):
+        """One group, factor 1: the program of every model that does not
+        ask for groups is what it was."""
+        x = jnp.asarray(RNG.randn(9, 8).astype(np.float32))
+        gate_w = jnp.asarray(RNG.randn(8, 16).astype(np.float32))
+        plain = jax.make_jaxpr(lambda a, b: route(a, b, 4, True))(x, gate_w)
+        asked = jax.make_jaxpr(
+            lambda a, b: route(a, b, 4, True, 1, 1, 1.0))(x, gate_w)
+        assert str(plain) == str(asked)
+        assert "reshape" not in str(plain)
+
+    def test_groups_that_do_not_divide_the_experts_are_refused(self):
+        x = jnp.zeros((2, 8), jnp.float32)
+        with pytest.raises(ValueError, match="groups"):
+            route(x, jnp.zeros((8, 16), jnp.float32), 2, False, 3, 2)
+
+    def test_group_limited_shares_add_up_to_the_whole_layer(self):
+        """The eight groups' parts, each routed over all 32 experts with
+        the group limit, add up to the uncut layer."""
+        t, d, h, e, k = 24, 8, 16, 32, 6
+        x = jnp.asarray(RNG.randn(t, d).astype(np.float32))
+        gate_w = jnp.asarray(RNG.randn(d, e).astype(np.float32))
+        w1 = jnp.asarray(RNG.randn(e, d, 2 * h).astype(np.float32) * 0.3)
+        w2 = jnp.asarray(RNG.randn(e, h, d).astype(np.float32) * 0.3)
+        kw = dict(top_k=k, activation="silu", gated=True,
+                  norm_topk_prob=False, n_group=8, topk_group=3,
+                  routed_scaling_factor=16.0)
+        whole, _, _ = moe_forward(x, gate_w, w1, None, w2, None, **kw)
+        total, pairs = 0.0, 0
+        for lo in range(0, e, 4):
+            part, _, stats = moe_forward(x, gate_w, w1[lo:lo + 4], None,
+                                         w2[lo:lo + 4], None, lo=lo, **kw)
+            total = total + part
+            pairs += int(stats[0])
+        np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
+                                   rtol=1e-4, atol=1e-4)
         assert pairs == t * k
 
 

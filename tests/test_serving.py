@@ -24,7 +24,18 @@ from paddle_tpu.serving.kernels.paged_attention import (
     paged_attention_kernel,
     paged_attention_reference,
 )
-from paddle_tpu.serving.kv_cache import BlockAllocator, PagedKVCache
+from paddle_tpu.serving.kernels.mla_attention import (
+    mla_attention_kernel,
+    mla_attention_reference,
+)
+from paddle_tpu.serving.kv_cache import (
+    BlockAllocator,
+    KVBlockPool,
+    KVPages,
+    LatentPages,
+    LatentPool,
+    PagedKVCache,
+)
 
 # the package re-exports a function under the module's name
 pa_module = importlib.import_module(
@@ -285,6 +296,181 @@ class TestPagedAttentionKernel:
 # ---------------------------------------------------------------------------
 # engine vs generate parity
 # ---------------------------------------------------------------------------
+
+class TestLatentDecodeKernel:
+    """``mla_decode`` in interpret mode against its jnp reference: one
+    shared row a token, every head's absorbed query against it, values
+    from its first ``rank`` columns."""
+
+    @staticmethod
+    def _case(seed, s, h, rank, rope, bs, nb, mb, lens, dtype):
+        rng = np.random.RandomState(seed)
+        width = -(-(rank + rope) // 128) * 128
+        pool = np.zeros((nb, bs, width), np.float32)
+        # page 0 is trash: anything at all, it is never a live page
+        pool[0] = 1e3 * rng.randn(bs, width)
+        q = np.zeros((s, h, width), np.float32)
+        q[..., :rank + rope] = rng.randn(s, h, rank + rope)
+        bt = np.zeros((s, mb), np.int32)
+        alloc = BlockAllocator(nb)
+        for i, n in enumerate(lens):
+            pages = alloc.alloc(-(-n // bs)) if n else []
+            bt[i, :len(pages)] = pages
+            for page in pages:
+                pool[page, :, :rank + rope] = rng.randn(bs, rank + rope)
+        return (jnp.asarray(q, dtype), jnp.asarray(pool, dtype), bt,
+                np.asarray(lens, np.int32))
+
+    @pytest.mark.parametrize("lens,bs,mb,dtype,tol,pages_a_trip", [
+        # ragged, idle slots, one token, exact page and group boundaries
+        ([0, 1, 16, 17, 100, 128], 16, 8, jnp.float32, 2e-5, None),
+        # several trips a slot (38 pages, 8 a trip; 37 pages: the last
+        # trip short by three), idle slots between
+        ([600, 0, 0, 333, 592], 16, 40, jnp.float32, 2e-5, 8),
+        ([257, 0, 512, 0], 16, 40, jnp.float32, 2e-5, 4),
+        ([5, 0, 77, 128], 16, 8, jnp.bfloat16, 2e-2, None),
+        ([9, 3, 0, 24], 8, 4, jnp.float32, 2e-5, 2),
+    ])
+    def test_kernel_matches_reference(self, monkeypatch, lens, bs, mb,
+                                      dtype, tol, pages_a_trip):
+        if pages_a_trip:
+            mla = importlib.import_module(
+                "paddle_tpu.serving.kernels.mla_attention")
+            monkeypatch.setattr(mla, "_PAGE_VMEM_BUDGET",
+                                2 * pages_a_trip * bs * 256
+                                * jnp.dtype(dtype).itemsize)
+        q, pool, bt, ln = self._case(len(lens), len(lens), 8, 128, 64, bs,
+                                     8 + sum(-(-n // bs) for n in lens),
+                                     mb, lens, dtype)
+        got = mla_attention_kernel(q, pool, bt, ln, scale=0.07, rank=128,
+                                   interpret=True)
+        want = mla_attention_reference(q, pool, bt, ln, scale=0.07,
+                                       rank=128)
+        assert got.shape == (len(lens), 8, 128)
+        live = ln > 0
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32)[live],
+            np.asarray(want, np.float32)[live], atol=tol)
+        # an idle slot ran no trip: exact zeros, whatever trash holds
+        assert not np.asarray(got, np.float32)[~live].any()
+
+    def test_reference_is_attention_over_the_expanded_heads(self):
+        """The absorbed form against attention written the long way:
+        k_h = [row[:rank] W_uk,h | row[rank:]], v_h = row[:rank] W_uv,h."""
+        rng = np.random.RandomState(3)
+        s, h, rank, rope, nope, dv, n = 1, 4, 128, 64, 16, 16, 21
+        _, pool, bt, ln = self._case(3, s, h, rank, rope, 16, 8, 4, [n],
+                                     jnp.float32)
+        w_uk = rng.randn(rank, h, nope).astype(np.float32) * 0.1
+        w_uv = rng.randn(rank, h, dv).astype(np.float32) * 0.1
+        q_nope = rng.randn(h, nope).astype(np.float32)
+        q_pe = rng.randn(h, rope).astype(np.float32)
+        q_lat = np.zeros((1, h, 256), np.float32)
+        q_lat[0, :, :rank] = np.einsum("hn,rhn->hr", q_nope, w_uk)
+        q_lat[0, :, rank:rank + rope] = q_pe
+        got = np.asarray(mla_attention_reference(
+            jnp.asarray(q_lat), pool, bt, ln, scale=0.11, rank=rank))[0]
+        got = np.einsum("hr,rhv->hv", got, w_uv)
+        rows = np.asarray(pool)[bt[0, :2]].reshape(32, 256)[:n]
+        k_nope = np.einsum("tr,rhn->thn", rows[:, :rank], w_uk)
+        v = np.einsum("tr,rhv->thv", rows[:, :rank], w_uv)
+        scores = 0.11 * (np.einsum("hn,thn->ht", q_nope, k_nope)
+                         + q_pe @ rows[:, rank:rank + rope].T)
+        p = np.exp(scores - scores.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        np.testing.assert_allclose(got, np.einsum("ht,thv->hv", p, v),
+                                   rtol=1e-4, atol=1e-5)
+
+
+class TestLatentPagesBesideKVPages:
+    """One ``PagedKVCache`` holding both paged kinds: a page id means
+    the same page in every layer's pool, whatever the layer keeps
+    there."""
+
+    @staticmethod
+    def _cache(num_blocks=12):
+        return PagedKVCache(
+            [KVPages(2, 8, "float32"), LatentPages(40, "float32"),
+             KVPages(2, 8, "float32")], num_blocks=num_blocks,
+            block_size=4, max_slots=3, max_blocks_per_slot=6)
+
+    def test_pools_by_kind(self):
+        cache = self._cache()
+        assert cache.has_latent and not cache.has_slot_state
+        assert isinstance(cache.pools[0], KVBlockPool)
+        assert isinstance(cache.pools[1], LatentPool)
+        # 40 values a token, a row of whole 128-lane tiles in the pool
+        assert cache.pools[1].rows.shape == (12, 4, 128)
+        assert cache.block_tables.shape == (3, 6)
+        assert cache.latent_stats() == {
+            "layers": 1, "row_bytes": 512, "pool_bytes": 12 * 4 * 512}
+
+    def test_allocation_release_and_regrowth_share_one_allocator(self):
+        cache = self._cache(num_blocks=8)
+        free = cache.allocator.free_blocks
+        assert cache.ensure_capacity(0, 9) and cache.ensure_capacity(1, 4)
+        assert cache.allocator.free_blocks == free - 4
+        pages = list(cache.slot_pages(0))
+        assert cache.block_tables[0, :3].tolist() == pages
+        # out of pages: nothing allocated, the caller preempts
+        assert not cache.ensure_capacity(2, 4 * 6)
+        assert cache.allocator.free_blocks == free - 4
+        cache.release_slot(0)
+        assert cache.allocator.free_blocks == free - 1
+        assert not cache.block_tables[0].any() and cache.seq_lens[0] == 0
+        # the preempted request's re-prefill takes pages anew
+        assert cache.ensure_capacity(0, 9)
+        assert sorted(cache.slot_pages(0)) == sorted(pages)
+
+    def test_prefill_and_decode_views_write_both_kinds_through_one_table(
+            self):
+        cache = self._cache()
+        assert cache.ensure_capacity(1, 7)
+        row = jnp.asarray(cache.block_tables[1])
+        rng = np.random.RandomState(0)
+        rows = jnp.asarray(rng.randn(1, 8, 40), jnp.float32)
+        views = cache.prefill_views(cache.pools, row, jnp.int32(6))
+        assert [type(v).__name__ for v in views] == [
+            "PagedPrefillView", "LatentPrefillView", "PagedPrefillView"]
+        assert views[1].absorbed is False
+        q = jnp.asarray(rng.randn(1, 8, 2, 12), jnp.float32)
+        v = jnp.asarray(rng.randn(1, 8, 2, 8), jnp.float32)
+        after = views[1].update(rows)
+        ctx = after.attend(q, q, v, 0.3)
+        assert np.asarray(getattr(ctx, "_value", ctx)).shape == (1, 8, 2, 8)
+        pages = cache.slot_pages(1)
+        plane = np.asarray(after.pool.rows)
+        np.testing.assert_array_equal(plane[pages[0], :, :40],
+                                      np.asarray(rows)[0, :4])
+        np.testing.assert_array_equal(plane[pages[1], :, :40],
+                                      np.asarray(rows)[0, 4:])
+        assert not plane[:, :, 40:].any()
+        # a decode step: slot 1 at length 6 rewrites position 6, slot 0
+        # and 2 are idle and write trash
+        pools = [cache.pools[0], after.pool, cache.pools[2]]
+        lens = jnp.asarray([0, 6, 0], jnp.int32)
+        dviews = cache.decode_views(pools, jnp.asarray(cache.block_tables),
+                                    lens)
+        assert dviews[1].absorbed is True
+        new = jnp.asarray(rng.randn(3, 1, 40), jnp.float32)
+        q_lat = jnp.asarray(rng.randn(3, 1, 2, 40), jnp.float32)
+        dafter = dviews[1].update(new)
+        ctx = dafter.attend(q_lat, 0.3, 32)
+        assert np.asarray(ctx._value).shape == (3, 1, 2, 32)
+        plane = np.asarray(dafter.pool.rows)
+        np.testing.assert_array_equal(plane[pages[1], 2, :40],
+                                      np.asarray(new)[1, 0])
+        np.testing.assert_array_equal(plane[pages[1], 1, :40],
+                                      np.asarray(rows)[0, 5])
+        # the context is attention over positions 0..6 of slot 1's rows
+        hist = np.concatenate([np.asarray(rows)[0, :6],
+                               np.asarray(new)[1]], 0)
+        scores = 0.3 * np.asarray(q_lat)[1, 0] @ hist.T
+        p = np.exp(scores - scores.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        np.testing.assert_allclose(np.asarray(ctx._value)[1, 0],
+                                   p @ hist[:, :32], rtol=1e-4, atol=1e-5)
+
 
 class TestEngineParity:
     def test_mixed_arrival_matches_generate(self, llama):

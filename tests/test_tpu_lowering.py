@@ -34,6 +34,7 @@ from paddle_tpu.kernels import moe_gmm as mg
 
 # the package re-exports a function under the module's name
 pa = importlib.import_module("paddle_tpu.serving.kernels.paged_attention")
+mla = importlib.import_module("paddle_tpu.serving.kernels.mla_attention")
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
@@ -52,6 +53,12 @@ CELL_S, CELL_NB, CELL_MB, CELL_H, CELL_HKV = 64, 10000, 160, 32, 8
 # pairs a token (benchmark/traffic/longdoc-backlog.json)
 QWEN_S, QWEN_NB, QWEN_MB, QWEN_H, QWEN_HKV, QWEN_D = 128, 72000, 576, 16, 2, 256
 QWEN_EXPERTS, QWEN_HID, QWEN_WIDTH, QWEN_TOPK = 256, 2048, 512, 10
+# deepseekv2-longctx-backlog: 128 heads over one latent row of 512 + 64
+# values a token (640 lanes in the pool), 128 slots x 640 pages of a
+# 60,000-page pool; prefill attends over expanded heads 192 wide in q/k
+# and 128 in v (benchmark/traffic/longctx-backlog.json)
+MLA_S, MLA_NB, MLA_MB, MLA_H, MLA_RANK, MLA_W = 128, 60000, 640, 128, 512, 640
+MLA_QK, MLA_V = 192, 128
 
 
 def _flash(dtype, d, segmented=False):
@@ -112,6 +119,38 @@ def _cases():
             lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
                                                interpret=False),
             [shape, shape, shape], {"flash_fwd"}))
+
+    cases.append((
+        "mla_decode_bf16_deepseek_cell",
+        lambda q, pool, bt, ln: mla.mla_attention_kernel(
+            q, pool, bt, ln, scale=0.1147, rank=MLA_RANK, interpret=False),
+        [((MLA_S, MLA_H, MLA_W), BF16), ((MLA_NB, BS, MLA_W), BF16),
+         ((MLA_S, MLA_MB), I32), ((MLA_S,), I32)],
+        {"mla_decode"}))
+    cases.append((
+        "mla_decode_f32_tiny",
+        lambda q, pool, bt, ln: mla.mla_attention_kernel(
+            q, pool, bt, ln, scale=0.2, rank=128, interpret=False),
+        [((S, 8, 256), F32), ((NB, BS, 256), F32), ((S, MB), I32),
+         ((S,), I32)],
+        {"mla_decode"}))
+    # latent attention's expanded heads: v narrower than q and k
+    wide = lambda n, h: ((1, n, h, MLA_QK), BF16)           # noqa: E731
+    narrow = lambda n, h: ((1, n, h, MLA_V), BF16)          # noqa: E731
+    cases.append((
+        "flash_bf16_qk192_v128_fwd_8192",
+        lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
+                                           scale=0.1147, interpret=False),
+        [wide(8192, MLA_H), wide(8192, MLA_H), narrow(8192, MLA_H)],
+        {"flash_fwd"}))
+    cases.append((
+        "flash_bf16_qk192_v128_bwd",
+        lambda q, k, v: jax.grad(
+            lambda q, k, v: fa.flash_attention(
+                q, k, v, causal=True, interpret=False).astype(F32).sum(),
+            argnums=(0, 1, 2))(q, k, v),
+        [wide(N, H), wide(N, H), narrow(N, H)],
+        {"flash_fwd", "flash_dq", "flash_dkv"}))
 
     def experts(x, w1, w2, sizes):
         # one expert layer's two calls: gate/up, then down
@@ -208,6 +247,11 @@ class TestMosaicCompile:
             # partial sums, and 32 pages a group inside the VMEM limit
             assert found == {"paged_decode": 1}
             assert pa._pages_per_group(BS, CELL_HKV, D, 2, CELL_MB) == 32
+        if name == "mla_decode_bf16_deepseek_cell":
+            # one call a layer by one name, 64 pages (1024 tokens) a
+            # trip: a double buffer of 2.6 MB
+            assert found == {"mla_decode": 1}
+            assert mla._pages_per_group(BS, MLA_W, 2, MLA_MB) == 64
         if name.startswith("moe_gmm"):
             # the kernel body is jitted: both calls are one kernel name
             assert found == {"moe_gmm": 2}

@@ -297,6 +297,9 @@ BEYOND_REFERENCE = [
      "kernels.quant.quantize_int8_block"),
     ("dequantize_int8_block", "inverse of quantize_int8_block",
      "kernels.quant.dequantize_int8_block"),
+    ("mla_decode", "absorbed latent-attention decode over latent pages "
+     "(one shared row a token for every head)",
+     "serving.kernels.mla_attention.mla_attention"),
 ]
 
 

@@ -86,29 +86,34 @@ def logits_through_cache(eng, tokens, steps, slot=1):
 
 
 def program_routing(model, tokens):
-    """[chosen experts [T, k] a layer] of the program's plain forward
-    over ``tokens``: each expert layer's input, caught on its way in,
-    through the layer's own router."""
+    """[chosen experts [T, k] an expert layer] of the program's plain
+    forward over ``tokens``: each expert layer's input, caught on its
+    way in, through the layer's own router (group-limited where the
+    layer says so)."""
     import numpy as np
 
     import paddle_tpu as paddle
     from paddle_tpu.parallel.moe import route
 
     caught = []
-    hooks = [layer.mlp.register_forward_pre_hook(
+    sparse = [layer.mlp for layer in model.model.layers
+              if hasattr(layer.mlp, "experts")]
+    hooks = [mlp.register_forward_pre_hook(
         lambda _layer, inputs: caught.append(inputs[0]))
-        for layer in model.model.layers]
+        for mlp in sparse]
     try:
         model(paddle.to_tensor(np.asarray([tokens], np.int32)))
     finally:
         for hook in hooks:
             hook.remove()
     out = []
-    for layer, x in zip(model.model.layers, caught):
-        e = layer.mlp.experts
+    for mlp, x in zip(sparse, caught):
+        e = mlp.experts
+        # the scaling factor changes no choice
         _, chosen, _ = route(x.reshape(-1, x.shape[-1]),
                              e.gate_weight._value, e.top_k,
-                             e.norm_topk_prob)
+                             e.norm_topk_prob, getattr(mlp, "n_group", 1),
+                             getattr(mlp, "topk_group", 1))
         out.append(np.asarray(chosen))
     return out
 
