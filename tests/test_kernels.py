@@ -16,6 +16,10 @@ from paddle_tpu.kernels.flash_attention import (
 from paddle_tpu.kernels.ring_attention import sequence_parallel_attention
 
 RNG = np.random.RandomState(21)
+# the forward kernel sits in a jitted wrapper, so that a model's layers
+# share one trace of it: a jaxpr prints the wrapper's body (and the
+# ``name=flash_fwd`` in it) once, and every call of it by this name
+FWD_CALL = "name=_flash_fwd_bhnd"
 
 
 def _qkv(b, n, h, d, kv_n=None):
@@ -350,7 +354,7 @@ class TestForwardKernelOutsideTheVjp:
         q, k, v = map(jnp.asarray, _qkv(1, 128, 2, 128))
         text = str(jax.make_jaxpr(lambda *a: flash_attention(
             *a, causal=True, interpret=True))(q, k, v))
-        assert text.count("name=flash_fwd") == 1
+        assert text.count(FWD_CALL) == 1
         assert "name=flash_dq" not in text
 
     def test_without_a_checkpoint_the_residuals_are_what_they_were(self):
@@ -360,7 +364,7 @@ class TestForwardKernelOutsideTheVjp:
         text = str(jax.make_jaxpr(jax.grad(lambda *a: flash_attention(
             *a, causal=True, interpret=True).sum(), argnums=(0, 1, 2)))(
                 q, k, v))
-        assert text.count("name=flash_fwd") == 1
+        assert text.count(FWD_CALL) == 1
         assert text.count("name=flash_dq") == 1
         assert text.count("name=flash_dkv") == 1
         for name in FLASH_SAVED_NAMES:
@@ -414,7 +418,7 @@ class TestRecomputedLayerKeepsAttentionOutput:
         for recompute in (False, True):
             fn, values = grads(recompute)
             text = str(jax.make_jaxpr(fn)(values, ids, labels))
-            assert text.count("name=flash_fwd") == self.LAYERS, recompute
+            assert text.count(FWD_CALL) == self.LAYERS, recompute
             assert text.count("name=flash_dq") == self.LAYERS
             assert text.count("name=flash_dkv") == self.LAYERS
             assert ("prevent_cse=" in text) == recompute   # a remat
@@ -439,7 +443,261 @@ class TestRecomputedLayerKeepsAttentionOutput:
         fn, values = grads(True)
         ids = jnp.zeros((2, 128), jnp.int32)
         text = str(jax.make_jaxpr(fn)(values, ids, ids))
-        assert text.count("name=flash_fwd") == 2 * self.LAYERS
+        assert text.count(FWD_CALL) == 2 * self.LAYERS
+
+
+def _out_and_lse(q, k, v, scale, causal, segs=None):
+    """``_reference_attention``'s output and, from the same masked
+    logits, the log-sum-exp the forward kernel saves."""
+    logits = jnp.einsum("bnd,bmd->bnm", q, k,
+                        precision=jax.lax.Precision.HIGHEST) * scale
+    keep = jnp.ones(logits.shape[1:], bool)
+    if causal:
+        keep = jnp.tril(keep)
+    keep = keep[None]
+    if segs is not None:
+        keep = keep & (segs[:, :, None] == segs[:, None, :])
+    lse = jax.scipy.special.logsumexp(jnp.where(keep, logits, -jnp.inf),
+                                      axis=-1)
+    return _reference_attention(q, k, v, scale, causal, segs=segs), lse
+
+
+# (name, n, kv_len, d, dv, causal, segmented, block_q, block_k, block_kv)
+FWD_CASES = [
+    ("causal_one_tile", 128, 128, 64, 64, True, False, 128, 128, 128),
+    ("causal_two_tiles", 256, 256, 64, 64, True, False, 128, 128, 128),
+    ("causal_many_tiles", 1024, 1024, 64, 64, True, False, 128, 128, 128),
+    ("causal_resident_block_walked", 1024, 1024, 64, 64, True, False, 128,
+     128, 512),
+    ("causal_whole_kv_resident", 1024, 1024, 64, 64, True, False, 256, 128,
+     1024),
+    ("causal_halved_diagonal_tile", 1024, 1024, 64, 64, True, False, 256,
+     256, 1024),
+    ("causal_halved_diagonal_one_tile", 512, 512, 64, 64, True, False, 512,
+     512, 512),
+    ("causal_q_tile_shorter", 512, 512, 64, 64, True, False, 64, 128, 256),
+    ("causal_q_tile_taller", 512, 512, 64, 64, True, False, 256, 128, 256),
+    ("causal_kv_tile_wider_than_q", 512, 512, 64, 64, True, False, 128, 256,
+     512),
+    ("causal_kv_longer", 256, 1024, 64, 64, True, False, 128, 128, 512),
+    ("causal_kv_longer_grid_steps", 256, 1024, 64, 64, True, False, 128,
+     128, 128),
+    ("causal_kv_shorter", 1024, 256, 64, 64, True, False, 128, 128, 256),
+    ("causal_kv_shorter_square_tiles", 1024, 512, 64, 64, True, False, 256,
+     256, 256),
+    ("full_one_block", 512, 512, 64, 64, False, False, 128, 128, 128),
+    ("full_resident_block_walked", 512, 1024, 64, 64, False, False, 256,
+     128, 512),
+    ("segmented_causal", 512, 512, 64, 64, True, True, 128, 128, 128),
+    ("segmented_causal_halved_diagonal", 512, 512, 64, 64, True, True, 256,
+     256, 256),
+    ("segmented_full", 512, 512, 64, 64, False, True, 128, 256, 256),
+    ("d128", 512, 512, 128, 128, True, False, 256, 256, 512),
+    ("d256", 512, 512, 256, 256, True, False, 256, 256, 512),
+    ("qk192_v128", 512, 512, 192, 128, True, False, 256, 256, 512),
+    ("qk192_v128_full", 256, 512, 192, 128, False, False, 128, 256, 512),
+]
+
+
+def _fwd_inputs(n, kv_len, d, dv, segmented, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    bh = 2
+    q = jax.random.normal(ks[0], (bh, n, d), jnp.float32)
+    k = jax.random.normal(ks[1], (bh, kv_len, d), jnp.float32)
+    v = jax.random.normal(ks[2], (bh, kv_len, dv), jnp.float32)
+    segs = None
+    if segmented:
+        # three packed sequences whose ends fall inside a tile
+        ends = jnp.asarray([n // 3 + 5, 2 * n // 3 - 7])
+        segs = jnp.broadcast_to(
+            jnp.searchsorted(ends, jnp.arange(n), side="right")
+            .astype(jnp.int32), (bh, n))
+    return q, k, v, segs
+
+
+class TestFlashForwardTileProgram:
+    """The forward kernel's two tile programs, its clamped K/V index maps
+    and the walk over a resident K/V block, in interpret mode against
+    ``_reference_attention`` for ``out`` and against the masked logits'
+    log-sum-exp for ``lse``."""
+
+    @pytest.mark.parametrize(
+        "n,kv_len,d,dv,causal,segmented,block_q,block_k,block_kv",
+        [c[1:] for c in FWD_CASES], ids=[c[0] for c in FWD_CASES])
+    def test_out_and_lse_match_the_reference(self, n, kv_len, d, dv, causal,
+                                             segmented, block_q, block_k,
+                                             block_kv):
+        from paddle_tpu.kernels.flash_attention import _flash_fwd_bhnd
+
+        q, k, v, segs = _fwd_inputs(n, kv_len, d, dv, segmented, seed=n + d)
+        scale = 1.0 / np.sqrt(d)
+        out, lse = _flash_fwd_bhnd(q, k, v, scale, causal, block_q, block_k,
+                                   True, segs=segs, block_kv=block_kv)
+        ref_out, ref_lse = _out_and_lse(q, k, v, scale, causal, segs)
+        assert out.shape == (2, n, dv) and lse.shape == (2, 1, n)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
+                                   rtol=1e-4, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(lse[:, 0]),
+                                   np.asarray(ref_lse), rtol=1e-5,
+                                   atol=2e-5)
+
+    @pytest.mark.parametrize("block_kv", [128, 512])
+    def test_keys_no_query_sees_never_reach_the_output(self, block_kv):
+        """K/V rows past the last block a query can see are NaN: what a
+        block that was not fetched, or a stale one, would hold."""
+        from paddle_tpu.kernels.flash_attention import _flash_fwd_bhnd
+
+        q, k, v, _ = _fwd_inputs(256, 1024, 64, 64, False, seed=3)
+        k = k.at[:, 512:].set(jnp.nan)
+        v = v.at[:, 512:].set(jnp.nan)
+        scale = 0.125
+        out, lse = _flash_fwd_bhnd(q, k, v, scale, True, 128, 128, True,
+                                   block_kv=block_kv)
+        ref_out, ref_lse = _out_and_lse(q, k[:, :256], v[:, :256], scale,
+                                        True)
+        assert np.isfinite(np.asarray(out)).all()
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
+                                   rtol=1e-4, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(lse[:, 0]),
+                                   np.asarray(ref_lse), rtol=1e-5,
+                                   atol=2e-5)
+
+    def test_a_scale_that_is_not_positive_is_not_folded(self):
+        from paddle_tpu.kernels.flash_attention import _flash_fwd_bhnd
+
+        q, k, v, _ = _fwd_inputs(256, 256, 64, 64, False, seed=4)
+        for scale in (-0.125, 0.0):
+            out, lse = _flash_fwd_bhnd(q, k, v, scale, True, 128, 128, True)
+            ref_out, ref_lse = _out_and_lse(q, k, v, scale, True)
+            np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
+                                       rtol=1e-4, atol=2e-5)
+            np.testing.assert_allclose(np.asarray(lse[:, 0]),
+                                       np.asarray(ref_lse), rtol=1e-5,
+                                       atol=2e-5)
+
+    @pytest.mark.parametrize("segmented", [False, True])
+    def test_backward_takes_the_lse_of_a_forward_tile_of_its_own(
+            self, segmented):
+        """The forward at 256 x 256 over a resident 512, ``flash_dq`` and
+        ``flash_dkv`` at 128 x 128: the gradients are the reference's."""
+        from paddle_tpu.kernels.flash_attention import _flash_core
+
+        q, k, v, segs = _fwd_inputs(512, 512, 64, 32, segmented, seed=9)
+        g = jax.random.normal(jax.random.PRNGKey(10), (2, 512, 32),
+                              jnp.float32)
+        scale = 0.125
+        fwd_tiles = (256, 256, 256 if segmented else 512)
+        out, vjp = jax.vjp(
+            lambda a, b_, c: _flash_core(a, b_, c, segs, scale, True, 128,
+                                         128, True, fwd_tiles), q, k, v)
+        ref_out, ref_vjp = jax.vjp(
+            lambda a, b_, c: _reference_attention(a, b_, c, scale, True,
+                                                  segs=segs), q, k, v)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
+                                   rtol=1e-4, atol=2e-5)
+        for mine, ref in zip(vjp(g), ref_vjp(g)):
+            np.testing.assert_allclose(np.asarray(mine), np.asarray(ref),
+                                       rtol=5e-3, atol=5e-4)
+
+    def test_public_entry_runs_the_chosen_tile(self):
+        """No tile named: 2048 rows run at the chooser's 1024 x 1024 over
+        the whole of K/V, gradients through the backward's 512 x 512."""
+        ks = jax.random.split(jax.random.PRNGKey(11), 3)
+        q, k, v = [jax.random.normal(kk, (1, 2048, 1, 64), jnp.float32)
+                   for kk in ks]
+        text = str(jax.make_jaxpr(lambda *a: flash_attention(
+            *a, causal=True, interpret=True))(q, k, v))
+        assert text.count(FWD_CALL) == 1
+        # two q blocks of 1024, one resident block of all 2048 keys
+        assert "grid=(1, 2, 1)" in text
+
+        def loss(fn):
+            return lambda q_, k_, v_: jnp.sum(fn(q_, k_, v_) ** 2)
+
+        got, got_g = jax.value_and_grad(loss(lambda *a: flash_attention(
+            *a, causal=True, interpret=True)), argnums=(0, 1, 2))(q, k, v)
+        ref, ref_g = jax.value_and_grad(loss(lambda q_, k_, v_: jnp.swapaxes(
+            _reference_attention(q_[0].swapaxes(0, 1), k_[0].swapaxes(0, 1),
+                                 v_[0].swapaxes(0, 1), 0.125, True), 0,
+            1)[None]), argnums=(0, 1, 2))(q, k, v)
+        np.testing.assert_allclose(float(got), float(ref), rtol=1e-4)
+        for mine, theirs in zip(got_g, ref_g):
+            np.testing.assert_allclose(np.asarray(mine), np.asarray(theirs),
+                                       rtol=5e-3, atol=5e-4)
+
+
+# what the benchmark's four cells hand the forward kernel: (rows, q/k head
+# dim, v head dim), bf16, causal, kv_len == rows
+CELL_FWD_SHAPES = (
+    [(n, 128, 128) for n in (128, 256, 512, 1024, 2048, 4096)]      # Mistral
+    + [(n, 256, 256) for n in (512, 1024, 2048, 4096, 8192)]      # Qwen3-Next
+    + [(n, 192, 128) for n in (512, 1024, 2048, 4096, 8192)]     # DeepSeek-V2
+)
+
+
+class TestFlashForwardTileChooser:
+    """``_fwd_tiles`` as a pure function of a call's shapes."""
+
+    @pytest.mark.parametrize("n,d,dv", CELL_FWD_SHAPES)
+    def test_tiles_of_the_cells_shapes(self, n, d, dv):
+        from paddle_tpu.kernels.flash_attention import (
+            _FWD_VMEM_BUDGET, _fwd_tiles, _fwd_vmem_bytes)
+
+        bq, bk, bkv = _fwd_tiles(n, n, d, dv, 2)
+        assert n % bq == 0 and n % bkv == 0 and bkv % bk == 0
+        # Mosaic: q rows on sublanes, kv rows on the score tile's lanes,
+        # block_q on the lanes of the lse tile
+        assert bq % 8 == 0 and (bq % 128 == 0 or bq == n)
+        assert bk % 128 == 0
+        assert _fwd_vmem_bytes(bq, bk, bkv, d, dv, 2) <= _FWD_VMEM_BUDGET
+        # a side of 1024 wherever the rows divide by it, and the whole of
+        # K/V resident at every length a cell runs
+        assert bq == bk == (1024 if n % 1024 == 0 else min(n, 512))
+        assert bkv == n
+
+    def test_wider_operands_and_longer_contexts_stay_in_budget(self):
+        from paddle_tpu.kernels.flash_attention import (
+            _FWD_VMEM_BUDGET, _fwd_tiles, _fwd_vmem_bytes)
+
+        for n, kv_len, d, dv, itemsize in ((8192, 8192, 256, 256, 4),
+                                           (4096, 65536, 128, 128, 2),
+                                           (2048, 2048, 512, 512, 4),
+                                           (1536, 1536, 128, 128, 2),
+                                           (128, 4096, 128, 128, 2)):
+            bq, bk, bkv = _fwd_tiles(n, kv_len, d, dv, itemsize)
+            assert n % bq == 0 and kv_len % bkv == 0 and bkv % bk == 0
+            assert bq % 128 == 0 and bk % 128 == 0
+            assert _fwd_vmem_bytes(bq, bk, bkv, d, dv,
+                                   itemsize) <= _FWD_VMEM_BUDGET
+        # 65,536 keys do not fit: the resident block is a part of them
+        assert _fwd_tiles(4096, 65536, 128, 128, 2)[2] < 65536
+        # a segmented call's kv segment ids are cut by the BlockSpec
+        assert _fwd_tiles(2048, 2048, 128, 128, 2, segmented=True) == (
+            1024, 1024, 1024)
+
+    @pytest.mark.parametrize("named", [dict(block_q=128), dict(block_k=128),
+                                       dict(block_q=256, block_k=128)])
+    def test_a_named_tile_wins(self, named, monkeypatch):
+        from paddle_tpu.kernels import flash_attention as fa
+
+        seen = []
+        real = fa._flash_fwd_bhnd
+
+        def spy(*args, **kw):
+            seen.append((args[5], args[6], kw["block_kv"]))
+            return real(*args, **kw)
+
+        monkeypatch.setattr(fa, "_flash_fwd_bhnd", spy)
+        q = jnp.zeros((1, 1024, 1, 64), jnp.float32)
+        jax.eval_shape(lambda: fa.flash_attention(q, q, q, causal=True,
+                                                  interpret=True, **named))
+        bq = named.get("block_q", 512)
+        bk = named.get("block_k", 512)
+        assert seen == [(bq, bk, bk)]
+        seen.clear()
+        jax.eval_shape(lambda: fa.flash_attention(q, q, q, causal=True,
+                                                  interpret=True))
+        assert seen == [(1024, 1024, 1024)]
 
 
 class TestFlashMinHeadDimFlag:

@@ -37,6 +37,10 @@ pa = importlib.import_module("paddle_tpu.serving.kernels.paged_attention")
 mla = importlib.import_module("paddle_tpu.serving.kernels.mla_attention")
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+# the forward kernel's place in an instruction's ``op_name``, below the
+# scope that called it: its jitted wrapper (one trace for all layers),
+# then the kernel's own name before ``pallas_call``
+FWD_KERNEL_PATH = 'jit(_flash_fwd_bhnd)/flash_fwd/pallas_call"'
 
 # smoke geometry: 8 x 1024 tokens, 16 heads x 128, vocab 32000;
 # serving: 8 slots, 512 pages of 16, 128 pages per slot
@@ -152,6 +156,15 @@ def _cases():
         [wide(N, H), wide(N, H), narrow(N, H)],
         {"flash_fwd", "flash_dq", "flash_dkv"}))
 
+    # mistral7b-pretrain-4k: 2 x 4096 tokens, 32 heads of 128; and the
+    # smallest prefill bucket a serving cell warms
+    train = ((2, 4096, 32, D), BF16)
+    cases.append(("flash_bf16_d128_train_4096_bwd", _flash(BF16, D)[1],
+                  [train] * 3, {"flash_fwd", "flash_dq", "flash_dkv"}))
+    small = ((1, 128, 32, D), BF16)
+    cases.append(("flash_bf16_d128_fwd_128", _flash(BF16, D)[0],
+                  [small] * 3, {"flash_fwd"}))
+
     def experts(x, w1, w2, sizes):
         # one expert layer's two calls: gate/up, then down
         h = mg.moe_gmm(x, w1, sizes, interpret=False)
@@ -252,6 +265,10 @@ class TestMosaicCompile:
             # trip: a double buffer of 2.6 MB
             assert found == {"mla_decode": 1}
             assert mla._pages_per_group(BS, MLA_W, 2, MLA_MB) == 64
+        if name.startswith("flash_"):
+            # one forward kernel a call whatever tile it chose, and one
+            # of each backward kernel where there is a gradient
+            assert found == dict.fromkeys(kernels, 1)
         if name.startswith("moe_gmm"):
             # the kernel body is jitted: both calls are one kernel name
             assert found == {"moe_gmm": 2}
@@ -435,8 +452,7 @@ class TestBlocksAreNamed:
         text = jax.jit(fwd).lower(avals, ids, ids).compile().as_text()
         assert mosaic_kernels(text) == {"flash_fwd": 2}
         for layer in (0, 1):
-            assert ('layer_%d/attn/flash_fwd/pallas_call"' % layer
-                    in text)
+            assert ('layer_%d/attn/' % layer + FWD_KERNEL_PATH in text)
         for scope in self.SCOPES:
             assert scope in text, scope
 
@@ -530,10 +546,11 @@ class TestRecomputedStepRunsFlashFwdOnce:
             "flash_fwd": self.LAYERS, "flash_dq": self.LAYERS,
             "flash_dkv": self.LAYERS}
         for layer in range(self.LAYERS):
-            assert ('/jvp(layer_%d)/attn/flash_fwd/pallas_call"' % layer
+            assert ('/jvp(layer_%d)/attn/' % layer + FWD_KERNEL_PATH
                     in programs["train"])
         # none of them is a backward pass's recomputation
-        assert 'checkpoint/attn/flash_fwd' not in programs["train"]
+        assert ('checkpoint/attn/' + FWD_KERNEL_PATH
+                not in programs["train"])
 
     def test_no_gradient_is_one_forward_kernel_a_layer(self, programs):
         assert mosaic_kernels(programs["forward"]) == {
