@@ -13,6 +13,9 @@ of the llama1b geometry (hidden 2048, 22 layers, 16 heads x 128, vocab
   serve_mixed     serving.Engine under prefix cache + chunked prefill
   serve_mla       serving.Engine over a latent page cache: the tiny
                   DeepSeek-V2 preset through mla_decode
+  serve_ssm       serving.Engine over slot state beside K/V pages: a small
+                  Nemotron-H through ssm_decode, against the family's
+                  plain reference
   train4          dp=2 x mp=2 on four chips (skipped below four)
 
 Each phase checks what came out by the repo's own means — finite
@@ -399,18 +402,26 @@ def check_greedy_parity(model, prompt, generated):
     # one device, like the engine it is compared with
     with _mesh.scoped_mesh(Mesh(np.array(jax.devices()[:1]), ("dp",))):
         logits = np.asarray(jax.jit(dense)(values, ids)[0], np.float32)
-    p = len(prompt)
+    check_tokens(logits, len(prompt), toks, "dense forward")
+
+
+def check_tokens(logits, p, toks, what):
+    """``toks`` against ``logits`` [T, vocab] of prompt (``p`` tokens) +
+    toks: each must be the argmax of its row or within PARITY_FRAC of
+    it."""
+    import numpy as np
+
     rows = logits[p - 1:p - 1 + len(toks)]
     tol = PARITY_FRAC * float(np.abs(rows).max())
     exact = int((rows.argmax(-1) == np.asarray(toks)).sum())
     gaps = rows.max(-1) - rows[np.arange(len(toks)), toks]
-    log("  greedy parity vs dense forward: %d/%d tokens are the dense "
-        "argmax; largest gap to it %.3e (tol %.3e = 2^-5 of max |logit|)"
-        % (exact, len(toks), float(gaps.max()), tol))
+    log("  greedy parity vs %s: %d/%d tokens are its argmax; largest gap "
+        "to it %.3e (tol %.3e = 2^-5 of max |logit|)"
+        % (what, exact, len(toks), float(gaps.max()), tol))
     if gaps.max() > tol:
         raise AssertionError(
-            "engine tokens %s are not the dense forward's greedy choice: "
-            "gaps %s > %.3e" % (toks, gaps.tolist(), tol))
+            "engine tokens %s are not the %s's greedy choice: "
+            "gaps %s > %.3e" % (toks, what, gaps.tolist(), tol))
 
 
 def phase_serve(geom, mixed=False, on_chip=True):
@@ -558,6 +569,100 @@ def phase_serve_mla(on_chip=True, dtype="bfloat16"):
                         "latent decode step")
 
 
+# a small Nemotron-H whose shapes the kernels tile as they stand: a
+# Mamba-2 state of 8 groups x 64 x (2 heads x 64), attention heads of 128
+SSM_CFG = dict(
+    family="nemotron_h", vocab_size=512, hidden_size=256,
+    hybrid_override_pattern="MEM*E", num_attention_heads=8,
+    num_key_value_heads=2, head_dim=128, mamba_num_heads=16,
+    mamba_head_dim=64, ssm_state_size=64, n_groups=8, conv_kernel=4,
+    chunk_size=128, time_step_min=0.001, time_step_max=0.1,
+    time_step_floor=1e-4, n_routed_experts=4, n_routed_experts_published=8,
+    num_experts_per_tok=3, moe_intermediate_size=256,
+    moe_shared_expert_intermediate_size=512, routed_scaling_factor=2.5,
+    norm_topk_prob=True, layer_norm_epsilon=1e-5,
+    max_position_embeddings=512, tie_word_embeddings=False)
+
+
+def phase_serve_ssm(on_chip=True, dtype="bfloat16"):
+    """serving.Engine on device 0 over slot state, K/V pages and layers
+    that keep nothing: a small Nemotron-H (``SSM_CFG``), prefill by the
+    chunked Mamba-2 form, decode through ``ssm_decode``; the kernel (the
+    interpreter off-chip) against its jnp twin on layer 0's live state,
+    and the engine's tokens against the family's plain float32 reference
+    (benchmark/families/nemotron_h.py)."""
+    import os
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu import serving
+    from paddle_tpu.serving.kernels.ssm import (ssm_decode_kernel,
+                                                ssm_decode_reference)
+    from paddle_tpu.serving.scheduler import RequestState
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    sys.path.insert(0, os.path.join(here, "benchmark"))
+    import run as bench
+
+    family = bench.load_module("families", "nemotron_h")
+    cfg = dict(SSM_CFG, torch_dtype=dtype)
+    model = family.build_model(cfg, SEED, training=False)
+    eng = serving.Engine(model, max_slots=4, num_blocks=64, block_size=16,
+                         max_model_len=256)
+    rng = np.random.RandomState(SEED + 3)
+    prompts = [rng.randint(0, cfg["vocab_size"], n).tolist()
+               for n in (5, 40, 100)]         # a slot stays idle
+    rids = [eng.add_request(p, 12) for p in prompts]
+    compared = False
+    while eng.has_work():
+        eng.step()
+        if not compared and all(
+                eng.requests[r].state is RequestState.DECODING
+                for r in rids):
+            state = eng.cache.pools[0]["state"] + 0     # a copy: the
+            lens = np.array(eng.cache.seq_lens)         # pool is carried
+            h, p = cfg["mamba_num_heads"], cfg["mamba_head_dim"]
+            g, n = cfg["n_groups"], cfg["ssm_state_size"]
+            keys = jax.random.split(jax.random.PRNGKey(SEED + 8), 4)
+            slots = eng.max_slots
+            args = (jax.random.normal(keys[0], (slots, h, p), state.dtype),
+                    jax.nn.softplus(jax.random.normal(keys[1], (slots, h))),
+                    -jnp.linspace(1.0, 16.0, h), jnp.ones((h,)),
+                    jax.random.normal(keys[2], (slots, g, n)),
+                    jax.random.normal(keys[3], (slots, g, n)),
+                    jnp.asarray(lens > 0))
+            want_y, want_state = ssm_decode_reference(*args, state)
+            got_y, got_state = ssm_decode_kernel(*args, state)
+            check_kernel_output("ssm_decode", got_y, want_y,
+                                (lens > 0)[:, None, None], lens)
+            check_kernel_output("ssm_decode (state)", got_state,
+                                want_state, True, lens)
+            if not np.array_equal(np.asarray(got_state)[lens == 0],
+                                  np.asarray(state)[lens == 0]):
+                raise AssertionError("ssm_decode: an idle slot's state "
+                                     "changed")
+            compared = True
+    if not compared:
+        raise AssertionError("never saw every request decoding at once")
+    stats = eng.stats()
+    log("  %d requests; decode_compiles %d, decode_steps %d; ssm %s"
+        % (len(rids), stats["decode_compiles"], stats["decode_steps"],
+           stats["ssm"]))
+    if stats["decode_compiles"] != 1:
+        raise AssertionError(
+            "decode_compiles == %d, not 1" % stats["decode_compiles"])
+    toks = eng.output(rids[2])[:PARITY_TOKENS]
+    logits = np.asarray(family.reference_logits(
+        family.weights_of(model), cfg, prompts[2] + toks), np.float32)
+    check_tokens(logits, len(prompts[2]), toks, "family reference")
+    if on_chip:    # after the stats: lowering traces once more
+        require_kernels(eng.hot_step_hlo(),
+                        ["ssm_decode", "paged_decode", "moe_gmm"],
+                        "state-space decode step")
+
+
 # -- driver ------------------------------------------------------------------
 
 class Phases:
@@ -622,6 +727,7 @@ def main():
     phases.run("serve", phase_serve, geom)
     phases.run("serve_mixed", phase_serve, geom, mixed=True)
     phases.run("serve_mla", phase_serve_mla)
+    phases.run("serve_ssm", phase_serve_ssm)
     if not four:
         log("train4: skipped (device_count=%d)" % len(devices))
     elif losses:

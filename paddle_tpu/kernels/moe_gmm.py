@@ -20,6 +20,14 @@ visit. The visit list has a static length (row tiles + groups - 1 is
 its bound); the tail past the real count repeats the last visit, which
 costs a grid step and no DMA.
 
+A width N that is no multiple of 128 (1856) cannot be cut into column
+tiles, and the chip does not even keep such a matrix that way: it lays
+[G, K, N] out with K, the multiple of 128, on the lanes. Handing the
+kernel [K, N] blocks then costs a copy of every matrix a call. So the
+kernel takes such a ``rhs`` as it lies, transposed ([G, N, K], which is
+no copy: the same bytes), a group's whole matrix a block, and
+contracts both operands' last dimension.
+
 Off the TPU the layer calls ``jax.lax.ragged_dot`` in this kernel's
 place (``grouped_matmul``), and the backward is always ``ragged_dot``'s.
 """
@@ -58,6 +66,19 @@ def _col_tile(k, n, itemsize):
     return best
 
 
+def _vmem_limit(tm, k, tn, itemsize):
+    """None, the compiler's own limit, while a [k, tn] block keeps to
+    ``_RHS_BLOCK_BYTES``. Where a group's whole matrix is the block
+    (``tn`` = N, a width that is no multiple of 128: 2688 x 1856 in
+    bfloat16 is 10 MB), the limit is what two of them, the row and
+    output tiles and the accumulator take, and half as much again."""
+    block = k * tn * itemsize
+    if block <= _RHS_BLOCK_BYTES:
+        return None
+    return int(1.5 * (2 * block + 2 * tm * (k + tn) * itemsize
+                      + 4 * tm * tn))
+
+
 def visit_list(group_sizes, m, tm):
     """(offsets [G+1], group of visit [V], row tile of visit [V],
     visits [1]) with V = m // tm + G - 1, the most visits there can be:
@@ -82,13 +103,14 @@ def visit_list(group_sizes, m, tm):
 
 
 def _gmm_kernel(off_ref, gid_ref, tile_ref, visits_ref, lhs_ref, rhs_ref,
-                out_ref, *, tm):
+                out_ref, *, tm, rhs_t):
     v = pl.program_id(1)
 
     @pl.when(v < visits_ref[0])
     def _visit():
         g = gid_ref[v]
-        acc = _dot(lhs_ref[...], rhs_ref[...], ((1,), (0,)))
+        acc = _dot(lhs_ref[...], rhs_ref[...],
+                   ((1,), (1 if rhs_t else 0,)))
         row = tile_ref[v] * tm + jax.lax.broadcasted_iota(
             jnp.int32, acc.shape, 0)
         mine = jnp.logical_and(row >= off_ref[g], row < off_ref[g + 1])
@@ -98,29 +120,38 @@ def _gmm_kernel(off_ref, gid_ref, tile_ref, visits_ref, lhs_ref, rhs_ref,
 
 
 # jitted so that a model's layers share one trace and one lowering
-@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
-def _moe_gmm(lhs, rhs, offsets, gid, tile, visits, *, tm, interpret):
+@functools.partial(jax.jit, static_argnames=("tm", "rhs_t", "interpret"))
+def _moe_gmm(lhs, rhs, offsets, gid, tile, visits, *, tm, rhs_t=False,
+             interpret):
     m, k = lhs.shape
-    _, _, n = rhs.shape
-    tn = _col_tile(k, n, rhs.dtype.itemsize)
+    if rhs_t:
+        # [G, N, K] as the chip keeps it: a group's whole matrix a block
+        tn = n = rhs.shape[1]
+        rhs_spec = pl.BlockSpec((None, n, k), lambda j, v, off, gid, tile,
+                                nv: (gid[v], 0, 0))
+    else:
+        n = rhs.shape[2]
+        tn = _col_tile(k, n, rhs.dtype.itemsize)
+        rhs_spec = pl.BlockSpec((None, k, tn), lambda j, v, off, gid, tile,
+                                nv: (gid[v], 0, j))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(n // tn, gid.shape[0]),
         in_specs=[
             pl.BlockSpec((tm, k), lambda j, v, off, gid, tile, nv:
                          (tile[v], 0)),
-            pl.BlockSpec((None, k, tn), lambda j, v, off, gid, tile, nv:
-                         (gid[v], 0, j)),
+            rhs_spec,
         ],
         out_specs=pl.BlockSpec((tm, tn), lambda j, v, off, gid, tile, nv:
                                (tile[v], j)),
     )
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, tm=tm),
+        functools.partial(_gmm_kernel, tm=tm, rhs_t=rhs_t),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(tm, k, tn, rhs.dtype.itemsize)),
         interpret=interpret,
         name="moe_gmm",
     )(offsets, gid, tile, visits, lhs, rhs)
@@ -137,7 +168,12 @@ def moe_gmm(lhs, rhs, group_sizes, interpret=None):
     if pad:
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
     visits = visit_list(group_sizes.astype(jnp.int32), m + pad, tm)
-    return _moe_gmm(lhs, rhs, *visits, tm=tm,
+    # a width the lanes cannot tile, under a K they can: the matrices
+    # as the chip keeps them (module docstring)
+    rhs_t = rhs.shape[2] % 128 != 0 and rhs.shape[1] % 128 == 0
+    if rhs_t:
+        rhs = jnp.swapaxes(rhs, 1, 2)
+    return _moe_gmm(lhs, rhs, *visits, tm=tm, rhs_t=rhs_t,
                     interpret=resolve_interpret(interpret))[:m]
 
 

@@ -30,12 +30,13 @@ from ..nn.layer import Layer
 
 _A = jnp.asarray
 _ACTIVATIONS = {"gelu": jax.nn.gelu, "relu": jax.nn.relu,
+                "relu2": lambda x: jnp.square(jax.nn.relu(x)),
                 "silu": jax.nn.silu}
 
 
 def route(x, gate_w, top_k, norm_topk_prob, n_group=1, topk_group=1,
-          routed_scaling_factor=1.0):
-    """Softmax router in float32 over every published expert.
+          routed_scaling_factor=1.0, select_bias=None):
+    """The router in float32 over every published expert.
     x [T, D], gate_w [D, E] -> (weights [T, k], experts [T, k] int32,
     aux): the top-k probabilities (renormalised to sum 1 when
     ``norm_topk_prob``, then times ``routed_scaling_factor``), their
@@ -45,7 +46,14 @@ def route(x, gate_w, top_k, norm_topk_prob, n_group=1, topk_group=1,
     ``n_group`` equal contiguous groups, a group scores its largest
     probability, and the top-k is taken inside the ``topk_group`` best
     groups only (so a token's experts lie on at most ``topk_group`` of
-    the devices that hold a group each)."""
+    the devices that hold a group each).
+
+    Without ``select_bias`` an expert's probability is its softmax. With
+    one ([E], the published ``e_score_correction_bias``) it is
+    ``sigmoid`` of its own logit, the top-k is taken of probability +
+    bias, and the weights are the probabilities of the chosen WITHOUT
+    the bias, over their sum + 1e-20 when normalised: the bias steers
+    the load and stays out of the result."""
     gate_w = _A(gate_w)
     if x.dtype == jnp.float32 or gate_w.dtype == jnp.float32:
         logits = jnp.dot(x.astype(jnp.float32), gate_w.astype(jnp.float32),
@@ -55,22 +63,34 @@ def route(x, gate_w, top_k, norm_topk_prob, n_group=1, topk_group=1,
         # native pass with a float32 accumulator IS the float32 matmul
         logits = jnp.dot(x, gate_w, preferred_element_type=jnp.float32,
                          precision=jax.lax.Precision.DEFAULT)
-    probs = jax.nn.softmax(logits, axis=-1)
-    e = probs.shape[-1]
-    candidates = probs
-    if n_group > 1:
-        if e % n_group or not 0 < topk_group <= n_group:
-            raise ValueError("route: %d experts in %d groups, top %d of "
-                             "them" % (e, n_group, topk_group))
-        grouped = probs.reshape(-1, n_group, e // n_group)
-        _, best = jax.lax.top_k(jnp.max(grouped, axis=-1), topk_group)
-        kept = jnp.any(best[:, :, None] == jnp.arange(n_group), axis=1)
-        candidates = jnp.where(kept[:, :, None], grouped,
-                               0.0).reshape(probs.shape)
-    weights, experts = jax.lax.top_k(candidates, top_k)
-    if norm_topk_prob:
-        weights = weights / jnp.maximum(
-            jnp.sum(weights, axis=-1, keepdims=True), 1e-9)
+    e = logits.shape[-1]
+    if select_bias is not None:
+        if n_group > 1:
+            raise ValueError("route: a selection bias with group-limited "
+                             "routing is not written")
+        probs = jax.nn.sigmoid(logits)
+        _, experts = jax.lax.top_k(
+            probs + _A(select_bias).astype(jnp.float32), top_k)
+        weights = jnp.take_along_axis(probs, experts, axis=-1)
+        if norm_topk_prob:
+            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                                 + 1e-20)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        candidates = probs
+        if n_group > 1:
+            if e % n_group or not 0 < topk_group <= n_group:
+                raise ValueError("route: %d experts in %d groups, top %d "
+                                 "of them" % (e, n_group, topk_group))
+            grouped = probs.reshape(-1, n_group, e // n_group)
+            _, best = jax.lax.top_k(jnp.max(grouped, axis=-1), topk_group)
+            kept = jnp.any(best[:, :, None] == jnp.arange(n_group), axis=1)
+            candidates = jnp.where(kept[:, :, None], grouped,
+                                   0.0).reshape(probs.shape)
+        weights, experts = jax.lax.top_k(candidates, top_k)
+        if norm_topk_prob:
+            weights = weights / jnp.maximum(
+                jnp.sum(weights, axis=-1, keepdims=True), 1e-9)
     if routed_scaling_factor != 1.0:
         weights = weights * routed_scaling_factor
     first = jax.nn.one_hot(experts[:, 0], e, dtype=probs.dtype)
@@ -80,7 +100,8 @@ def route(x, gate_w, top_k, norm_topk_prob, n_group=1, topk_group=1,
 
 def moe_forward(x, gate_w, w_in, b_in, w_out, b_out, *, top_k, lo=0,
                 activation="gelu", gated=False, norm_topk_prob=None,
-                n_group=1, topk_group=1, routed_scaling_factor=1.0):
+                n_group=1, topk_group=1, routed_scaling_factor=1.0,
+                select_bias=None):
     """The dropless expert layer on raw arrays.
 
     x [T, D]; gate_w [D, E] routes over all E published experts; the
@@ -89,8 +110,8 @@ def moe_forward(x, gate_w, w_in, b_in, w_out, b_out, *, top_k, lo=0,
     act(gate) * up), w_out [H, F, D]; b_in [H, F or 2F] / b_out [H, D]
     or None. ``norm_topk_prob`` None means "when top_k > 1" (a single
     choice keeps its raw probability, or the router would get no
-    gradient); ``n_group``, ``topk_group`` and ``routed_scaling_factor``
-    are ``route``'s. -> (out [T, D], aux loss, stats int32 [3]): the share of
+    gradient); ``n_group``, ``topk_group``, ``routed_scaling_factor``
+    and ``select_bias`` are ``route``'s. -> (out [T, D], aux loss, stats int32 [3]): the share of
     the result the held experts give; pairs routed here, held experts
     that received a row, the largest load of one expert."""
     x = _A(x)
@@ -101,7 +122,7 @@ def moe_forward(x, gate_w, w_in, b_in, w_out, b_out, *, top_k, lo=0,
         norm_topk_prob = top_k > 1
     weights, experts, aux = route(x, gate_w, top_k, norm_topk_prob,
                                   n_group, topk_group,
-                                  routed_scaling_factor)
+                                  routed_scaling_factor, select_bias)
     here = jnp.logical_and(experts >= lo, experts < lo + held)   # [T, k]
     # pairs sorted by held expert; the pairs of experts held elsewhere
     # sort to the end, past every group, where nothing is computed

@@ -27,8 +27,8 @@ step. Both off: every compiled function, shape and output below is
 bit-identical to the tier-1 engine (test-pinned).
 
 The engine OWNS the cache: a model's ``paged_cache_spec()`` lists, one
-entry a layer, what a slot holds there (serving/kv_cache.py: K/V pages
-or fixed slot-indexed state), and its layers take a per-layer hook
+entry a layer, what a slot holds there (serving/kv_cache.py: K/V pages,
+latent pages, fixed slot-indexed state, or nothing), and its layers take a per-layer hook
 object (``update_and_attend`` for pages, ``read``/``write`` for state)
 — the model never allocates or stores cache state.
 
@@ -324,6 +324,8 @@ class Engine:
             cache = s.cache
             entries = []
             for i, pool in enumerate(cache.pools):
+                if pool is None:        # a layer that keeps nothing
+                    continue
                 if isinstance(pool, LatentPool):
                     entries.append(("latent_pool/layer%d" % i, pool.rows))
                     continue
@@ -602,6 +604,13 @@ class Engine:
             out["latent"] = dict(
                 self.cache.latent_stats(),
                 cached_tokens=self.metrics.live_tokens_mean())
+        out["ssm"] = None
+        ssm_layers = getattr(self.model, "ssm_layers", 0)
+        if ssm_layers:
+            out["ssm"] = {
+                "layers": int(ssm_layers),
+                "state_bytes_slot": out["state"]["slot_bytes"],
+                "active_slots": self.metrics.active_slots_mean()}
         return out
 
     def request_status(self, rid):

@@ -36,6 +36,11 @@ release and preemption do to it:
     (serving/kernels/mla_attention.py). Prefix adoption, the chunked
     mixed step and int8 pages have no latent form yet, and the engine
     refuses them for a model that has such a layer.
+``NoCache`` (kind ``nothing``)
+    a layer that keeps nothing for a sequence (an expert or MLP block
+    that is a layer of its own, not the second half of one): no pool
+    (``None``, an empty node of the pools' tree), nothing to grow,
+    release or rebuild, and a hook the layer never calls.
 
 The rest of this docstring is the kv_pages kind.
 
@@ -112,6 +117,12 @@ class LatentPages(NamedTuple):
     width: int
     dtype: str = "float32"
     kind = "latent_pages"
+
+
+class NoCache(NamedTuple):
+    """One layer's entry of a cache spec, kind ``nothing``."""
+
+    kind = "nothing"
 
 
 class LatentPool(NamedTuple):
@@ -212,7 +223,8 @@ class BlockAllocator:
 class PagedKVCache:
     """One pool a layer (``KVBlockPool`` for a kv_pages layer,
     ``LatentPool`` for a latent_pages layer, a dict of [max_slots, ...]
-    arrays for a slot_state layer) + the host-side table/length
+    arrays for a slot_state layer, ``None`` for a layer that keeps
+    nothing) + the host-side table/length
     bookkeeping + the one allocator."""
 
     def __init__(self, layers, num_blocks, block_size, max_slots,
@@ -242,6 +254,8 @@ class PagedKVCache:
         self.cow_clones = 0             # copy-on-write page splits
 
     def _new_pool(self, spec):
+        if spec.kind == "nothing":
+            return None
         if spec.kind == "slot_state":
             return {name: jnp.zeros((self.max_slots,) + tuple(shape),
                                     jnp.dtype(dtype))
@@ -287,6 +301,8 @@ class PagedKVCache:
                 return PagedPrefillView(p, table_row, self.block_size)
             if spec.kind == "latent_pages":
                 return LatentPrefillView(p, table_row, self.block_size)
+            if spec.kind == "nothing":
+                return NoView()
             return StatePrefillView(p, table_row[-1], true_len)
 
         return [view(spec, p) for spec, p in zip(self.layers, pools)]
@@ -303,6 +319,8 @@ class PagedKVCache:
             if spec.kind == "latent_pages":
                 return LatentDecodeView(p, block_tables, seq_lens,
                                         self.block_size)
+            if spec.kind == "nothing":
+                return NoView()
             return StateDecodeView(p, seq_lens > 0)
 
         return [view(spec, p) for spec, p in zip(self.layers, pools)]
@@ -675,6 +693,13 @@ class PagedMixedView:
             self.block_size)
 
 
+class NoView:
+    """The hook of a layer that keeps nothing: there for the engine,
+    which reads every layer's ``pool`` back after a step."""
+
+    pool = None
+
+
 class StatePrefillView:
     """A slot_state layer's hook for single-request prefill. The layer
     starts from ``read()`` (a zero row: the prefill that takes a slot
@@ -703,7 +728,10 @@ class StateDecodeView:
     """A slot_state layer's hook for the batched decode step: ``read()``
     is every slot's row, ``write`` stores the rows of the ``active``
     slots and leaves an idle slot's row as it was. ``valid_len`` is
-    None: every row is one real token."""
+    None: every row is one real token. A layer whose kernel updates an
+    array in place (given ``active``, it leaves the idle rows itself)
+    names it in ``kept``, and that array is stored as it comes: a select
+    over it would read and write the whole pool once more."""
 
     valid_len = None
 
@@ -714,11 +742,12 @@ class StateDecodeView:
     def read(self):
         return self.pool
 
-    def write(self, arrays):
+    def write(self, arrays, kept=()):
         def keep(new, old):
             on = self.active.reshape((-1,) + (1,) * (old.ndim - 1))
             return jnp.where(on, new.astype(old.dtype), old)
 
         return StateDecodeView(
-            {name: keep(arrays[name], a) for name, a in self.pool.items()},
+            {name: arrays[name] if name in kept else keep(arrays[name], a)
+             for name, a in self.pool.items()},
             self.active)

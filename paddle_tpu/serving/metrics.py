@@ -56,7 +56,11 @@ An empty ring gives ``None`` for its entry, never 0. (``state`` and
 ``latent``, the slot_state and latent_pages sides of the cache, are
 added by ``Engine.stats()``; ``latent.cached_tokens`` is the mean over
 the recent decode steps of the tokens the cache held for the decoding
-slots, from the same ring of step rows.)
+slots, from the same ring of step rows. ``ssm``, for a model that
+declares state-space layers (``model.ssm_layers``): ``layers``,
+``state_bytes_slot`` (one slot's state and convolution tail over all of
+them) and ``active_slots``, the mean over the same recent decode steps
+of the slots whose state the step read and wrote.)
 
 Spans: ``span(name, **meta)`` IS ``jax.profiler.TraceAnnotation`` — a
 span lands in the profiler's trace, on the device trace's clock, and
@@ -481,6 +485,13 @@ class EngineMetrics:
         engine counts them for a latent cache only); None while empty."""
         live = [r[-3] for r in list(self.steps) if r[-1]]
         return statistics.fmean(live) if live else None
+
+    def active_slots_mean(self):
+        """Mean, over the recent steps in which a decode ran, of the
+        slots it decoded: the rows whose recurrent state a state-space
+        layer's step read and wrote; None while empty."""
+        rows = [r[-1] for r in list(self.steps) if r[-1]]
+        return statistics.fmean(rows) if rows else None
 
     def on_prefill_done(self, seconds, tokens, bucket):
         self.prefills.append((seconds, tokens, bucket))

@@ -74,6 +74,9 @@ class TestPhasesAtTinySize:
     def test_serve_mla(self):
         cs.phase_serve_mla(on_chip=False, dtype="float32")
 
+    def test_serve_ssm(self):
+        cs.phase_serve_ssm(on_chip=False, dtype="float32")
+
     def test_train4_shards_over_four_devices(self, train_out):
         from paddle_tpu.distributed import mesh as pmesh
 

@@ -875,3 +875,72 @@ class TestFusedCE:
                 assert out == [True]
         finally:
             fl.set_flags({"FLAGS_fused_lm_head_ce": False})
+
+
+class TestSsmDecodeKernel:
+    """``ssm_decode`` (serving/kernels/ssm.py) in interpret mode against
+    its ``jax.numpy`` twin: the state of the active slots updated in
+    place, an idle slot's row bit for bit as it was."""
+
+    @staticmethod
+    def _inputs(s, h, p, g, n, dtype, seed):
+        rng = np.random.RandomState(seed)
+        x = jnp.asarray(rng.randn(s, h, p), dtype)
+        dt = jnp.asarray(np.log1p(np.exp(rng.randn(s, h))), jnp.float32)
+        a = -jnp.asarray(rng.uniform(1.0, 16.0, h), jnp.float32)
+        d = jnp.asarray(rng.uniform(0.5, 1.5, h), jnp.float32)
+        b = jnp.asarray(rng.randn(s, g, n), dtype)
+        c = jnp.asarray(rng.randn(s, g, n), dtype)
+        active = jnp.asarray(rng.rand(s) < 0.6)
+        state = jnp.asarray(rng.randn(s, g, n, h // g * p), jnp.float32)
+        return x, dt, a, d, b, c, active, state
+
+    @pytest.mark.parametrize("s,h,p,g,n,dtype", [
+        (5, 4, 8, 2, 16, jnp.float32),
+        (6, 16, 16, 8, 24, jnp.bfloat16)])
+    def test_kernel_matches_its_twin_and_idle_slots_stay(self, s, h, p, g,
+                                                         n, dtype):
+        from paddle_tpu.serving.kernels import ssm
+
+        args = self._inputs(s, h, p, g, n, dtype, seed=s)
+        active = np.asarray(args[6])
+        assert active.any() and not active.all()
+        y_t, s_t = ssm.ssm_decode_reference(*args)
+        y_k, s_k = ssm.ssm_decode_kernel(*args, interpret=True)
+        assert y_k.shape == (s, h, p) and y_k.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(y_k)[active],
+                                   np.asarray(y_t)[active],
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(s_k), np.asarray(s_t),
+                                   rtol=1e-6, atol=1e-6)
+        for new in (s_k, s_t):
+            assert np.array_equal(np.asarray(new)[~active],
+                                  np.asarray(args[7])[~active])
+        assert not np.array_equal(np.asarray(s_k)[active],
+                                  np.asarray(args[7])[active])
+
+    def test_off_the_tpu_the_dispatch_takes_the_twin(self):
+        from paddle_tpu.serving.kernels import ssm
+
+        args = self._inputs(3, 4, 8, 2, 16, jnp.float32, seed=1)
+        text = str(jax.make_jaxpr(ssm.ssm_decode)(*args))
+        assert "pallas_call" not in text
+        y, new = ssm.ssm_decode(*args)
+        y_t, s_t = ssm.ssm_decode_reference(*args)
+        assert np.array_equal(np.asarray(y), np.asarray(y_t))
+        assert np.array_equal(np.asarray(new), np.asarray(s_t))
+
+    def test_all_layers_share_one_trace_of_the_kernel(self):
+        """The kernel body sits in a jitted wrapper: two calls in one
+        program are two calls of one ``_ssm_decode``."""
+        from paddle_tpu.serving.kernels import ssm
+
+        args = self._inputs(3, 4, 8, 2, 16, jnp.float32, seed=2)
+
+        def two_layers(*a):
+            y, state = ssm.ssm_decode_kernel(*a, interpret=True)
+            return ssm.ssm_decode_kernel(*a[:7], state, interpret=True)
+
+        text = str(jax.make_jaxpr(two_layers)(*args))
+        assert text.count("name=_ssm_decode") == 2
+        assert text.count("name=ssm_decode") == 1

@@ -238,6 +238,132 @@ class TestGroupLimitedRouting:
         assert pairs == t * k
 
 
+# the softmax router's program, primitive by primitive, as PR 35 left it:
+# what the two sparse cells of the benchmark trace
+PLAIN_ROUTE = [
+    "dot_general", "reduce_max", "max", "broadcast_in_dim",
+    "stop_gradient", "sub", "exp", "reduce_sum", "broadcast_in_dim", "div",
+    "top_k", "reduce_sum", "broadcast_in_dim", "max", "div", "slice",
+    "squeeze", "jit", "reduce_sum", "div", "reduce_sum", "div", "mul",
+    "reduce_sum", "mul"]
+GROUPED_ROUTE = [
+    "dot_general", "reduce_max", "max", "broadcast_in_dim",
+    "stop_gradient", "sub", "exp", "reduce_sum", "broadcast_in_dim", "div",
+    "reshape", "reduce_max", "top_k", "broadcast_in_dim", "iota",
+    "broadcast_in_dim", "eq", "reduce_or", "broadcast_in_dim", "jit",
+    "reshape", "top_k", "mul", "slice", "squeeze", "jit", "reduce_sum",
+    "div", "reduce_sum", "div", "mul", "reduce_sum", "mul"]
+
+
+def _primitives(fn, *args):
+    return [e.primitive.name for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns]
+
+
+class TestSigmoidBiasRouting:
+    """``route(select_bias=...)``: sigmoid scores, the bias in the
+    choice and out of the weights (Nemotron-H's router)."""
+
+    def _inputs(self, t=50, d=12, e=32, seed=0):
+        rng = np.random.RandomState(seed)
+        return (rng.randn(t, d).astype(np.float32),
+                rng.randn(d, e).astype(np.float32),
+                rng.uniform(-0.3, 0.3, e).astype(np.float32))
+
+    @pytest.mark.parametrize("k,scaling,renormalise", [
+        (6, 2.5, True), (3, 1.0, False), (1, 2.0, True)])
+    def test_route_matches_a_router_written_apart(self, k, scaling,
+                                                  renormalise):
+        x, gate_w, bias = self._inputs(seed=k)
+        weights, experts, _ = route(
+            jnp.asarray(x), jnp.asarray(gate_w), k, renormalise,
+            routed_scaling_factor=scaling, select_bias=jnp.asarray(bias))
+        scores = 1.0 / (1.0 + np.exp(-(x @ gate_w)))
+        chosen = np.argsort(-(scores + bias), axis=-1)[:, :k]
+        want = np.take_along_axis(scores, chosen, -1)
+        if renormalise:
+            want = want / want.sum(-1, keepdims=True)
+        assert np.array_equal(np.asarray(experts), chosen)
+        np.testing.assert_allclose(np.asarray(weights), want * scaling,
+                                   rtol=1e-5)
+
+    def test_bias_changes_the_choice_and_not_the_weights(self):
+        x, gate_w, bias = self._inputs(seed=7)
+        x, gate_w = jnp.asarray(x), jnp.asarray(gate_w)
+        none = jnp.zeros((32,), jnp.float32)
+        pushed = none.at[5].set(10.0)       # expert 5 always wins a place
+        w0, e0, _ = route(x, gate_w, 6, True, routed_scaling_factor=2.5,
+                          select_bias=none)
+        w1, e1, _ = route(x, gate_w, 6, True, routed_scaling_factor=2.5,
+                          select_bias=pushed)
+        assert not np.array_equal(np.asarray(e0), np.asarray(e1))
+        assert (np.asarray(e1)[:, 0] == 5).all()
+        # its weight is its own score's share, not the pushed one's: no
+        # weight is anywhere near 10, and a row's weights sum to the
+        # scaling factor
+        scores = np.asarray(jax.nn.sigmoid(x @ gate_w))
+        picked = np.take_along_axis(scores, np.asarray(e1), -1)
+        np.testing.assert_allclose(
+            np.asarray(w1), 2.5 * picked / picked.sum(-1, keepdims=True),
+            rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(w1).sum(-1), 2.5, rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(w0).sum(-1), 2.5, rtol=1e-5)
+
+    def test_a_bias_with_group_limited_routing_is_refused(self):
+        x, gate_w, bias = self._inputs()
+        with pytest.raises(ValueError, match="selection bias"):
+            route(jnp.asarray(x), jnp.asarray(gate_w), 4, True, 4, 2,
+                  select_bias=jnp.asarray(bias))
+
+    def test_ungated_relu2_experts_match_a_dense_oracle(self):
+        """``moe_forward(gated=False, activation="relu2")`` with the
+        sigmoid router: every expert on every token, weighted by the
+        router, is the same sum; two halves add up to it."""
+        t, d, h, e, k = 24, 8, 16, 8, 3
+        rng = np.random.RandomState(2)
+        x = jnp.asarray(rng.randn(t, d).astype(np.float32))
+        gate_w = jnp.asarray(rng.randn(d, e).astype(np.float32))
+        bias = jnp.asarray(rng.uniform(-0.3, 0.3, e).astype(np.float32))
+        w1 = jnp.asarray(rng.randn(e, d, h).astype(np.float32) * 0.3)
+        w2 = jnp.asarray(rng.randn(e, h, d).astype(np.float32) * 0.3)
+        kw = dict(top_k=k, activation="relu2", gated=False,
+                  norm_topk_prob=True, routed_scaling_factor=2.5,
+                  select_bias=bias)
+        whole, _, stats = moe_forward(x, gate_w, w1, None, w2, None, **kw)
+        weights, experts, _ = route(x, gate_w, k, True,
+                                    routed_scaling_factor=2.5,
+                                    select_bias=bias)
+        dense = jnp.zeros((t, e)).at[jnp.arange(t)[:, None], experts].set(
+            weights)
+        hid = jnp.square(jax.nn.relu(jnp.einsum("td,edf->etf", x, w1)))
+        want = jnp.einsum("te,etd->td", dense,
+                          jnp.einsum("etf,efd->etd", hid, w2))
+        np.testing.assert_allclose(np.asarray(whole), np.asarray(want),
+                                   rtol=1e-4, atol=1e-5)
+        assert int(stats[0]) == t * k
+        halves = sum(moe_forward(x, gate_w, w1[lo:lo + 4], None,
+                                 w2[lo:lo + 4], None, lo=lo, **kw)[0]
+                     for lo in (0, 4))
+        np.testing.assert_allclose(np.asarray(halves), np.asarray(whole),
+                                   rtol=1e-4, atol=1e-5)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_the_softmax_path_traces_to_what_it_did(self, dtype):
+        """No bias: the router of the sparse cells the benchmark already
+        has is the program it was, plain and group-limited."""
+        x = jnp.zeros((9, 8), dtype)
+        gate_w = jnp.zeros((8, 16), dtype)
+        assert _primitives(lambda a, b: route(a, b, 4, True),
+                           x, gate_w) == PLAIN_ROUTE
+        assert _primitives(
+            lambda a, b: route(a, b, 6, False, 8, 3, 16.0),
+            x, gate_w) == GROUPED_ROUTE
+        plain = jax.make_jaxpr(lambda a, b: route(a, b, 4, True))(x, gate_w)
+        asked = jax.make_jaxpr(lambda a, b: route(
+            a, b, 4, True, 1, 1, 1.0, select_bias=None))(x, gate_w)
+        assert str(plain) == str(asked)
+        assert "logistic" not in str(plain)
+
+
 class TestMoeGmmKernel:
     """kernels/moe_gmm.py in interpret mode against jax.lax.ragged_dot
     (the rows of no group are undefined and not compared)."""
@@ -248,6 +374,10 @@ class TestMoeGmmKernel:
         (640, 64, 128, [100, 0, 3, 200, 1, 0, 136, 200]),    # 128-row tiles
         (40, 16, 24, [5, 30, 5]),                # M padded to the tile
         (64, 16, 128, [64]),                     # one group, whole tiles
+        # a width the lanes cannot tile under a K they can (1856 under
+        # 2688): the matrices are taken transposed, whole
+        (96, 128, 72, [10, 0, 50, 20]),
+        (640, 128, 24, [100, 0, 3, 200, 1, 0, 136, 200]),
     ])
     def test_kernel_matches_ragged_dot(self, m, k, n, sizes):
         from paddle_tpu.kernels import moe_gmm as mg

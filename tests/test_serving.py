@@ -382,6 +382,81 @@ class TestLatentDecodeKernel:
                                    rtol=1e-4, atol=1e-5)
 
 
+class TestALayerThatKeepsNothing:
+    """A spec entry of kind ``nothing`` (a layer that is one expert or
+    MLP block) through ``PagedKVCache`` and both view builders: no pool,
+    a hook nobody calls, and the other layers' bookkeeping as it was."""
+
+    @staticmethod
+    def _cache():
+        from paddle_tpu.serving.kv_cache import NoCache, SlotState
+
+        state = SlotState((("state", (2, 4, 8), "float32"),
+                           ("conv", (3, 10), "float32")))
+        return PagedKVCache(
+            [state, NoCache(), KVPages(2, 8, "float32"), NoCache()],
+            num_blocks=12, block_size=4, max_slots=3,
+            max_blocks_per_slot=6)
+
+    def test_pools_by_kind(self):
+        cache = self._cache()
+        assert [spec.kind for spec in cache.layers] == [
+            "slot_state", "nothing", "kv_pages", "nothing"]
+        assert cache.has_slot_state and not cache.has_latent
+        assert cache.pools[1] is None and cache.pools[3] is None
+        assert isinstance(cache.pools[2], KVBlockPool)
+        assert cache.state_stats() == {
+            "slots": 3, "layers": 1, "slot_bytes": (64 + 30) * 4,
+            "pool_bytes": 3 * (64 + 30) * 4}
+        # a layer without a pool is an empty node of the pools' tree:
+        # nothing of it is donated, copied or reset
+        leaves = jax.tree_util.tree_leaves(cache.pools)
+        assert len(leaves) == 2 + 2
+        assert cache.pools_alive()
+        cache.reset_pools()
+        assert cache.pools[1] is None
+
+    def test_both_view_builders_and_the_engines_read_back(self):
+        from paddle_tpu.serving.kv_cache import NoView
+
+        cache = self._cache()
+        assert cache.ensure_capacity(1, 7)
+        row = jnp.asarray(cache.block_tables[1])
+        views = cache.prefill_views(cache.pools, row, jnp.int32(6))
+        assert [type(v).__name__ for v in views] == [
+            "StatePrefillView", "NoView", "PagedPrefillView", "NoView"]
+        assert isinstance(views[1], NoView) and views[1].pool is None
+        lens = jnp.asarray([0, 6, 0], jnp.int32)
+        dviews = cache.decode_views(cache.pools,
+                                    jnp.asarray(cache.block_tables), lens)
+        assert [type(v).__name__ for v in dviews] == [
+            "StateDecodeView", "NoView", "PagedDecodeView", "NoView"]
+        # what the engine's steps hand back: the pools, layer by layer
+        back = [v.pool for v in dviews]
+        assert back[1] is None and back[3] is None
+        assert jax.tree_util.tree_structure(back) \
+            == jax.tree_util.tree_structure(cache.pools)
+        # release and copy-on-write walk past the empty entries
+        assert cache.make_writable(1, 0, 7)
+        cache.release_slot(1)
+        assert cache.seq_lens[1] == 0
+
+    def test_a_kernels_rows_are_stored_as_they_come(self):
+        """``StateDecodeView.write(kept=...)``: the named array is not
+        selected over again; the others are."""
+        from paddle_tpu.serving.kv_cache import StateDecodeView
+
+        pool = {"state": jnp.zeros((3, 2)), "conv": jnp.zeros((3, 2))}
+        ones = {"state": jnp.ones((3, 2)), "conv": jnp.ones((3, 2))}
+        active = jnp.asarray([True, False, True])
+        plain = StateDecodeView(pool, active).write(ones).pool
+        assert np.asarray(plain["state"])[:, 0].tolist() == [1, 0, 1]
+        kept = StateDecodeView(pool, active).write(
+            ones, kept=("state",)).pool
+        assert kept["state"] is ones["state"]
+        assert np.asarray(kept["conv"])[:, 0].tolist() == [1, 0, 1]
+
+
 class TestLatentPagesBesideKVPages:
     """One ``PagedKVCache`` holding both paged kinds: a page id means
     the same page in every layer's pool, whatever the layer keeps

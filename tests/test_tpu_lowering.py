@@ -35,6 +35,7 @@ from paddle_tpu.kernels import moe_gmm as mg
 # the package re-exports a function under the module's name
 pa = importlib.import_module("paddle_tpu.serving.kernels.paged_attention")
 mla = importlib.import_module("paddle_tpu.serving.kernels.mla_attention")
+ssm = importlib.import_module("paddle_tpu.serving.kernels.ssm")
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 # the forward kernel's place in an instruction's ``op_name``, below the
@@ -63,6 +64,12 @@ QWEN_EXPERTS, QWEN_HID, QWEN_WIDTH, QWEN_TOPK = 256, 2048, 512, 10
 # and 128 in v (benchmark/traffic/longctx-backlog.json)
 MLA_S, MLA_NB, MLA_MB, MLA_H, MLA_RANK, MLA_W = 128, 60000, 640, 128, 512, 640
 MLA_QK, MLA_V = 192, 128
+# nemotron3nano-longreason-backlog: 256 slots of Mamba-2 state, 64 heads
+# x 64 with a state of 128 in 8 groups; 64 experts held of 2688 x 1856
+# (a width that is no multiple of 128), six pairs a token
+# (benchmark/traffic/longreason-backlog.json)
+SSM_S, SSM_H, SSM_P, SSM_G, SSM_N = 256, 64, 64, 8, 128
+NEMO_EXPERTS, NEMO_HID, NEMO_WIDTH, NEMO_TOPK = 64, 2688, 1856, 6
 
 
 def _flash(dtype, d, segmented=False):
@@ -180,6 +187,32 @@ def _cases():
              ((QWEN_EXPERTS, QWEN_WIDTH, QWEN_HID), BF16),
              ((QWEN_EXPERTS,), I32)],
             {"moe_gmm"}))
+    def relu2_experts(x, w1, w2, sizes):
+        # an ungated expert layer's two calls: up, then down
+        h = jnp.square(jax.nn.relu(mg.moe_gmm(x, w1, sizes,
+                                              interpret=False)))
+        return mg.moe_gmm(h, w2, sizes, interpret=False)
+
+    for name, tokens in (("moe_gmm_bf16_w1856_decode", SSM_S),
+                         ("moe_gmm_bf16_w1856_prefill", 8192)):
+        cases.append((
+            name, relu2_experts,
+            [((tokens * NEMO_TOPK, NEMO_HID), BF16),
+             ((NEMO_EXPERTS, NEMO_HID, NEMO_WIDTH), BF16),
+             ((NEMO_EXPERTS, NEMO_WIDTH, NEMO_HID), BF16),
+             ((NEMO_EXPERTS,), I32)],
+            {"moe_gmm"}))
+    for name, s, dtype in (("ssm_decode_bf16_nemotron_cell", SSM_S, BF16),
+                           ("ssm_decode_f32_8_slots", 8, F32)):
+        cases.append((
+            name,
+            lambda x, dt, a, d, b, c, on, state: ssm.ssm_decode_kernel(
+                x, dt, a, d, b, c, on, state, interpret=False),
+            [((s, SSM_H, SSM_P), dtype), ((s, SSM_H), F32),
+             ((SSM_H,), F32), ((SSM_H,), F32), ((s, SSM_G, SSM_N), dtype),
+             ((s, SSM_G, SSM_N), dtype), ((s,), jnp.bool_),
+             ((s, SSM_G, SSM_N, SSM_H // SSM_G * SSM_P), F32)],
+            {"ssm_decode"}))
     for name, h, hkv in (("paged_mixed_bf16_mha", 16, 16),
                          ("paged_mixed_bf16_gqa", 32, 8)):
         pool = ((NB, BS, hkv, D), BF16)
@@ -272,6 +305,8 @@ class TestMosaicCompile:
         if name.startswith("moe_gmm"):
             # the kernel body is jitted: both calls are one kernel name
             assert found == {"moe_gmm": 2}
+        if name.startswith("ssm_decode"):
+            assert found == {"ssm_decode": 1}
 
 
 class TestInterpretNeverOnTPU:
@@ -614,3 +649,59 @@ class TestPrefillHeadOnOneRow:
         assert logits in every.as_text()
         assert "%d,%d]" % (self.P, self.VOCAB) not in one.as_text()
         assert mosaic_kernels(one.as_text()) == {"flash_fwd": 1}
+
+
+class TestNemotronDecodeStep:
+    """The decode step of ``nemotron3nano-longreason-backlog`` compiled
+    for the v5e at the published widths (pattern ``MEM*``, 8 experts
+    held, 64 slots: the cell's is ``MEMEM*EME``, 64 and 256): one
+    ``ssm_decode`` a Mamba-2 layer, one ``paged_decode``, the expert
+    layer's two ``moe_gmm``, and nothing but the kernel reads or writes
+    a layer's [slots, 8, 128, 512] float32 state: it comes in as the
+    step's (donated) argument, goes through the kernel aliased in place
+    and out as the step's result. No select keeps the idle slots' rows
+    and no second pass reads the state out."""
+
+    SLOTS = 64
+
+    def test_kernels_and_one_reader_of_the_state(self, v5e, monkeypatch):
+        import re
+
+        from paddle_tpu import serving
+        from paddle_tpu.models.nemotron_h import (NemotronHConfig,
+                                                  NemotronHForCausalLM)
+        from paddle_tpu.nn import initializer
+
+        monkeypatch.setattr(initializer.Initializer, "create", _bf16_zeros)
+        # the kernels' dispatch asks the backend; here it is the CPU
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        model = NemotronHForCausalLM(NemotronHConfig(
+            vocab_size=4096, hybrid_override_pattern="MEM*",
+            experts_held=range(8), dtype="bfloat16"))
+        model.eval()
+        eng = serving.Engine(model, max_slots=self.SLOTS, num_blocks=512,
+                             block_size=16, max_model_len=2048)
+        _, _, fn, args = eng._hot_step()
+        avals = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(
+                jnp.shape(a), jnp.result_type(a), sharding=v5e), args)
+        text = eng._run_eval(
+            jax.jit(fn, donate_argnums=(1,)).lower, *avals).compile(
+            ).as_text()
+        assert mosaic_kernels(text) == {
+            "ssm_decode": 2, "paged_decode": 1, "moe_gmm": 2}
+        for layer, scope in enumerate(("ssm", "moe", "ssm", "attn")):
+            assert "/layer_%d/%s/" % (layer, scope) in text
+        state = "f32[%d,8,128,512]" % self.SLOTS
+        touching = [line.strip() for line in text.splitlines()
+                    if state in line
+                    and line.lstrip().startswith(("%", "ROOT "))]
+        allowed = re.compile(
+            r" (parameter|get-tuple-element|tuple|bitcast)\(|"
+            r" custom-call\(.*op_name=\"[^\"]*/ssm_decode/pallas_call")
+        others = [line[:160] for line in touching
+                  if not allowed.search(line)]
+        assert others == []
+        # in as an argument, through the kernel, out as a result: twice
+        assert sum(" parameter(" in line for line in touching) == 2
+        assert sum(" custom-call(" in line for line in touching) == 2

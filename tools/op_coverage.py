@@ -300,6 +300,9 @@ BEYOND_REFERENCE = [
     ("mla_decode", "absorbed latent-attention decode over latent pages "
      "(one shared row a token for every head)",
      "serving.kernels.mla_attention.mla_attention"),
+    ("ssm_decode", "Mamba-2 decode step over slot state (a slot's float32 "
+     "state read once and written once, in place)",
+     "serving.kernels.ssm.ssm_decode"),
 ]
 
 

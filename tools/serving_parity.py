@@ -96,8 +96,10 @@ def program_routing(model, tokens):
     from paddle_tpu.parallel.moe import route
 
     caught = []
-    sparse = [layer.mlp for layer in model.model.layers
-              if hasattr(layer.mlp, "experts")]
+    # an expert block is whatever holds the layer's ``experts``: a
+    # layer's second half (``mlp``) or a layer's one mixer
+    sparse = [block for block in model.sublayers()
+              if hasattr(block, "experts")]
     hooks = [mlp.register_forward_pre_hook(
         lambda _layer, inputs: caught.append(inputs[0]))
         for mlp in sparse]
@@ -109,11 +111,14 @@ def program_routing(model, tokens):
     out = []
     for mlp, x in zip(sparse, caught):
         e = mlp.experts
-        # the scaling factor changes no choice
+        # the scaling factor changes no choice; a selection bias does
+        bias = getattr(mlp, "e_score_correction_bias", None)
         _, chosen, _ = route(x.reshape(-1, x.shape[-1]),
                              e.gate_weight._value, e.top_k,
                              e.norm_topk_prob, getattr(mlp, "n_group", 1),
-                             getattr(mlp, "topk_group", 1))
+                             getattr(mlp, "topk_group", 1),
+                             select_bias=None if bias is None
+                             else bias._value)
         out.append(np.asarray(chosen))
     return out
 
