@@ -46,11 +46,15 @@ from .flash_attention import _dot, resolve_interpret
 _RHS_BLOCK_BYTES = 2 * 1024 * 1024
 
 
+# rows a group from which a visit multiplies 128 rows (``row_tile``)
+ROWS_A_GROUP = 64
+
+
 def row_tile(m, groups):
     """Rows a visit multiplies: 128 where a group owns about a tile or
     more (prefill), 32 where it owns a few rows (decode), so the MXU is
     not fed 125 rows of padding for every 3 real ones."""
-    return 128 if m >= 64 * groups else 32
+    return 128 if m >= ROWS_A_GROUP * groups else 32
 
 
 def _col_tile(k, n, itemsize):

@@ -556,9 +556,10 @@ class NemotronHForCausalLM(Layer):
             mixer.balance(x.reshape(-1, x.shape[-1]), rounds, step)
 
     def moe_step_stats(self):
-        """int32 [expert layers, 3] of the step just traced: pairs
+        """int32 [expert layers, 4] of the step just traced: pairs
         routed to the experts held here, held experts that received a
-        row, the largest load of one expert."""
+        row, the largest load of one expert, rows handed to the
+        grouped matmuls."""
         return jnp.stack([layer.mixer.step_stats
                           for layer in self.backbone.layers
                           if layer.kind == "E"])

@@ -528,9 +528,10 @@ class Qwen3NextForCausalLM(Layer):
         return self._run(input_ids, caches, position_offset, logits_at)
 
     def moe_step_stats(self):
-        """int32 [layers, 3] of the step just traced: pairs routed to
+        """int32 [layers, 4] of the step just traced: pairs routed to
         the experts held here, held experts that received a row, the
-        largest load of one expert."""
+        largest load of one expert, rows handed to the grouped
+        matmuls."""
         return jnp.stack([layer.mlp.step_stats
                           for layer in self.model.layers])
 

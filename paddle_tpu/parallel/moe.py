@@ -15,16 +15,25 @@ no expert is padded; an expert that received no row costs nothing. What
 the experts held elsewhere would have added is LEFT OUT of the result:
 with ``experts_held`` the whole range that is nothing, with a share it
 is the partial sum a chip of an expert-parallel deployment computes
-before the exchange. The exchange itself (all-to-all between the chips
+before the exchange, and the layer pays for the pairs that land here,
+not for every pair of every token: a prefill lays out one block of the
+sorted pair list, sized to hold the pairs of the experts held, so its
+forward gather, its activation and the kernel's grid are a block's
+(``_pair_block``; every pair is laid out instead where a skewed routing
+puts more than a block here, so nothing is dropped). A decode step,
+whose pairs are a block at most, and a layer that holds every expert lay
+all T * k pairs out. The exchange itself (all-to-all between the chips
 that share a layer) is not here; nothing stands in for it.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
 from ..core.dispatch import primitive
-from ..kernels.moe_gmm import grouped_matmul
+from ..kernels.moe_gmm import ROWS_A_GROUP, grouped_matmul
 from ..nn import initializer as I
 from ..nn.layer import Layer
 
@@ -98,6 +107,75 @@ def route(x, gate_w, top_k, norm_topk_prob, n_group=1, topk_group=1,
     return weights, experts.astype(jnp.int32), aux
 
 
+# A block of the sorted pair list is no fewer rows than a decode step
+# has pairs (256 slots x 6): a decode step lays out every pair.
+_MIN_BLOCK = 2048
+
+
+def _pair_block(pairs, held, num_experts):
+    """Rows of the sorted pair list a prefill lays out, from shapes
+    alone: a third more than the held experts' share of the ``pairs``
+    (their part of the router's width), so that a block holds the pairs
+    of a layer whose load is near even; whole 128-row tiles; and, where
+    ``moe_gmm`` would multiply every pair on 128-row tiles, enough rows
+    a held expert that it does so on a block too."""
+    block = max(_MIN_BLOCK, -(-4 * pairs * held // (3 * num_experts)))
+    if pairs >= ROWS_A_GROUP * held:
+        block = max(block, ROWS_A_GROUP * held)
+    return -(-block // 128) * 128
+
+
+def _run_experts(rows, row_key, sizes, w_in, b_in, w_out, b_out,
+                 activation, gated):
+    """The held experts on rows sorted by expert: ``rows`` [M, D],
+    ``row_key`` [M] a row's held expert (``held`` for a row of no
+    group), ``sizes`` [H] rows a group -> y [M, D]. A row of no group
+    comes back undefined, not zero."""
+    h = grouped_matmul(rows, w_in, sizes)
+    # a row's own expert, for the biases (rows past every group: any)
+    expert_of_row = jnp.minimum(row_key, w_in.shape[0] - 1)
+    if b_in is not None:
+        h = h + _A(b_in)[expert_of_row]
+    act = _ACTIVATIONS[activation]
+    if gated:
+        f = h.shape[-1] // 2
+        h = act(h[:, :f]) * h[:, f:]
+    else:
+        h = act(h)
+    y = grouped_matmul(h.astype(rows.dtype), w_out, sizes)
+    if b_out is not None:
+        y = y + _A(b_out)[expert_of_row]
+    return y
+
+
+def _lay_out(x, weights, here, order, sorted_key, sizes, rows, top_k,
+             experts):
+    """The first ``rows`` rows of the sorted pair list laid out at once
+    ([rows, ...] arrays), which must hold every pair of an expert held
+    here: gather their rows of ``x``, run the experts, and sum each
+    token's pairs back in float32. ``rows`` = T * k lays out every pair,
+    whatever the routing: what a decode step runs, and a layer that
+    holds every expert. -> out [T, D]."""
+    t, d = x.shape
+    pairs = order.shape[0]
+    y = _run_experts(x[order[:rows] // top_k], sorted_key[:rows], sizes,
+                     *experts)                                   # [rows, D]
+    # back to pair order, one choice at a time: k gathers of [T, D]
+    # summed in float32 as they come, so the [T, k, D] block is never
+    # laid out (k is no multiple of a tile: that reshape is a copy).
+    # A row of no group is undefined, not zero.
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(pairs, dtype=order.dtype)).reshape(t, top_k)
+    if rows < pairs:
+        # a pair held elsewhere sorts past the rows laid out: any row
+        back = jnp.minimum(back, rows - 1)
+    out = jnp.zeros((t, d), jnp.float32)
+    for j in range(top_k):
+        picked = y[back[:, j]].astype(jnp.float32) * weights[:, j, None]
+        out = out + jnp.where(here[:, j, None], picked, 0.0)
+    return out.astype(x.dtype)
+
+
 def moe_forward(x, gate_w, w_in, b_in, w_out, b_out, *, top_k, lo=0,
                 activation="gelu", gated=False, norm_topk_prob=None,
                 n_group=1, topk_group=1, routed_scaling_factor=1.0,
@@ -111,9 +189,17 @@ def moe_forward(x, gate_w, w_in, b_in, w_out, b_out, *, top_k, lo=0,
     or None. ``norm_topk_prob`` None means "when top_k > 1" (a single
     choice keeps its raw probability, or the router would get no
     gradient); ``n_group``, ``topk_group``, ``routed_scaling_factor``
-    and ``select_bias`` are ``route``'s. -> (out [T, D], aux loss, stats int32 [3]): the share of
-    the result the held experts give; pairs routed here, held experts
-    that received a row, the largest load of one expert."""
+    and ``select_bias`` are ``route``'s. -> (out [T, D], aux loss, stats
+    int32 [4]): the share of the result the held experts give; pairs
+    routed here, held experts that received a row, the largest load of
+    one expert, rows of the sorted pair list handed to the grouped
+    matmuls.
+
+    Where some experts are held elsewhere and the T * k pairs are more
+    than one block (``_pair_block``: a prefill), the layer lays out the
+    first block of the sorted list if every pair that lands here is in
+    it, and every pair if not; else (a decode step, a layer that holds
+    every expert) every pair, as one program."""
     x = _A(x)
     w_in, w_out = _A(w_in), _A(w_out)
     t, d = x.shape
@@ -125,42 +211,34 @@ def moe_forward(x, gate_w, w_in, b_in, w_out, b_out, *, top_k, lo=0,
                                   routed_scaling_factor, select_bias)
     here = jnp.logical_and(experts >= lo, experts < lo + held)   # [T, k]
     # pairs sorted by held expert; the pairs of experts held elsewhere
-    # sort to the end, past every group, where nothing is computed
+    # sort to the end, past every group: the live count is ends[held]
     key = jnp.where(here, experts - lo, held).reshape(-1)        # [T*k]
     order = jnp.argsort(key, stable=True)
     sorted_key = key[order]
     # the keys are sorted: a group's rows lie between two searches
     ends = jnp.searchsorted(sorted_key, jnp.arange(held + 1, dtype=key.dtype))
     sizes = (ends[1:] - ends[:-1]).astype(jnp.int32)
-    rows = x[order // top_k]                                     # [T*k, D]
-    h = grouped_matmul(rows, w_in, sizes)
-    # a row's own expert, for the biases (rows past every group: any)
-    expert_of_row = jnp.minimum(sorted_key, held - 1)
-    if b_in is not None:
-        h = h + _A(b_in)[expert_of_row]
-    act = _ACTIVATIONS[activation]
-    if gated:
-        f = h.shape[-1] // 2
-        h = act(h[:, :f]) * h[:, f:]
+    lay_out = functools.partial(
+        _lay_out, top_k=top_k,
+        experts=(w_in, b_in, w_out, b_out, activation, gated))
+    routed = (x, weights, here, order, sorted_key, sizes)
+    pairs, num_experts = t * top_k, jnp.shape(gate_w)[-1]
+    block = _pair_block(pairs, held, num_experts)
+    if held < num_experts and pairs > block:
+        # two programs under one cond: a block where it holds every
+        # pair that lands here, which it is sized to do; every pair
+        # where the routing is skewed past it, so none is dropped
+        rows_run = jnp.where(ends[held] <= block, block, pairs)
+        out = jax.lax.cond(rows_run == block,
+                           functools.partial(lay_out, rows=block),
+                           functools.partial(lay_out, rows=pairs), *routed)
     else:
-        h = act(h)
-    y = grouped_matmul(h.astype(x.dtype), w_out, sizes)
-    if b_out is not None:
-        y = y + _A(b_out)[expert_of_row]
-    # back to pair order, one choice at a time: k gathers of [T, D]
-    # summed in float32 as they come, so the [T, k, D] block is never
-    # laid out (k is no multiple of a tile: that reshape is a copy).
-    # A row of no group is undefined, not zero.
-    back = jnp.zeros_like(order).at[order].set(
-        jnp.arange(order.shape[0], dtype=order.dtype)).reshape(t, top_k)
-    out = jnp.zeros((t, d), jnp.float32)
-    for j in range(top_k):
-        picked = y[back[:, j]].astype(jnp.float32) * weights[:, j, None]
-        out = out + jnp.where(here[:, j, None], picked, 0.0)
-    out = out.astype(x.dtype)
+        rows_run = pairs
+        out = lay_out(*routed, rows=pairs)
     stats = jnp.stack([jnp.sum(here, dtype=jnp.int32),
                        jnp.sum(sizes > 0, dtype=jnp.int32),
-                       jnp.max(sizes)])
+                       jnp.max(sizes),
+                       jnp.asarray(rows_run, jnp.int32)])
     return out, aux.astype(x.dtype), stats
 
 
@@ -244,7 +322,7 @@ class MoELayer(Layer):
 # variable-count protocol (exchange counts, then ragged payloads) has no
 # static-shape analog, so equal splits are required. The expert layer
 # above does not call these: it computes its own experts' share and
-# leaves the exchange between chips to a later PR (ROADMAP R1).
+# leaves the exchange between chips to a later PR (ROADMAP R3).
 # ---------------------------------------------------------------------------
 
 def global_scatter(x, group=None):

@@ -1263,8 +1263,9 @@ class Engine:
     def _with_moe_counters(self, tokens):
         """The step's tokens, and behind them for a model that declares
         expert layers the counters of the step being traced, flat int32
-        [3 * layers] (pairs routed here, experts that received a row,
-        the largest expert load; a layer after a layer): they ride back
+        [4 * layers] (pairs routed here, experts that received a row,
+        the largest expert load, rows handed to the grouped matmuls; a
+        layer after a layer): they ride back
         to the host in the step's one readback. A model that declares
         none gets its tokens as they are, and its compiled steps are
         what they were."""

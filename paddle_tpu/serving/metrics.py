@@ -44,9 +44,14 @@ here, ``to_dict()`` reduces them when asked):
       (``model.moe_layers``): the counters every compiled step hands
       back behind its tokens, a layer at a time (pairs routed to the
       experts held here, held experts that received a row, the largest
-      load of one expert). ``pairs``, ``experts_touched``, ``load_max``
+      load of one expert, rows of the sorted pair list handed to the
+      grouped matmuls). ``pairs``, ``experts_touched``, ``load_max``
       and ``load_max_over_mean`` (largest load over pairs / experts
       held) are means over the recent decode steps and their layers;
+      ``prefill_rows_over_pairs`` is the mean over the recent prefills
+      and their layers of the rows handed to the grouped matmuls over
+      the pairs routed here (1 is a layer that lays out what it
+      computes; ``None`` while the ring holds no prefill);
       ``calls`` lists the recent programs, prefills included, newest
       last, as [real token rows, rows the program ran (a prefill's
       bucket, every slot of a decode step), 1 for a decode step, pairs
@@ -200,6 +205,8 @@ STEP_RING = 512
 PREFILL_RING = 512
 GAP_RING = 16384
 MOE_RING = 2048
+# counters an expert layer's step returns (parallel/moe.py moe_forward)
+MOE_COUNTERS = 4
 
 
 def _percentile(ordered, q):
@@ -374,7 +381,7 @@ class EngineMetrics:
         self.prefills = collections.deque(maxlen=PREFILL_RING)
         self.token_gaps = collections.deque(maxlen=GAP_RING)
         # one row a compiled step of a model with expert layers:
-        # (real rows, rows run, int32 [layers, 3] counters, decode step?)
+        # (real rows, rows run, int32 [layers, 4] counters, decode step?)
         self.moe_calls = collections.deque(maxlen=MOE_RING)
         self.moe_experts_held = 0
 
@@ -497,13 +504,13 @@ class EngineMetrics:
         self.prefills.append((seconds, tokens, bucket))
 
     def on_moe_call(self, counters, rows, rows_run, decode):
-        """``counters``: the flat int32 [3 * layers] a step returned
+        """``counters``: the flat int32 [4 * layers] a step returned
         behind its tokens; ``rows``: its real token rows; ``rows_run``:
         the rows the program ran (a prefill's bucket, every slot of a
         decode step)."""
         self.moe_calls.append(
             (int(rows), int(rows_run),
-             np.asarray(counters).reshape(-1, 3), bool(decode)))
+             np.asarray(counters).reshape(-1, MOE_COUNTERS), bool(decode)))
 
     def _moe_dict(self):
         calls = list(self.moe_calls)
@@ -511,8 +518,14 @@ class EngineMetrics:
         if not steps:
             return None
         held = max(self.moe_experts_held, 1)
-        per = np.stack(steps).astype(np.float64)        # [n, layers, 3]
+        per = np.stack(steps).astype(np.float64)        # [n, layers, 4]
         pairs, touched, largest = per[..., 0], per[..., 1], per[..., 2]
+        prefills = [c for _, _, c, decode in calls if not decode]
+        laid_out = None
+        if prefills:
+            laid = np.stack(prefills).astype(np.float64)
+            laid_out = float(
+                (laid[..., 3] / np.maximum(laid[..., 0], 1.0)).mean())
         return {
             "layers": int(per.shape[1]),
             "experts_held": self.moe_experts_held,
@@ -522,6 +535,7 @@ class EngineMetrics:
             "load_max_over_mean": float(
                 (largest / np.maximum(pairs / held, 1e-9)).mean()),
             "recent_steps": len(steps),
+            "prefill_rows_over_pairs": laid_out,
             "calls": [[rows, rows_run, int(decode), c[:, 0].tolist(),
                        c[:, 1].tolist()]
                       for rows, rows_run, c, decode in calls],

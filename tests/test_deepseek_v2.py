@@ -195,6 +195,37 @@ def test_prefill_then_decode_match_the_full_forward(family, tiny,
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
 
+@pytest.mark.parametrize("block,fits", [(32, True), (8, False)])
+def test_a_prefill_that_lays_out_a_block_matches_the_reference(
+        family, tiny, monkeypatch, block, fits):
+    """The tiny model's prefill bucket has 32 x 3 pairs, a quarter of
+    them on the four experts held here: a block of 32 rows holds them
+    and is all the expert layers lay out, a block of 8 does not and
+    they lay out every pair; the logits through the cache are the
+    reference's either way, a decode step (2 slots x 3 pairs) lays out
+    its pairs as ever, and the engine's ring says how much the prefill
+    laid out."""
+    from paddle_tpu.parallel import moe
+
+    model, weights = tiny
+    steps = 4
+    seq = _ids(32 + steps, seed=3)
+    monkeypatch.setattr(moe, "_pair_block", lambda *shapes: block)
+    got, _ = logits_through_cache(_engine(model), seq, steps)
+    want = np.asarray(family.reference_logits(weights, CFG, seq))[31:]
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+    eng = _engine(model)
+    eng.add_request(seq[:32], max_new_tokens=steps)
+    eng.run()
+    stats = eng.stats()["moe"]
+    (prefill,) = [c for c in stats["calls"] if not c[2]]
+    pairs = prefill[3]
+    assert all(p <= block for p in pairs) == fits
+    assert stats["prefill_rows_over_pairs"] == pytest.approx(np.mean(
+        [(block if p <= block else 96) / max(p, 1) for p in pairs]))
+    assert all(len(c) == 5 for c in stats["calls"])
+
+
 def test_absorbed_decode_is_the_models_own_expanded_forward(tiny):
     """The same weights both ways inside the program: a decode row
     through the cache against the plain forward's row."""

@@ -105,9 +105,10 @@ class TestMoEPrimitive:
         np.testing.assert_allclose(np.asarray(out),
                                    np.ones((t, d), np.float32),
                                    rtol=1e-5, atol=1e-5)
-        pairs, touched, largest = (int(v) for v in stats)
+        pairs, touched, largest, rows_run = (int(v) for v in stats)
         assert pairs == t * top_k and largest == t
         assert touched == top_k
+        assert rows_run == t * top_k        # every pair laid out at once
 
     def test_topk_gated_matches_dense_oracle(self):
         """top-4 of 16 with renormalised weights, gated experts without
@@ -362,6 +363,264 @@ class TestSigmoidBiasRouting:
             a, b, 4, True, 1, 1, 1.0, select_bias=None))(x, gate_w)
         assert str(plain) == str(asked)
         assert "logistic" not in str(plain)
+
+
+# what follows the router in a layer that lays out every pair, primitive
+# by primitive, as PR 37 left it but for the fourth counter's
+# ``broadcast_in_dim``: what a decode step of the three sparse cells traces
+def _every_pair_tail(top_k, activation):
+    return ([
+        "ge", "lt", "and", "sub", "jit", "reshape", "jit", "lt", "add",
+        "select_n", "broadcast_in_dim", "gather", "iota", "jit", "slice",
+        "slice", "sub", "jit", "lt", "add", "select_n", "broadcast_in_dim",
+        "gather", "ragged_dot_general", "min"] + activation + [
+        "ragged_dot_general", "broadcast_in_dim", "iota", "lt", "add",
+        "select_n", "broadcast_in_dim", "scatter", "reshape",
+        "broadcast_in_dim"] + top_k * [
+        "slice", "squeeze", "lt", "add", "select_n", "broadcast_in_dim",
+        "gather", "convert_element_type", "broadcast_in_dim", "gather",
+        "broadcast_in_dim", "mul", "broadcast_in_dim", "gather",
+        "broadcast_in_dim", "jit", "add"] + [
+        "convert_element_type", "convert_element_type", "reduce_sum", "gt",
+        "convert_element_type", "reduce_sum", "reduce_max",
+        "broadcast_in_dim", "broadcast_in_dim", "broadcast_in_dim",
+        "broadcast_in_dim", "concatenate", "convert_element_type"])
+
+
+GATED_SILU = ["slice", "jit", "slice", "mul"]
+RELU2 = ["custom_jvp_call", "square"]
+SIGMOID_ROUTE = [
+    "dot_general", "logistic", "broadcast_in_dim", "add", "top_k", "jit",
+    "reduce_sum", "broadcast_in_dim", "add", "div", "mul", "slice",
+    "squeeze", "jit", "reduce_sum", "div", "reduce_sum", "div", "mul",
+    "reduce_sum", "mul"]
+
+
+def _share_oracle(x, gate_w, w1, b1, w2, b2, k, held, gated, activation):
+    """Softmax top-k renormalised over all experts, a token and an
+    expert at a time; only the experts in ``held`` contribute."""
+    logits = x @ gate_w
+    probs = np.exp(logits - logits.max(-1, keepdims=True))
+    probs /= probs.sum(-1, keepdims=True)
+    out = np.zeros_like(x)
+    for t in range(x.shape[0]):
+        top = np.argsort(-probs[t], kind="stable")[:k]
+        for e in top:
+            if e not in held:
+                continue
+            i = e - held[0]
+            a = x[t] @ w1[i] + (0 if b1 is None else b1[i])
+            if gated:
+                f = a.shape[0] // 2
+                a = a[:f] / (1 + np.exp(-a[:f])) * a[f:]
+            else:
+                a = np.square(np.maximum(a, 0))
+            y = a @ w2[i] + (0 if b2 is None else b2[i])
+            out[t] += probs[t, e] / probs[t, top].sum() * y
+    return out
+
+
+class TestPairBlock:
+    """``moe_forward`` over a share of the experts and more pairs than
+    one block: the layer lays out the first block of the sorted pair
+    list where every pair that lands here is in it, every pair where
+    not."""
+
+    E, K, LO, HELD, BLOCK, T = 8, 2, 2, 3, 8, 20       # 40 pairs
+
+    def _chosen(self, live):
+        """[T, 2] experts a token, ``live`` of the 40 pairs on the held
+        experts 2..4; "one": every pair that lands here on expert 3."""
+        away = [(0, 1), (5, 6), (7, 0), (1, 6)]
+        chosen = [list(away[t % 4]) for t in range(self.T)]
+        if live == "one":
+            for t in range(self.T):
+                chosen[t][t % 2] = 3
+            return chosen
+        left = live
+        for t in range(self.T):         # first choices, then second
+            if left:
+                chosen[t][0] = 2 + t % 3
+                left -= 1
+        for t in range(self.T):
+            if left:
+                chosen[t][1] = 2 + (t + 1) % 3
+                left -= 1
+        return chosen
+
+    def _inputs(self, chosen, bias, gated, seed=0):
+        rng = np.random.RandomState(seed)
+        e, d, h = self.E, self.E, 6
+        # the router reads a token's choices off its features
+        x = rng.randn(self.T, d).astype(np.float32) * 0.1
+        for t, (first, second) in enumerate(chosen):
+            x[t, first] += 6.0
+            x[t, second] += 5.0
+        gate_w = np.eye(d, e, dtype=np.float32)
+        w1 = rng.randn(self.HELD, d, 2 * h if gated else h).astype(
+            np.float32) * 0.3
+        w2 = rng.randn(self.HELD, h, d).astype(np.float32) * 0.3
+        b1 = b2 = None
+        if bias:
+            b1 = rng.randn(*w1.shape[::2]).astype(np.float32) * 0.3
+            b2 = rng.randn(self.HELD, d).astype(np.float32) * 0.3
+        return x, gate_w, w1, b1, w2, b2
+
+    @staticmethod
+    def _forward(arrays, block, monkeypatch, **kw):
+        from paddle_tpu.parallel import moe
+
+        monkeypatch.setattr(moe, "_pair_block", lambda *shapes: block)
+        return moe_forward(*(None if a is None else jnp.asarray(a)
+                             for a in arrays), **kw)
+
+    @pytest.mark.parametrize("bias", [False, True], ids=["nobias", "bias"])
+    @pytest.mark.parametrize("gated", [True, False], ids=["silu", "relu2"])
+    @pytest.mark.parametrize("live,fits", [
+        (0, True),          # nothing held is chosen
+        (1, True),          # one row
+        (8, True),          # exactly one block
+        (9, False),         # one row over a block
+        (40, False),        # every pair lands here
+        ("one", False),     # all 20 pairs that land here on one expert
+    ])
+    def test_a_block_matches_the_oracle_and_every_pair_laid_out(
+            self, live, fits, gated, bias, monkeypatch):
+        arrays = self._inputs(self._chosen(live), bias, gated)
+        kw = dict(top_k=self.K, lo=self.LO, gated=gated,
+                  activation="silu" if gated else "relu2")
+        blocked, _, stats = self._forward(arrays, self.BLOCK, monkeypatch,
+                                          **kw)
+        once, _, stats_once = self._forward(arrays, 10 ** 6, monkeypatch,
+                                            **kw)
+        want = _share_oracle(*arrays, self.K,
+                             range(self.LO, self.LO + self.HELD), gated,
+                             kw["activation"])
+        np.testing.assert_allclose(np.asarray(blocked), want, rtol=1e-4,
+                                   atol=1e-5)
+        np.testing.assert_allclose(np.asarray(blocked), np.asarray(once),
+                                   rtol=1e-4, atol=1e-5)
+        pairs = 20 if live == "one" else live
+        assert int(stats[0]) == pairs
+        assert stats[:3].tolist() == stats_once[:3].tolist()
+        # the rows handed to the grouped matmuls: a block where it held
+        # every pair that landed here, else every pair
+        assert int(stats[3]) == (self.BLOCK if fits else self.T * self.K)
+        assert int(stats_once[3]) == self.T * self.K
+
+    def test_a_layer_that_holds_every_expert_is_one_program(
+            self, monkeypatch):
+        """No pair can land elsewhere, so a block saves nothing: the
+        layer lays every pair out whatever their number, with no
+        ``cond``."""
+        rng = np.random.RandomState(1)
+        x = rng.randn(self.T, 8).astype(np.float32)
+        gate_w = rng.randn(8, self.E).astype(np.float32)
+        w1 = rng.randn(self.E, 8, 12).astype(np.float32) * 0.3
+        w2 = rng.randn(self.E, 6, 8).astype(np.float32) * 0.3
+        out, _, stats = self._forward(
+            (x, gate_w, w1, None, w2, None), self.BLOCK, monkeypatch,
+            top_k=self.K, gated=True, activation="silu")
+        np.testing.assert_allclose(
+            np.asarray(out), _share_oracle(x, gate_w, w1, None, w2, None,
+                                           self.K, range(self.E), True,
+                                           "silu"), rtol=1e-4, atol=1e-5)
+        assert stats.tolist()[0::3] == [self.T * self.K, self.T * self.K]
+        text = str(jax.make_jaxpr(lambda *a: moe_forward(
+            *a[:3], None, a[3], None, top_k=self.K, gated=True,
+            activation="silu"))(*map(jnp.asarray, (x, gate_w, w1, w2))))
+        assert "cond[" not in text
+
+    @pytest.mark.parametrize("live,rows", [(6, 8), (13, 40)],
+                             ids=["a-block", "every-pair"])
+    def test_gradient_through_a_share(self, live, rows, monkeypatch):
+        """Reverse mode through the ``cond`` and either of its programs,
+        against ``jax.grad`` of every held expert on every token
+        weighted by the router."""
+        arrays = self._inputs(self._chosen(live), True, True, seed=4)
+        arrays = [jnp.asarray(a) for a in arrays]
+        held = self.HELD
+
+        def shared(x, gate_w, w1, b1, w2, b2):
+            out, aux, stats = moe_forward(
+                x, gate_w, w1, b1, w2, b2, top_k=self.K, lo=self.LO,
+                gated=True, activation="silu")
+            return jnp.sum(jnp.sin(out)) + 0.1 * aux, stats
+
+        def dense(x, gate_w, w1, b1, w2, b2):
+            weights, experts, aux = route(x, gate_w, self.K, True)
+            table = jnp.zeros((self.T, self.E)).at[
+                jnp.arange(self.T)[:, None], experts].set(weights)
+            a = jnp.einsum("td,edf->etf", x, w1) + b1[:, None]
+            f = a.shape[-1] // 2
+            hid = jax.nn.silu(a[..., :f]) * a[..., f:]
+            y = jnp.einsum("etf,efd->etd", hid, w2) + b2[:, None]
+            out = jnp.einsum("te,etd->td",
+                             table[:, self.LO:self.LO + held], y)
+            return jnp.sum(jnp.sin(out)) + 0.1 * aux
+
+        from paddle_tpu.parallel import moe
+
+        monkeypatch.setattr(moe, "_pair_block", lambda *shapes: self.BLOCK)
+        (value, stats), got = jax.value_and_grad(
+            shared, argnums=range(6), has_aux=True)(*arrays)
+        assert stats.tolist()[0::3] == [live, rows]
+        want_value, want = jax.value_and_grad(dense, argnums=range(6))(
+            *arrays)
+        np.testing.assert_allclose(float(value), float(want_value),
+                                   rtol=1e-5)
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-5)
+
+    @pytest.mark.parametrize(
+        "slots,e,held,k,router,activation,kw", [
+            (128, 160, 20, 6, GROUPED_ROUTE, GATED_SILU,
+             dict(gated=True, activation="silu", norm_topk_prob=False,
+                  n_group=8, topk_group=3, routed_scaling_factor=16.0)),
+            (128, 512, 256, 10, PLAIN_ROUTE, GATED_SILU,
+             dict(gated=True, activation="silu", norm_topk_prob=True)),
+            (256, 128, 64, 6, SIGMOID_ROUTE, RELU2,
+             dict(activation="relu2", norm_topk_prob=True,
+                  routed_scaling_factor=2.5,
+                  select_bias=jnp.zeros((128,), jnp.float32))),
+        ], ids=["deepseek-v2", "qwen3-next", "nemotron-h"])
+    def test_a_decode_step_traces_to_the_program_it_was(
+            self, slots, e, held, k, router, activation, kw):
+        """The pairs of a decode step of the three sparse cells (128 x
+        6, 128 x 10, 256 x 6) are at most one block: the layer lays out
+        every pair, the program it was with one more counter behind the
+        three."""
+        from paddle_tpu.parallel.moe import _pair_block
+
+        assert slots * k <= _pair_block(slots * k, held, e)
+        x = jnp.zeros((slots, 64), jnp.bfloat16)
+        gate_w = jnp.zeros((64, e), jnp.bfloat16)
+        w1 = jnp.zeros((held, 64, 64 if kw.get("gated") else 32),
+                       jnp.bfloat16)
+        w2 = jnp.zeros((held, 32, 64), jnp.bfloat16)
+        got = _primitives(lambda *a: moe_forward(
+            *a[:3], None, a[3], None, top_k=k, **kw), x, gate_w, w1, w2)
+        assert got == router + _every_pair_tail(k, activation)
+
+    @pytest.mark.parametrize("held,e,k,blocks", [
+        # rows of a prefill bucket -> pairs a block, by family
+        (20, 160, 6, {1024: 2048, 4096: 4096, 8192: 8192}),
+        (256, 512, 10, {1024: 6912, 2048: 16384, 8192: 54656}),
+        (64, 128, 6, {1024: 4096, 4096: 16384, 8192: 32768}),
+    ], ids=["deepseek-v2", "qwen3-next", "nemotron-h"])
+    def test_a_block_is_a_third_over_the_share_held_in_whole_tiles(
+            self, held, e, k, blocks):
+        """A block comes from shapes alone: a third more than the held
+        experts' share of the pairs, whole 128-row tiles, and
+        ``moe_gmm`` on the row tile it would pick for every pair."""
+        from paddle_tpu.kernels.moe_gmm import row_tile
+        from paddle_tpu.parallel.moe import _pair_block
+
+        for rows, block in blocks.items():
+            assert _pair_block(rows * k, held, e) == block
+            assert block % 128 == 0 and block >= 4 * rows * k * held / (3 * e)
+            assert row_tile(block, held) == row_tile(rows * k, held)
 
 
 class TestMoeGmmKernel:

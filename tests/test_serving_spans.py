@@ -294,6 +294,16 @@ def test_stats_moe_block_counts_what_the_steps_routed(qwen):
     for rows, rows_run, decode, pairs, _ in calls:
         if decode:
             assert 1 <= rows <= rows_run == 4 and max(pairs) <= 16
+    # five fields a program, as the kernel's roofline reader unpacks
+    # them; the fourth counter is reduced apart. This tiny model's
+    # prefills are one block at most, so each layer laid out every pair
+    # of the bucket: rows_run x top-4 over the pairs routed here
+    assert all(len(c) == 5 for c in calls)
+    want = np.mean([rows_run * 4 / max(p, 1)
+                    for _, rows_run, _, pairs, _ in prefills
+                    for p in pairs])
+    assert moe["prefill_rows_over_pairs"] == pytest.approx(want)
+    assert moe["prefill_rows_over_pairs"] >= 1.0
 
 
 def test_stats_state_block_sizes_the_slot_state(qwen):
@@ -310,13 +320,38 @@ def test_moe_ring_stays_at_its_cap():
     em = smetrics.EngineMetrics(max_slots=2)
     em.moe_experts_held = 4
     for i in range(smetrics.MOE_RING + 10):
-        em.on_moe_call(np.asarray([2, 2, 1, 2, 1, 2]), 2, 2, decode=True)
+        em.on_moe_call(np.asarray([2, 2, 1, 8, 2, 1, 2, 8]), 2, 2,
+                       decode=True)
     assert len(em.moe_calls) == smetrics.MOE_RING
     moe = em.to_dict()["moe"]
     assert moe["layers"] == 2 and moe["pairs"] == 2.0
     assert moe["experts_touched"] == 1.5 and moe["load_max"] == 1.5
     # (1 / (2 / 4) + 2 / (2 / 4)) / 2
     assert moe["load_max_over_mean"] == 3.0
+    # the ring holds decode steps alone: nothing to say of a prefill
+    assert moe["prefill_rows_over_pairs"] is None
+
+
+def test_prefill_rows_over_pairs_reads_the_prefills_alone():
+    """The mean, over the ring's prefills and their layers, of the rows
+    handed to the grouped matmuls over the pairs routed here; a decode
+    step's fourth counter is not in it, and ``calls`` keeps five
+    fields."""
+    em = smetrics.EngineMetrics(max_slots=2)
+    em.moe_experts_held = 4
+    em.on_moe_call(np.asarray([100, 4, 40, 128, 50, 3, 30, 256]), 60, 64,
+                   decode=False)
+    em.on_moe_call(np.asarray([0, 0, 0, 0, 64, 4, 20, 64]), 16, 16,
+                   decode=False)
+    assert em.to_dict()["moe"] is None          # no decode step yet
+    em.on_moe_call(np.asarray([2, 2, 1, 8, 2, 1, 2, 8]), 2, 2, decode=True)
+    moe = em.to_dict()["moe"]
+    # a layer that nothing was routed to laid nothing out: 0 / 1
+    assert moe["prefill_rows_over_pairs"] == pytest.approx(
+        (128 / 100 + 256 / 50 + 0.0 + 64 / 64) / 4)
+    assert moe["calls"] == [[60, 64, 0, [100, 50], [4, 3]],
+                            [16, 16, 0, [0, 64], [0, 4]],
+                            [2, 2, 1, [2, 2], [2, 1]]]
 
 
 def test_a_span_without_a_session_touches_no_native_code(monkeypatch):

@@ -309,6 +309,83 @@ class TestMosaicCompile:
             assert found == {"ssm_decode": 1}
 
 
+# deepseekv2-longctx-backlog's expert layer: 20 experts held of 160 at
+# 5120 x 1536 gated, six pairs a token, group-limited
+DSV2_EXPERTS, DSV2_ROUTER, DSV2_HID, DSV2_WIDTH, DSV2_TOPK = (
+    20, 160, 5120, 1536, 6)
+
+
+class TestExpertLayerPrefill:
+    """``moe_forward`` on the 8192 rows of the three sparse cells'
+    largest prefill, compiled for the v5e at each family's widths: two
+    programs under one ``cond``, each with the grouped kernel twice
+    (the jitted body is one kernel name), the first on a block's rows
+    and the second on every pair's; no expert matrix is copied (PR 37's
+    finding); the layer's temporaries are within a tenth of those of the
+    layer that lays every pair out, which is what the parent compiled
+    (the second program is that layer, and sets the peak)."""
+
+    ROWS = 8192
+
+    @pytest.mark.parametrize("held,router,hid,width,top_k,kw", [
+        (DSV2_EXPERTS, DSV2_ROUTER, DSV2_HID, DSV2_WIDTH, DSV2_TOPK,
+         dict(gated=True, activation="silu", norm_topk_prob=False,
+              n_group=8, topk_group=3, routed_scaling_factor=16.0)),
+        (QWEN_EXPERTS, 2 * QWEN_EXPERTS, QWEN_HID, QWEN_WIDTH, QWEN_TOPK,
+         dict(gated=True, activation="silu", norm_topk_prob=True)),
+        (NEMO_EXPERTS, 2 * NEMO_EXPERTS, NEMO_HID, NEMO_WIDTH, NEMO_TOPK,
+         dict(activation="relu2", norm_topk_prob=True,
+              routed_scaling_factor=2.5, select_bias=True)),
+    ], ids=["deepseek-v2", "qwen3-next", "nemotron-h"])
+    def test_a_block_and_every_pair_compile_under_one_cond(
+            self, v5e, monkeypatch, held, router, hid, width, top_k, kw):
+        from paddle_tpu.parallel import moe
+
+        # the kernels' dispatch asks the backend; here it is the CPU
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        kw = dict(kw)
+        wide = width * (2 if kw.get("gated") else 1)
+        if kw.get("select_bias"):
+            kw["select_bias"] = jnp.zeros((router,), F32)
+        pairs = self.ROWS * top_k
+        block = moe._pair_block(pairs, held, router)
+        assert block < pairs
+
+        def compiled_layer():
+            # a new function a compile: a new trace
+            def layer(x, gate_w, w1, w2):
+                return moe.moe_forward(x, gate_w, w1, None, w2, None,
+                                       top_k=top_k, **kw)
+
+            avals = [jax.ShapeDtypeStruct(shape, BF16, sharding=v5e)
+                     for shape in ((self.ROWS, hid), (hid, router),
+                                   (held, hid, wide), (held, width, hid))]
+            return jax.jit(layer).lower(*avals).compile()
+
+        both = compiled_layer()
+        text = both.as_text()
+        assert mosaic_kernels(text) == {"moe_gmm": 4}
+        assert " conditional(" in text
+        matrices = ("bf16[%d,%d,%d]" % (held, hid, wide),
+                    "bf16[%d,%d,%d]" % (held, wide, hid),
+                    "bf16[%d,%d,%d]" % (held, width, hid),
+                    "bf16[%d,%d,%d]" % (held, hid, width))
+        copies = [line.strip()[:160] for line in text.splitlines()
+                  if " copy(" in line and line.lstrip().startswith("%")
+                  and any(m in line.split(" copy(")[0] for m in matrices)]
+        assert copies == []
+        # the kernel runs on a block's rows in one program and on every
+        # pair's in the other
+        for rows in (block, pairs):
+            assert any("custom-call(" in line and "moe_gmm" in line
+                       and "bf16[%d,%d]" % (rows, hid) in line
+                       for line in text.splitlines()), rows
+        monkeypatch.setattr(moe, "_pair_block", lambda *shapes: 10 ** 9)
+        once = compiled_layer()
+        assert mosaic_kernels(once.as_text()) == {"moe_gmm": 2}
+        assert (both.memory_analysis().temp_size_in_bytes
+                <= 1.1 * once.memory_analysis().temp_size_in_bytes)
+
 class TestInterpretNeverOnTPU:
     def test_resolve_interpret(self, monkeypatch):
         assert fa.resolve_interpret(None) is True       # CPU: interpreter
