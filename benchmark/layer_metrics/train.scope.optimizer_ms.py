@@ -1,0 +1,13 @@
+"""Device milliseconds a traced step under the ``optimizer`` scope:
+gradient clipping and the update, whatever of it XLA did not fuse into a
+gradient matmul's epilogue (a fused instruction is booked where its own
+``op_name`` points). Device seconds of the traced window booked to the
+class, over ``len(obs["traced_step_s"])``: of a training step's device
+time, how much is this. From ``scope_time`` (the trace joined to every
+program's HLO ``op_name``s); nothing when the trace or a cross-check
+fails."""
+import scope_time
+
+
+def read(obs):
+    return scope_time.per_step_ms(obs, "optimizer")

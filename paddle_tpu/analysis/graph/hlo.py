@@ -343,3 +343,102 @@ def mosaic_kernels(hlo_text):
             name = lhs.group(1) if lhs else "?"
         out[name] = out.get(name, 0) + 1
     return out
+
+
+# -- model scopes -------------------------------------------------------------
+# The models open ``jax.named_scope("layer_<i>")`` around a decoder layer
+# and one of SCOPE_KINDS inside it; what belongs to no layer is under one
+# of MODEL_SCOPES. XLA carries the scope path as every instruction's
+# ``op_name``, which is how device time gets the model's names
+# (benchmark/scope_time.py keeps its own copy of this grammar).
+
+SCOPE_KINDS = ("attn", "mla", "mlp", "moe", "gdn", "ssm")
+MODEL_SCOPES = ("lm_head", "embed", "optimizer")
+# autodiff, remat and control flow wrap a path element or a whole path,
+# and may stand between the layer and its kind: jvp(layer_0)/attn,
+# transpose(jvp(layer_2/mlp)),
+# transpose(jvp(layer_0))/jvp(layer_0)/checkpoint/rematted_computation/attn
+_LAYER_SCOPE_RE = re.compile(r"(?<![\w.-])layer_(\d+)(?![\w.-])")
+_KIND_SCOPE_RE = re.compile(
+    r"(?<![\w.-])(%s)(?![\w.-])" % "|".join(SCOPE_KINDS))
+_MODEL_SCOPE_RE = re.compile(
+    r"(?<![\w.-])(%s)(?![\w.-])" % "|".join(MODEL_SCOPES))
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_OPCODE_RE = re.compile(r"=.*?[\s)}\]]([a-z][a-z0-9-]*)\(")
+_CALLED_RE = re.compile(
+    r"(?:body|condition|to_apply|calls|true_computation|"
+    r"false_computation)=%?([\w.-]+)|branch_computations=\{([^}]*)\}")
+
+
+def scope_of(op_name):
+    """(layer index or None, kind or None) of an instruction's
+    ``op_name``. With a ``layer_<i>`` anywhere in the string, the kind
+    is the first path element after it that is one of SCOPE_KINDS,
+    inside ``jvp``, ``transpose``, ``checkpoint``, ``while/body`` and
+    ``cond/branch_1_fun`` wrappers too; without a layer and its kind,
+    the first of MODEL_SCOPES that is a path element; else
+    (None, None)."""
+    layer = _LAYER_SCOPE_RE.search(op_name)
+    if layer:
+        kind = _KIND_SCOPE_RE.search(op_name, layer.end())
+        if kind:
+            return int(layer.group(1)), kind.group(1)
+    m = _MODEL_SCOPE_RE.search(op_name)
+    return None, (m.group(1) if m else None)
+
+
+def instruction_scopes(hlo_text):
+    """{instruction short name: (layer, kind)} for every instruction of
+    a compiled HLO module's text, fused computations' too; an
+    instruction without ``op_name`` metadata reads (None, None)."""
+    out = {}
+    for line in hlo_text.splitlines():
+        lhs = _LHS_NAME_RE.match(line)
+        if not lhs or "(" not in line:
+            continue
+        name = _OP_NAME_RE.search(line)
+        out[lhs.group(1)] = scope_of(name.group(1)) if name \
+            else (None, None)
+    return out
+
+
+def executed_instructions(hlo_text):
+    """[(instruction short name, opcode, op_name)] of the instructions
+    the device runs one by one: those of the entry computation and of
+    the computations it reaches through ``while``, ``conditional``,
+    ``call`` and async wrappers, not the inside of a fusion or of a
+    reducer. A line scan like ``mosaic_kernels`` (TPU layouts nest
+    parentheses inside a result type)."""
+    comps, entry, comp = {}, None, None
+    for line in hlo_text.splitlines():
+        stripped = line.strip()
+        if stripped.endswith("{") and "->" in stripped:
+            cm = _COMP_RE.match(stripped)
+            if cm:
+                comp = cm.group("name")
+                comps[comp] = []
+                if cm.group("entry"):
+                    entry = comp
+            continue
+        lhs = _LHS_NAME_RE.match(line)
+        opcode = _OPCODE_RE.search(line)
+        if comp is None or not lhs or not opcode:
+            continue
+        name = _OP_NAME_RE.search(line)
+        called = []
+        if opcode.group(1) in ("while", "conditional", "call",
+                               "async-start"):
+            for one, many in _CALLED_RE.findall(line):
+                called += [one] if one else re.findall(r"[\w.-]+", many)
+        comps[comp].append((lhs.group(1), opcode.group(1),
+                            name.group(1) if name else "", called))
+    out, seen, todo = [], set(), [entry]
+    while todo:
+        comp = todo.pop()
+        if comp in seen or comp not in comps:
+            continue
+        seen.add(comp)
+        for name, opcode, op_name, called in comps[comp]:
+            out.append((name, opcode, op_name))
+            todo += called
+    return out

@@ -418,7 +418,7 @@ class DeepseekV2DecoderLayer(Layer):
             mixed, cache = self.self_attn(
                 rms_norm(x, self.input_layernorm._value, self.eps), cache,
                 position_offset)
-        x = x + mixed
+            x = x + mixed
         with jax.named_scope("moe" if self.sparse else "mlp"):
             x = x + self.mlp(rms_norm(
                 x, self.post_attention_layernorm._value, self.eps))
@@ -454,8 +454,9 @@ class DeepseekV2ForCausalLM(Layer):
 
     def _run(self, input_ids, caches, position_offset, logits_at=None):
         c = self.config
-        x = jnp.take(self.model.embed_tokens._value, _val(input_ids),
-                     axis=0)
+        with jax.named_scope("embed"):
+            x = jnp.take(self.model.embed_tokens._value, _val(input_ids),
+                         axis=0)
         if caches is None:
             caches = [None] * c.num_hidden_layers
         new_caches = []

@@ -383,11 +383,12 @@ class LlamaModel(Layer):
 
     def forward(self, input_ids, caches=None, position_offset=0,
                 logits_at=None):
-        x = self.embed_tokens(input_ids)
-        # dp on batch, sep on sequence when those axes exist
-        spec = self._sep_spec() if caches is None else None
-        if spec is not None:
-            x = mark_sharding(x, *spec)
+        with jax.named_scope("embed"):
+            x = self.embed_tokens(input_ids)
+            # dp on batch, sep on sequence when those axes exist
+            spec = self._sep_spec() if caches is None else None
+            if spec is not None:
+                x = mark_sharding(x, *spec)
         new_caches = []
         use_remat = self.config.recompute and caches is None
         for i, layer in enumerate(self.layers):
@@ -399,7 +400,8 @@ class LlamaModel(Layer):
                     x = _remat_layer(layer, x)
                 else:
                     x = layer(x)
-        x = self.norm(rows_at(x, logits_at))
+        with jax.named_scope("lm_head"):
+            x = self.norm(rows_at(x, logits_at))
         if caches is not None:
             return x, new_caches
         return x
@@ -510,7 +512,8 @@ class LlamaForCausalLM(GenerationMixin, Layer):
         return list(self.llama.layers)
 
     def forward_embed(self, input_ids):
-        return self.llama.embed_tokens(input_ids)
+        with jax.named_scope("embed"):
+            return self.llama.embed_tokens(input_ids)
 
     def forward_head(self, h):
         with jax.named_scope("lm_head"):

@@ -449,13 +449,13 @@ class NemotronHBlock(Layer):
         self.mixer = mixer(config)
 
     def forward(self, x, cache):
-        h = rms_norm(x, self.norm._value, self.eps)
         with jax.named_scope(self.scope):
+            h = rms_norm(x, self.norm._value, self.eps)
             if self.kind == "E":
                 mixed = self.mixer(h)
             else:
                 mixed, cache = self.mixer(h, cache)
-        return x + mixed, cache
+            return x + mixed, cache
 
 
 class NemotronHModel(Layer):
@@ -491,7 +491,8 @@ class NemotronHForCausalLM(Layer):
     def _run(self, input_ids, caches, logits_at=None):
         c = self.config
         ids = _val(input_ids)
-        x = jnp.take(self.backbone.embeddings._value, ids, axis=0)
+        with jax.named_scope("embed"):
+            x = jnp.take(self.backbone.embeddings._value, ids, axis=0)
         if caches is None:
             b, t = ids.shape
             caches = [

@@ -450,14 +450,16 @@ class Qwen3NextDecoderLayer(Layer):
         self.mlp = Qwen3NextSparseMoe(c)
 
     def forward(self, x, cache, position_offset):
-        h = rms_norm_zero_centred(x, self.input_layernorm._value, self.eps)
-        if self.full_attention:
-            with jax.named_scope("attn"):
+        # the norm before a mixer and the residual add after it are
+        # under the mixer's scope: a device trace books time by it
+        with jax.named_scope("attn" if self.full_attention else "gdn"):
+            h = rms_norm_zero_centred(x, self.input_layernorm._value,
+                                      self.eps)
+            if self.full_attention:
                 mixed, cache = self.self_attn(h, cache, position_offset)
-        else:
-            with jax.named_scope("gdn"):
+            else:
                 mixed, cache = self.linear_attn(h, cache)
-        x = x + mixed
+            x = x + mixed
         with jax.named_scope("moe"):
             x = x + self.mlp(rms_norm_zero_centred(
                 x, self.post_attention_layernorm._value, self.eps))
@@ -494,7 +496,8 @@ class Qwen3NextForCausalLM(Layer):
     def _run(self, input_ids, caches, position_offset, logits_at=None):
         c = self.config
         ids = _val(input_ids)
-        x = jnp.take(self.model.embed_tokens._value, ids, axis=0)
+        with jax.named_scope("embed"):
+            x = jnp.take(self.model.embed_tokens._value, ids, axis=0)
         if caches is None:
             b, t = ids.shape
             caches = [

@@ -532,12 +532,13 @@ class CompiledTrainStep:
                         else jax.lax.pmean(loss_l, axes))
                 ef_l = [ef_m[n][0] for n in trainable_names]
                 key = None
-                if stochastic:
-                    key = jax.random.fold_in(
-                        jax.random.fold_in(rng_m, step_m), salt)
-                new_grads, new_ef = _compress.reduce_grads_traced(
-                    grads_l, ef_l, axes, nranks, buckets,
-                    stochastic=stochastic, key=key, mean=not sum_loss)
+                with jax.named_scope("optimizer"):
+                    if stochastic:
+                        key = jax.random.fold_in(
+                            jax.random.fold_in(rng_m, step_m), salt)
+                    new_grads, new_ef = _compress.reduce_grads_traced(
+                        grads_l, ef_l, axes, nranks, buckets,
+                        stochastic=stochastic, key=key, mean=not sum_loss)
                 ef_out = {n: e[None] for n, e in
                           zip(trainable_names, new_ef)}
                 return loss, new_grads, ef_out
@@ -565,16 +566,21 @@ class CompiledTrainStep:
                 else:
                     loss, grads, new_ef = quantized_grads(
                         state_vals, ef_state, step_i, rng_key, batch)
-            if zero_stage >= 2:
-                grads = [jax.lax.with_sharding_constraint(
-                    g, grad_shardings[n])
-                    for n, g in zip(trainable_names, grads)]
-            gdict = dict(zip(trainable_names, grads))
-            pdict = {n: state[n] for n in trainable_names}
-            # lr threaded as an ARGUMENT: an lr captured at trace time
-            # would freeze the scheduler's value into the executable
-            new_p, new_s = opt.functional_apply(pdict, gdict, opt_state,
-                                                lr=lr_i, step=step_i)
+            # what follows the backward pass (gradient clipping and the
+            # update, the quantized sync's error feedback above) is the
+            # optimizer's in a device trace
+            with jax.named_scope("optimizer"):
+                if zero_stage >= 2:
+                    grads = [jax.lax.with_sharding_constraint(
+                        g, grad_shardings[n])
+                        for n, g in zip(trainable_names, grads)]
+                gdict = dict(zip(trainable_names, grads))
+                pdict = {n: state[n] for n in trainable_names}
+                # lr threaded as an ARGUMENT: an lr captured at trace
+                # time would freeze the scheduler's value into the
+                # executable
+                new_p, new_s = opt.functional_apply(
+                    pdict, gdict, opt_state, lr=lr_i, step=step_i)
             out_state = []
             for n in names:
                 out_state.append(new_p[n] if n in new_p else state[n])

@@ -9,6 +9,12 @@ recorder (csrc/trace.cc, the host_event_recorder.h analog); device-side
 tracing is delegated to jax.profiler (Xprof) which captures XLA/TPU
 activity — the CUPTI analog is the TPU runtime's own tracer, reached via
 jax.profiler.start_trace.
+
+A device capture is read by model scope: the models open
+``jax.named_scope("layer_<i>")`` and ``attn`` / ``mla`` / ``mlp`` /
+``moe`` / ``gdn`` / ``ssm`` inside it, ``embed``, ``lm_head`` and
+``optimizer`` beside the layers, and XProf groups device time by that
+``op_name`` (there is no summary-table switch here for it).
 """
 from __future__ import annotations
 
@@ -111,20 +117,6 @@ class ProfilerState(enum.Enum):
 class ProfilerTarget(enum.Enum):
     CPU = 0
     TPU = 1  # reference: GPU
-
-
-class SummaryView(enum.Enum):
-    """reference profiler.SummaryView: which summary tables to print."""
-
-    DeviceView = 0
-    OverView = 1
-    ModelView = 2
-    DistributedView = 3
-    KernelView = 4
-    OperatorView = 5
-    MemoryView = 6
-    MemoryManipulationView = 7
-    UDFView = 8
 
 
 def make_scheduler(*, closed, ready, record, repeat=0, skip_first=0):

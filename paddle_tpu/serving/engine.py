@@ -1257,8 +1257,9 @@ class Engine:
         from ..core.tensor import Tensor
 
         lv = logits._value if isinstance(logits, Tensor) else logits
-        return jnp.argmax(lv[:, 0, :].astype(jnp.float32),
-                          axis=-1).astype(jnp.int32)
+        with jax.named_scope("lm_head"):
+            return jnp.argmax(lv[:, 0, :].astype(jnp.float32),
+                              axis=-1).astype(jnp.int32)
 
     def _with_moe_counters(self, tokens):
         """The step's tokens, and behind them for a model that declares
@@ -1271,9 +1272,10 @@ class Engine:
         what they were."""
         if not self._moe_layers:
             return tokens
-        return jnp.concatenate([
-            tokens.reshape(-1),
-            self.model.moe_step_stats().reshape(-1).astype(jnp.int32)])
+        with jax.named_scope("lm_head"):
+            return jnp.concatenate([
+                tokens.reshape(-1),
+                self.model.moe_step_stats().reshape(-1).astype(jnp.int32)])
 
     def _decode_fn(self, state_vals, pools, tokens, block_tables,
                    seq_lens):
@@ -1289,8 +1291,9 @@ class Engine:
                 logits, views = self.model.generate_step(
                     Tensor(tokens[:, None]), views, seq_lens)
         lv = logits._value if isinstance(logits, Tensor) else logits
-        nxt = jnp.argmax(lv[:, -1, :].astype(jnp.float32),
-                         axis=-1).astype(jnp.int32)
+        with jax.named_scope("lm_head"):
+            nxt = jnp.argmax(lv[:, -1, :].astype(jnp.float32),
+                             axis=-1).astype(jnp.int32)
         return self._with_moe_counters(nxt), [v.pool for v in views]
 
     def _suffix_prefill_fn(self, state_vals, pools, ids, table_row,

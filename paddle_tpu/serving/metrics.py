@@ -39,7 +39,9 @@ here, ``to_dict()`` reduces them when asked):
   itl_ms         {p50, p95} of the time between consecutive output
       tokens of one request, each stamped when its step's tokens reached
       the host (a prefill's token and the same step's first decode token
-      get their own two stamps)
+      get their own two stamps), over the tokens of the recent steps: a
+      step leaves one row of (gap, tokens that had it), so the ring
+      covers the same stretch of time whatever the number of slots
   moe            for a model that declares expert layers
       (``model.moe_layers``): the counters every compiled step hands
       back behind its tokens, a layer at a time (pairs routed to the
@@ -203,16 +205,23 @@ HOST_PHASES = ("schedule", "upload", "dispatch", "readback", "accept")
 # compiles, so the account is over recent rows, not since construction
 STEP_RING = 512
 PREFILL_RING = 512
-GAP_RING = 16384
+# steps, not tokens: a ring of tokens is 256 steps at 64 slots and 64 at
+# 256, and whether a long prefill fell inside it decided the percentile
+GAP_RING = 2048
 MOE_RING = 2048
 # counters an expert layer's step returns (parallel/moe.py moe_forward)
 MOE_COUNTERS = 4
 
 
-def _percentile(ordered, q):
-    """The value at rank ceil(q n) of a sorted sample."""
-    return ordered[min(max(math.ceil(q * len(ordered)) - 1, 0),
-                       len(ordered) - 1)]
+def _weighted_percentile(ordered, q):
+    """The value at rank ceil(q n) of a sample given as sorted
+    (value, count) pairs, n the sum of the counts."""
+    rank = max(math.ceil(q * sum(n for _, n in ordered)), 1)
+    for value, n in ordered:
+        rank -= n
+        if rank <= 0:
+            return value
+    return ordered[-1][0]
 
 
 def counter(name, value):
@@ -376,10 +385,13 @@ class EngineMetrics:
         # phase_s is the open step's seconds by host phase, which the
         # engine adds to at each boundary; a finished step's row is
         # (*phase seconds, prefills run, rows decoded)
-        self.on_step_begin()
         self.steps = collections.deque(maxlen=STEP_RING)
         self.prefills = collections.deque(maxlen=PREFILL_RING)
+        # one row a step: ((gap seconds, tokens that had it), ...); the
+        # open step's gaps are merged in _gaps until it ends
         self.token_gaps = collections.deque(maxlen=GAP_RING)
+        self._gaps = {}
+        self.on_step_begin()
         # one row a compiled step of a model with expert layers:
         # (real rows, rows run, int32 [layers, 4] counters, decode step?)
         self.moe_calls = collections.deque(maxlen=MOE_RING)
@@ -468,9 +480,21 @@ class EngineMetrics:
         self.output_tokens += 1
         _TOKENS.inc()
         if gap_s is not None:
-            self.token_gaps.append(gap_s)
+            self._gaps[gap_s] = self._gaps.get(gap_s, 0) + 1
+
+    def _close_gaps(self):
+        if self._gaps:
+            self.token_gaps.append(tuple(self._gaps.items()))
+            self._gaps = {}
+
+    def gap_rows(self):
+        """[(gap seconds, tokens that had it)] of the recent steps."""
+        # list() copies a deque in one C call, so a reader on another
+        # thread never sees it mid-append
+        return [pair for row in list(self.token_gaps) for pair in row]
 
     def on_step_begin(self):
+        self._close_gaps()      # of a step that failed before its end
         self.phase_s = dict.fromkeys(HOST_PHASES, 0.0)
         self._prefills_before = self.prefill_runs
         self._rows = 0
@@ -480,6 +504,7 @@ class EngineMetrics:
         """Close the open step's row. A step that found nothing to do
         leaves none, so polling an idle engine does not push the
         working steps out of the ring."""
+        self._close_gaps()
         prefills = self.prefill_runs - self._prefills_before
         if self._rows or prefills:
             self.steps.append(
@@ -620,7 +645,7 @@ class EngineMetrics:
         # another thread (fleet/replica.py) never sees one mid-append
         decode_only = [r for r in list(self.steps) if r[-1] and not r[-2]]
         prefills = list(self.prefills)
-        gaps = sorted(self.token_gaps)
+        gaps = sorted(self.gap_rows())
         return {
             "requests_in": self.requests_in,
             "requests_finished": self.requests_finished,
@@ -655,8 +680,8 @@ class EngineMetrics:
             "recent_steps": len(decode_only),
             "prefill_ms": (1e3 * statistics.median(r[0] for r in prefills)
                            if prefills else None),
-            "itl_ms": ({"p50": 1e3 * _percentile(gaps, 0.50),
-                        "p95": 1e3 * _percentile(gaps, 0.95)}
+            "itl_ms": ({"p50": 1e3 * _weighted_percentile(gaps, 0.50),
+                        "p95": 1e3 * _weighted_percentile(gaps, 0.95)}
                        if gaps else None),
             "moe": self._moe_dict(),
         }

@@ -198,11 +198,14 @@ def test_stats_account_for_a_decode_step(llama):
                for _, tokens, bucket in eng.metrics.prefills)
     itl = stats["itl_ms"]
     assert itl["p95"] >= itl["p50"] > 0
-    # every token but a request's first closes a gap
-    assert len(eng.metrics.token_gaps) == stats["output_tokens"] - 20
+    # every token but a request's first closes a gap; a step leaves one
+    # row of (gap, tokens that had it)
+    rows = eng.metrics.gap_rows()
+    assert sum(n for _, n in rows) == stats["output_tokens"] - 20
+    assert len(eng.metrics.token_gaps) <= len(eng.metrics.steps)
     # a prefill's token and the same step's first decode token are two
     # stamps: no gap is zero
-    assert min(eng.metrics.token_gaps) > 0
+    assert min(gap for gap, _ in rows) > 0
 
 
 def test_chunked_prefill_fills_the_same_keys(llama):
@@ -216,7 +219,8 @@ def test_chunked_prefill_fills_the_same_keys(llama):
     assert stats["host_ms"]["readback"] > 0
     assert stats["recent_steps"] > 0
     assert stats["itl_ms"]["p95"] >= stats["itl_ms"]["p50"] > 0
-    assert len(eng.metrics.token_gaps) == stats["output_tokens"] - 8
+    assert sum(n for _, n in eng.metrics.gap_rows()) \
+        == stats["output_tokens"] - 8
     # no prefill of its own: the prompt rides the mixed step
     assert stats["prefill_ms"] is None
 
@@ -236,13 +240,81 @@ def test_rings_stay_at_their_caps(llama):
     # the other two rings, fed as the engine feeds them
     em = smetrics.EngineMetrics(max_slots=1)
     for i in range(smetrics.GAP_RING + 100):
+        em.on_step_begin()
         em.on_output_token(1e-3 * (i % 7 + 1))
+        em.on_step_end()
     for i in range(smetrics.PREFILL_RING + 100):
         em.on_prefill_done(0.01, 8, 8)
     assert len(em.token_gaps) == smetrics.GAP_RING
     assert len(em.prefills) == smetrics.PREFILL_RING
     assert em.output_tokens == smetrics.GAP_RING + 100
     assert em.to_dict()["itl_ms"] == {"p50": 4.0, "p95": 7.0}
+
+
+def _fed(slots, steps, prefill_every, step_s=0.025, prefill_s=0.1):
+    """An EngineMetrics fed as the engine feeds it: ``steps`` decode
+    steps of ``slots`` tokens each, every ``prefill_every``-th one
+    behind a prefill that lengthens every slot's gap."""
+    em = smetrics.EngineMetrics(max_slots=slots)
+    for i in range(steps):
+        em.on_step_begin()
+        gap = step_s + (prefill_s if i % prefill_every == 0 else 0.0)
+        for _ in range(slots):
+            em.on_output_token(gap)
+        em.on_step_end()
+    return em
+
+
+@pytest.mark.parametrize("slots", [64, 256])
+def test_the_gap_ring_is_bounded_by_steps_whatever_the_slots(slots):
+    """One row a step: 256 slots fill the ring no sooner than 64, so
+    ``itl_ms`` covers the same stretch of time at both (a ring of
+    16,384 tokens was 64 steps at 256 slots, and whether one long
+    prefill fell inside it decided the p95)."""
+    em = _fed(slots, smetrics.GAP_RING + 50, prefill_every=12)
+    assert len(em.token_gaps) == smetrics.GAP_RING
+    assert all(row == ((pytest.approx(row[0][0]), slots),)
+               for row in em.token_gaps)
+    assert sum(n for _, n in em.gap_rows()) == slots * smetrics.GAP_RING
+    # a prefill every 12th step is a twelfth of the tokens: inside the
+    # last twentieth, so the p95 is the long gap, the median the short
+    itl = em.to_dict()["itl_ms"]
+    assert itl["p50"] == pytest.approx(25.0)
+    assert itl["p95"] == pytest.approx(125.0)
+
+
+def test_gap_percentiles_are_weighted_by_tokens():
+    """A step's gaps are merged: (gap, tokens that had it). 256 tokens
+    at 20 ms and one step with 9 tokens at 100 ms, 1 at 300 ms."""
+    em = smetrics.EngineMetrics(max_slots=256)
+    em.on_step_begin()
+    for _ in range(256):
+        em.on_output_token(0.020)
+    em.on_step_end()
+    em.on_step_begin()
+    for gap in [0.100] * 9 + [0.300]:
+        em.on_output_token(gap)
+    em.on_step_end()
+    assert list(em.token_gaps) == [((0.020, 256),),
+                                   ((0.100, 9), (0.300, 1))]
+    itl = em.to_dict()["itl_ms"]
+    # 266 tokens: rank 133 is a 20 ms gap, rank ceil(0.95 * 266) = 253
+    # too; unweighted over the three distinct gaps it would read 300
+    assert itl == {"p50": pytest.approx(20.0), "p95": pytest.approx(20.0)}
+    assert smetrics._weighted_percentile(
+        sorted(em.gap_rows()), 0.97) == pytest.approx(0.100)
+    assert smetrics._weighted_percentile(
+        sorted(em.gap_rows()), 1.0) == pytest.approx(0.300)
+
+
+def test_a_failed_steps_gaps_are_kept_by_the_next_step():
+    em = smetrics.EngineMetrics(max_slots=2)
+    em.on_step_begin()
+    em.on_output_token(0.030)       # the step raised before its end
+    em.on_step_begin()
+    em.on_output_token(0.010)
+    em.on_step_end()
+    assert list(em.token_gaps) == [((0.030, 1),), ((0.010, 1),)]
 
 
 def test_a_dense_model_has_no_moe_and_no_state_block(llama):
