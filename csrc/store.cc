@@ -318,9 +318,11 @@ void pt_store_server_stop(int handle) {
     // wake workers blocked in recv on idle-but-connected clients:
     // without this, join below waited for every client to disconnect
     // first (observed deadlock: master.close() with a live peer hung
-    // the process in pthread_join)
+    // the process in pthread_join). Only the read side: a worker that
+    // holds a request still writes the reply it owes (a peer's last
+    // barrier get, its confirmation) before it sees stop and closes.
     std::lock_guard<std::mutex> lk(s->mu);
-    for (int fd : s->client_fds) shutdown(fd, SHUT_RDWR);
+    for (int fd : s->client_fds) shutdown(fd, SHUT_RD);
   }
   for (auto& t : s->workers)
     if (t.joinable()) t.join();

@@ -82,9 +82,9 @@ class Scenario:
         self.sched.store = store
         self.log = self.sched.log
 
-    def client(self, name, timeout_s=None):
+    def client(self, name, timeout_s=None, is_master=False):
         return SimClient(self.store, self.sched, name,
-                         timeout_s=timeout_s)
+                         timeout_s=timeout_s, is_master=is_master)
 
     def task(self, name, fn, crashable=False):
         return self.sched.spawn(name, fn, crashable=crashable)

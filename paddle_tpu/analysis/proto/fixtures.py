@@ -135,7 +135,8 @@ class BarrierFixture(ProtoFixture):
     name = "barrier"
     doc = ("round-based store barrier: name reuse across same-size "
            "and shrunk generations; no hang, no timeout, no round "
-           "leak; arrival retry (lost ack) stays exact")
+           "leak; arrival retry (lost ack) stays exact; the master "
+           "(r0) waits for every peer's confirmation")
     max_schedules = 500
     max_steps = 250
     # gen -> planned participants; gen 3 is the shrunk restart world
@@ -146,7 +147,7 @@ class BarrierFixture(ProtoFixture):
         log = scenario.log
 
         def mk(rank):
-            client = scenario.client("r%d" % rank)
+            client = scenario.client("r%d" % rank, is_master=(rank == 0))
 
             def fn():
                 for gen, world in ((1, 3), (2, 3), (3, 2)):

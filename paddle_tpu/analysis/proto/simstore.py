@@ -85,10 +85,14 @@ class SimClient:
     # seconds here, so a forgotten-timeout wait still unwinds
     DEFAULT_TIMEOUT_S = 300.0
 
-    def __init__(self, store, sched, name, timeout_s=None):
+    def __init__(self, store, sched, name, timeout_s=None,
+                 is_master=False):
         self._store = store
         self._sched = sched
         self._cid = name
+        # the client in the process that hosts the store: the barrier
+        # holds it until every peer has confirmed leaving
+        self.is_master = is_master
         self._seq = 0
         self._timeout_s = (self.DEFAULT_TIMEOUT_S if timeout_s is None
                            else float(timeout_s))

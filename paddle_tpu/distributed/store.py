@@ -340,10 +340,11 @@ class TCPStore:
         round instead of over-counting into an instant or impossible
         release (the pre-resilience bug: ``count``+``go`` keys lived
         forever, so arrival world_size+1 could never reach the ==
-        trigger while ``go`` was already set). State is two counters
-        per (name, world_size) — nothing to clean up, no delete/arrive
-        race. The counter namespace includes ``world_size`` because
-        round arithmetic is only coherent within ONE world size: a
+        trigger while ``go`` was already set). State is one counter
+        per (name, world_size) and fresh keys per round — nothing to
+        clean up, no delete/arrive race. The namespace includes
+        ``world_size`` because round arithmetic is only coherent
+        within ONE world size: a
         SHRUNK restart generation reusing the name (3 ranks arrive,
         then 2 survivors re-barrier) would otherwise fold the old
         world's arrivals into the new world's rounds and strand the
@@ -356,9 +357,7 @@ class TCPStore:
         round_i = (n - 1) // world_size
         # the go key is PER ROUND (a fresh KV key, not a mutated one):
         # waiters ride the server-side blocking get and are released
-        # the instant the last arrival sets it — no poll gap a releaser
-        # could win by closing its store first (the pre-round barrier's
-        # push-release property, kept)
+        # the instant the last arrival sets it
         go_key = "%s/go/%d" % (ns, round_i)
         if n == (round_i + 1) * world_size:
             self.set(go_key, b"1")
@@ -376,6 +375,26 @@ class TCPStore:
                 "barrier %r timed out (%d/%d arrived in round %d)"
                 % (name, max(cur - round_i * world_size, 0),
                    world_size, round_i))
+        # The master hosts the store and may close it once it returns;
+        # a peer that has not read the go key yet (its next poll not
+        # yet sent) would then wait out its whole timeout on a dead
+        # server. So each peer confirms it got past under its arrival
+        # slot, and the master returns only once every slot has.
+        left = "%s/left/%d/" % (ns, round_i)
+        slot = n - 1 - round_i * world_size
+        if not self.is_master:
+            self.set(left + str(slot), b"1")
+            return
+        end = time.monotonic() + (self.timeout_ms / 1000.0
+                                  if timeout_s is None else timeout_s)
+        for other in range(world_size):
+            if other != slot and self.get(
+                    left + str(other),
+                    max(end - time.monotonic(), 0)) is None:
+                raise TimeoutError(
+                    "barrier %r released, but the peer in slot %d never "
+                    "confirmed it got past (round %d)"
+                    % (name, other, round_i))
 
     def close(self):
         with self._mu:

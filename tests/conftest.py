@@ -4,8 +4,23 @@ Tests run on the CPU (``JAX_PLATFORMS=cpu``); mesh/collective tests use
 XLA's CPU multi-device simulation (SURVEY §4: this replaces the
 reference's multi-process localhost NCCL harness). Both are set here,
 before jax is imported. The program itself runs on the chip —
-``python chip_smoke.py`` — never from a test."""
+``python chip_smoke.py`` — never from a test.
+
+Tests that start ranks as processes go through
+``dist_utils.run_ranks``, which waits on one deadline for the whole
+group (at most 180 s) and kills every rank past it. Every test's setup,
+call and teardown each get ``TEST_LIMIT_S`` (300 s): past it the hook
+below dumps every thread's stack and fails that test, and the run goes
+on. It is a constant, not a knob: it only has to sit above every group
+deadline, so that a hung group is reported by run_ranks, which names
+the ranks, and below the suite's own limit, so that one hang costs one
+test and not the run. A test that arms its own ``signal.alarm`` (or
+runs a tool whose ``main()`` does) replaces this one for the rest of
+that phase, which then runs without the limit."""
+import faulthandler
 import os
+import signal
+import threading
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -19,6 +34,33 @@ import pytest  # noqa: E402
 # a pytest plugin may have imported jax before this file ran, in which
 # case the variable above came too late for its config
 jax.config.update("jax_platforms", "cpu")
+
+
+TEST_LIMIT_S = 300
+
+
+def _past_limit(signum, frame):
+    faulthandler.dump_traceback(all_threads=True)
+    pytest.fail("test phase ran past the %s s limit (stacks on stderr)"
+                % TEST_LIMIT_S, pytrace=False)
+
+
+@pytest.hookimpl(hookwrapper=True)
+def _limited(item):
+    if threading.current_thread() is not threading.main_thread():
+        yield
+        return
+    old = signal.signal(signal.SIGALRM, _past_limit)
+    signal.alarm(TEST_LIMIT_S)
+    try:
+        yield
+    finally:
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, old)
+
+
+pytest_runtest_setup = pytest_runtest_call = pytest_runtest_teardown = \
+    _limited
 
 
 @pytest.fixture

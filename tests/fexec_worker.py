@@ -20,8 +20,8 @@ from paddle_tpu.distributed.store import TCPStore  # noqa: E402
 
 
 def main():
-    rank = int(os.environ["FEXEC_RANK"])
-    port = int(os.environ["FEXEC_PORT"])
+    rank = int(os.environ["PADDLE_TRAINER_ID"])
+    port = int(os.environ["PADDLE_MASTER"].rpartition(":")[2])
     n_micro = int(os.environ.get("FEXEC_MICRO", "5"))
     store = TCPStore(port=port, is_master=(rank == 0))
     bus = MessageBus(store, rank)

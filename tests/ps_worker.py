@@ -28,7 +28,7 @@ def main():
     from paddle_tpu.distributed.ps.service import PsClient
 
     host, _, port = os.environ["PADDLE_PSERVER"].partition(":")
-    wid = int(os.environ.get("PS_WORKER_ID", "0"))
+    wid = int(os.environ["PADDLE_TRAINER_ID"])
     steps = int(os.environ.get("PS_NUM_STEPS", "30"))
     dim, n_feat, vocab, batch = 8, 4, 100, 32
 

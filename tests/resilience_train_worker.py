@@ -8,16 +8,18 @@ CompiledTrainStep.run_steps windows, periodic snapshots, an
 ElasticManager heartbeat over the shared TCPStore, and a
 StoreProcessGroup all-reduce after every window.
 
-Rank ``DIE_RANK`` hard-kills itself (os._exit) MID-run_steps of window
-``DIE_AT_WINDOW`` (a timer thread fires while the compiled call is in
-flight). The survivors' next all-reduce times out waiting for the dead
-rank's frame (flight-recorder postmortem and all); the recovery funnel
-confirms the death through the elastic verdict, rebuilds membership
-over the store under a new generation (leader publishes members + the
-min common snapshot step; generation-suffixed barrier), resumes from
-the snapshot, and finishes all TOTAL_STEPS. Rank 0 then re-runs the
-whole schedule uninterrupted on a fresh model and asserts the
-recovered trajectory is IDENTICAL — prints TRAJECTORY_MATCH.
+Rank ``DIE_RANK`` hard-kills itself (os._exit) in window
+``DIE_AT_WINDOW``: building that window's batch never returns, so the
+death lands in that window on every run, however fast the machine
+finishes the windows after it. The survivors' next all-reduce times
+out waiting for the dead rank's frame (flight-recorder postmortem and
+all); the recovery funnel confirms the death through the elastic
+verdict, rebuilds membership over the store under a new generation
+(leader publishes members + the min common snapshot step;
+generation-suffixed barrier), resumes from the snapshot, and finishes
+all TOTAL_STEPS. Rank 0 then re-runs the whole schedule uninterrupted
+on a fresh model and asserts the recovered trajectory is IDENTICAL —
+prints TRAJECTORY_MATCH.
 
 Runs under PT_WATCHDOG=1: the incident must leave diagnostics, not
 stalls — survivors exit 0 with a clean (never-503) healthz.
@@ -30,7 +32,6 @@ from __future__ import annotations
 import json
 import os
 import sys
-import threading
 
 K = 2               # steps per run_steps window
 BATCH = 8           # divisible by any inherited virtual-device mesh
@@ -51,13 +52,13 @@ def make_step():
     return CompiledTrainStep(model, nn.CrossEntropyLoss(), opt)
 
 
-def make_batch_fn(die_window=None, on_window=None):
+def make_batch_fn(die_window=None):
     import numpy as np
 
     def batch_fn(step_i):
         window = (step_i - 1) // K
-        if on_window is not None:
-            on_window(window)
+        if window == die_window:
+            os._exit(17)
         rng = np.random.RandomState(5000 + window)
         x = rng.randn(K, BATCH, FEATS).astype(np.float32)
         y = rng.randint(0, CLASSES, (K, BATCH)).astype(np.int64)
@@ -99,13 +100,6 @@ def main():
 
     step = make_step()
 
-    def kill_mid_window(window):
-        if rank == die_rank and window == die_window:
-            # die while the compiled window is IN FLIGHT: the batch_fn
-            # runs right before dispatch, so a short-fuse timer lands
-            # the kill mid-run_steps
-            threading.Timer(0.05, lambda: os._exit(17)).start()
-
     def post_step(step_i, loss):
         # the all-reduce IS the fast death-detection signal (a dead
         # peer's missing frame raises TimeoutError into the recovery
@@ -129,7 +123,8 @@ def main():
               flush=True)
 
     loop = ResilientTrainLoop(
-        step, make_batch_fn(on_window=kill_mid_window), snap_dir,
+        step, make_batch_fn(die_window if rank == die_rank else None),
+        snap_dir,
         elastic=elastic, snapshot_every=2 * K, keep=3,
         post_step=post_step, on_generation=on_generation,
         store_timeout_s=30, steps_per_call=K)

@@ -16,7 +16,6 @@ from __future__ import annotations
 import json
 import os
 import re
-import subprocess
 import sys
 
 import numpy as np
@@ -420,35 +419,15 @@ class TestCompressed4Proc:
     @pytest.fixture(scope="class")
     def cluster(self):
         sys.path.insert(0, os.path.join(REPO, "tests"))
-        from dist_utils import free_ports
+        from dist_utils import free_port, rank_env, report, run_ranks
 
-        port = free_ports(1)
-        procs = []
-        for rank in range(4):
-            env = dict(os.environ)
-            env.update({
-                "PYTHONPATH": REPO + os.pathsep
-                + env.get("PYTHONPATH", ""),
-                "JAX_PLATFORMS": "cpu",
-                "PADDLE_TRAINER_ID": str(rank),
-                "PADDLE_TRAINERS_NUM": "4",
-                "PADDLE_MASTER": "127.0.0.1:%d" % port,
-            })
-            procs.append(subprocess.Popen(
-                [sys.executable, WORKER], env=env,
-                stdout=subprocess.PIPE, stderr=subprocess.PIPE,
-                text=True))
+        port = free_port()
+        outs = run_ranks([sys.executable, WORKER],
+                         [rank_env(rank, 4, port) for rank in range(4)],
+                         90)
         results = {}
-        for rank, p in enumerate(procs):
-            try:
-                out, err = p.communicate(timeout=300)
-            except subprocess.TimeoutExpired:
-                for q in procs:
-                    q.kill()
-                raise
-            assert p.returncode == 0, (
-                "rank %d rc=%d\nstdout:\n%s\nstderr:\n%s"
-                % (rank, p.returncode, out[-2000:], err[-3000:]))
+        for rank, rc, out, _ in outs:
+            assert rc == 0, report(outs)
             line = [l for l in out.splitlines()
                     if l.startswith("COMPRESS_RESULT ")][0]
             results[rank] = json.loads(line[len("COMPRESS_RESULT "):])

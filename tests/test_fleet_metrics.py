@@ -5,12 +5,11 @@ must equal the single-process AUC over the union of the data."""
 from __future__ import annotations
 
 import os
-import subprocess
 import sys
 
 import numpy as np
 
-from dist_utils import free_port
+from dist_utils import free_port, rank_env, report, run_ranks
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -95,29 +94,11 @@ class TestTwoProcess:
         from paddle_tpu.distributed.fleet import metrics
 
         port = free_port()
-        procs = []
-        for rank in range(2):
-            env = dict(os.environ)
-            env.update({
-                "JAX_PLATFORMS": "cpu",
-                "PADDLE_TRAINER_ID": str(rank),
-                "PADDLE_TRAINERS_NUM": "2",
-                "PADDLE_MASTER": "127.0.0.1:%d" % port,
-            })
-            procs.append(subprocess.Popen(
-                [sys.executable, "-c", WORKER], env=env,
-                stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True))
-        outs = []
-        for p in procs:
-            try:
-                outs.append(p.communicate(timeout=180))
-            except subprocess.TimeoutExpired:
-                for q in procs:
-                    q.kill()
-                raise
-        for p, (o, e) in zip(procs, outs):
-            assert p.returncode == 0, e[-2000:]
-        aucs = [float(o.split("AUC ")[1].split()[0]) for o, _ in outs]
+        res = run_ranks([sys.executable, "-c", WORKER],
+                        [rank_env(rank, 2, port) for rank in range(2)], 60)
+        assert all(rc == 0 for _, rc, _, _ in res), report(res)
+        outs = [out for _, _, out, _ in res]
+        aucs = [float(o.split("AUC ")[1].split()[0]) for o in outs]
         # both ranks see the same GLOBAL metric...
         assert abs(aucs[0] - aucs[1]) < 1e-9
         # ...equal to the single-process metric over the full data
@@ -127,5 +108,5 @@ class TestTwoProcess:
         pos, neg = _bins(scores, labels, n=512)
         assert abs(aucs[0] - metrics.auc(pos, neg)) < 1e-9
         # global accuracy is the pooled fraction
-        accs = [float(o.split("ACC ")[1].split()[0]) for o, _ in outs]
+        accs = [float(o.split("ACC ")[1].split()[0]) for o in outs]
         assert abs(accs[0] - (labels == 1).mean()) < 1e-9

@@ -13,7 +13,7 @@ import time
 
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from dist_utils import REPO, base_env, free_port, report, run_ranks
 
 
 def _run(cmd, **kw):
@@ -59,12 +59,7 @@ class TestLaunchCLI:
         assert r.returncode == 3
 
     def test_multi_node_rendezvous(self, tmp_path):
-        import socket
-
-        s = socket.socket()
-        s.bind(("127.0.0.1", 0))
-        port = s.getsockname()[1]
-        s.close()
+        port = free_port()
         script = tmp_path / "worker.py"
         script.write_text(
             "import json, os, sys\n"
@@ -73,18 +68,14 @@ class TestLaunchCLI:
             "    {k: os.environ.get(k) for k in\n"
             "     ('PADDLE_TRAINER_ID', 'PADDLE_NODE_RANK',\n"
             "      'PADDLE_TRAINERS_NUM', 'PADDLE_MASTER')}))\n")
-        env = dict(os.environ)
-        env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-        launchers = [subprocess.Popen(
-            [sys.executable, "-m", "paddle_tpu.distributed.launch",
-             "--nnodes", "2", "--node_rank", str(n),
-             "--master", "127.0.0.1:%d" % port,
-             "--log_dir", str(tmp_path / ("log%d" % n)),
-             "--job_id", "mn", str(script), str(tmp_path)],
-            env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-            text=True) for n in range(2)]
-        outs = [p.communicate(timeout=120)[0] for p in launchers]
-        assert all(p.returncode == 0 for p in launchers), outs
+        res = run_ranks(
+            lambda n: [sys.executable, "-m", "paddle_tpu.distributed.launch",
+                       "--nnodes", "2", "--node_rank", str(n),
+                       "--master", "127.0.0.1:%d" % port,
+                       "--log_dir", str(tmp_path / ("log%d" % n)),
+                       "--job_id", "mn", str(script), str(tmp_path)],
+            [base_env()] * 2, 60)
+        assert all(rc == 0 for _, rc, _, _ in res), report(res)
         for rank in (0, 1):
             data = json.loads((tmp_path / ("rank%d.json" % rank)).read_text())
             assert data["PADDLE_TRAINER_ID"] == str(rank)
@@ -167,17 +158,9 @@ def _spawn_target(out_dir):
 
 class TestRPC:
     def test_rpc_two_workers(self, tmp_path):
-        # pick a free port for the master store
-        import socket
-
-        s = socket.socket()
-        s.bind(("127.0.0.1", 0))
-        port = s.getsockname()[1]
-        s.close()
         script = tmp_path / "rpc_worker.py"
         script.write_text(
-            "import os, sys\n"
-            "sys.path.insert(0, %r)\n"
+            "import sys\n"
             "from paddle_tpu.distributed import rpc\n"
             "rank = int(sys.argv[1])\n"
             "rpc.init_rpc('worker%%d' %% rank, rank=rank, world_size=2,\n"
@@ -189,20 +172,10 @@ class TestRPC:
             "    assert out == 1024, out\n"
             "    fut = rpc.rpc_async('worker1', divmod, args=(7, 3))\n"
             "    assert fut.result(timeout=30) == (2, 1)\n"
-            "rpc.shutdown()\n" % (REPO, port))
-        env = {**os.environ,
-               "PYTHONPATH": REPO + os.pathsep
-               + os.environ.get("PYTHONPATH", ""),
-               "JAX_PLATFORMS": "cpu"}
-        procs = [subprocess.Popen(
-            [sys.executable, str(script), str(r)], env=env,
-            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
-            for r in range(2)]
-        outs = []
-        for p in procs:
-            out, _ = p.communicate(timeout=120)
-            outs.append(out)
-        assert all(p.returncode == 0 for p in procs), outs
+            "rpc.shutdown()\n" % free_port())
+        res = run_ranks(lambda r: [sys.executable, str(script), str(r)],
+                        [base_env()] * 2, 60)
+        assert all(rc == 0 for _, rc, _, _ in res), report(res)
 
     def test_rpc_errors_propagate(self):
         from paddle_tpu.distributed import rpc

@@ -19,7 +19,6 @@ import glob
 import gzip
 import json
 import os
-import subprocess
 import sys
 import urllib.request
 
@@ -34,7 +33,7 @@ from paddle_tpu.monitor import trace_merge as tm
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 sys.path.insert(0, os.path.join(REPO, "tests"))
-from dist_utils import free_port  # noqa: E402
+from dist_utils import free_port, rank_env, report, run_ranks  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
@@ -545,42 +544,17 @@ class TestDesync8Ranks:
         dump_dir = str(tmp_path_factory.mktemp("fr_dumps"))
         port = free_port()
         worker = os.path.join(REPO, "tests", "monitor_desync_worker.py")
-        procs = []
-        for rank in range(self.WORLD):
-            env = dict(os.environ)
-            env.update({
-                "PYTHONPATH": REPO + os.pathsep +
-                env.get("PYTHONPATH", ""),
-                "JAX_PLATFORMS": "cpu",
-                "PADDLE_TRAINER_ID": str(rank),
-                "PADDLE_TRAINERS_NUM": str(self.WORLD),
-                "PADDLE_MASTER": "127.0.0.1:%d" % port,
-                "PT_MONITOR_DUMP_DIR": dump_dir,
-                "PT_FR_GRACE_S": "6",
-                "DESYNC_RANK": str(self.DESYNC_RANK),
-                "DESYNC_OP_TIMEOUT_S": "5",
-            })
-            procs.append(subprocess.Popen(
-                [sys.executable, worker], env=env,
-                stdout=subprocess.PIPE, stderr=subprocess.PIPE,
-                text=True))
-        outs = []
-        for rank, p in enumerate(procs):
-            try:
-                out, err = p.communicate(timeout=240)
-            except subprocess.TimeoutExpired:
-                for q in procs:
-                    q.kill()
-                raise
-            outs.append((rank, p.returncode, out, err))
-        return dump_dir, outs
+        envs = [rank_env(rank, self.WORLD, port,
+                         PT_MONITOR_DUMP_DIR=dump_dir, PT_FR_GRACE_S=6,
+                         DESYNC_RANK=self.DESYNC_RANK,
+                         DESYNC_OP_TIMEOUT_S=5)
+                for rank in range(self.WORLD)]
+        return dump_dir, run_ranks([sys.executable, worker], envs, 150)
 
     def test_every_rank_detects_and_exits_clean(self, desync_run):
         _, outs = desync_run
         for rank, rc, out, err in outs:
-            assert rc == 0, (
-                "rank %d rc=%d\nstdout:\n%s\nstderr:\n%s"
-                % (rank, rc, out[-2000:], err[-3000:]))
+            assert rc == 0, report(outs)
             assert "DESYNC_CAUGHT" in out, (rank, out)
 
     def test_report_names_diverging_rank_and_seq(self, desync_run):

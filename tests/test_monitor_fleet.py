@@ -42,7 +42,7 @@ from paddle_tpu.monitor import trace_merge as tm
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "tests"))
-from dist_utils import free_port  # noqa: E402
+from dist_utils import free_port, rank_env, report, run_ranks  # noqa: E402
 
 
 def _fleet_threads():
@@ -468,53 +468,23 @@ class TestFleetMultiProc:
         dump_dir = str(tmp_path_factory.mktemp("fleet_dumps"))
         port = free_port()
         worker = os.path.join(REPO, "tests", "fleet_worker.py")
-        procs = []
-        for rank in range(self.WORLD):
-            env = dict(os.environ)
-            env.update({
-                "PYTHONPATH": REPO + os.pathsep +
-                env.get("PYTHONPATH", ""),
-                "JAX_PLATFORMS": "cpu",
-                "PADDLE_TRAINER_ID": str(rank),
-                "PADDLE_TRAINERS_NUM": str(self.WORLD),
-                "PADDLE_MASTER": "127.0.0.1:%d" % port,
-                "PT_MONITOR_DUMP_DIR": dump_dir,
-                "FLAGS_monitor_fleet": "1",
-                "FLAGS_perf_sentinels": "1",
-                "FLAGS_monitor_timeseries": "1",
-                "FLAGS_monitor_trace": "1",
-                "FLAGS_monitor_memory": "1",
-                "FLAGS_monitor_slo": "1",
-                "PT_MEM_CAPACITY_BYTES": str(1 << 30),
-                "STRAGGLER_RANK": str(self.STRAGGLER_RANK),
-                "STRAGGLER_RECOVER_STEP": "25",
-                "NAN_RANK": str(self.NAN_RANK),
-                "NAN_STEP": "30",
-                "STEPS": "45",
-                "FAST_S": "0.08",
-                "SLOW_S": "0.32",
-            })
-            procs.append(subprocess.Popen(
-                [sys.executable, worker], env=env,
-                stdout=subprocess.PIPE, stderr=subprocess.PIPE,
-                text=True))
-        outs = []
-        for rank, p in enumerate(procs):
-            try:
-                out, err = p.communicate(timeout=300)
-            except subprocess.TimeoutExpired:
-                for q in procs:
-                    q.kill()
-                raise
-            outs.append((rank, p.returncode, out, err))
-        return dump_dir, outs
+        envs = [rank_env(
+            rank, self.WORLD, port,
+            PT_MONITOR_DUMP_DIR=dump_dir,
+            FLAGS_monitor_fleet=1, FLAGS_perf_sentinels=1,
+            FLAGS_monitor_timeseries=1, FLAGS_monitor_trace=1,
+            FLAGS_monitor_memory=1, FLAGS_monitor_slo=1,
+            PT_MEM_CAPACITY_BYTES=1 << 30,
+            STRAGGLER_RANK=self.STRAGGLER_RANK, STRAGGLER_RECOVER_STEP=25,
+            NAN_RANK=self.NAN_RANK, NAN_STEP=30, STEPS=45,
+            FAST_S=0.08, SLOW_S=0.32)
+            for rank in range(self.WORLD)]
+        return dump_dir, run_ranks([sys.executable, worker], envs, 150)
 
     def test_all_ranks_exit_clean(self, fleet_run):
         _, outs = fleet_run
         for rank, rc, out, err in outs:
-            assert rc == 0, (
-                "rank %d rc=%s\nstdout:\n%s\nstderr:\n%s"
-                % (rank, rc, out[-2000:], err[-3000:]))
+            assert rc == 0, report(outs)
             assert "FLEET_OK rank=%d" % rank in out, (rank, out)
 
     def test_straggler_named_while_run_progresses(self, fleet_run):

@@ -17,23 +17,10 @@ import sys
 
 import numpy as np
 
-from dist_utils import free_ports
+from dist_utils import (REPO, base_env, free_ports, rank_env, report,
+                        run_ranks)
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WORKER = os.path.join(REPO, "tests", "multihost_worker.py")
-
-
-def _clean_env(local_devices):
-    """CPU-only env without the launcher variables of an enclosing
-    job."""
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("PYTHONPATH", "PADDLE_MASTER",
-                        "PADDLE_TRAINERS_NUM", "PADDLE_TRAINER_ID",
-                        "PADDLE_NNODES", "PADDLE_NODE_RANK")}
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=%d"
-                        % local_devices)
-    return env
 
 
 def _parse_losses(stdout):
@@ -44,10 +31,12 @@ def _parse_losses(stdout):
 
 
 def _golden_single_process(steps):
-    env = _clean_env(8)
+    """The same steps in one process over 8 devices, outside any
+    enclosing job's launcher variables."""
+    env = base_env(XLA_FLAGS="--xla_force_host_platform_device_count=8")
     proc = subprocess.run([sys.executable, WORKER, str(steps)], cwd=REPO,
                           env=env, capture_output=True, text=True,
-                          timeout=600)
+                          timeout=120)
     assert proc.returncode == 0, proc.stderr[-3000:]
     losses = _parse_losses(proc.stdout)
     assert len(losses) == steps, proc.stdout
@@ -65,33 +54,17 @@ def test_two_processes_one_global_mesh():
     # reserve the store port AND the +1 the JAX coordinator derives from
     # it, plus the +10/+11 endpoint slots announced to the store
     port = free_ports(12)
-    procs = []
-    for rank in range(2):
-        env = _clean_env(4)
-        env.update({
-            "PADDLE_NNODES": "2",
-            "PADDLE_NODE_RANK": str(rank),
-            "PADDLE_TRAINERS_NUM": "2",
-            "PADDLE_TRAINER_ID": str(rank),
-            "PADDLE_MASTER": "127.0.0.1:%d" % port,
-            "PADDLE_CURRENT_ENDPOINT": "127.0.0.1:%d" % (port + 10 + rank),
-        })
-        procs.append(subprocess.Popen(
-            [sys.executable, WORKER, str(steps)], cwd=REPO, env=env,
-            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True))
-    outs = []
-    for p in procs:
-        try:
-            out, err = p.communicate(timeout=600)
-        except subprocess.TimeoutExpired:
-            for q in procs:
-                q.kill()
-            raise
-        outs.append((p.returncode, out, err))
-    for rc, out, err in outs:
-        assert rc == 0, err[-3000:]
-    losses = [_parse_losses(out) for _, out, _ in outs]
-    assert len(losses[0]) == steps and len(losses[1]) == steps, outs
+    outs = run_ranks(
+        [sys.executable, WORKER, str(steps)],
+        [rank_env(rank, 2, port, PADDLE_NNODES=2, PADDLE_NODE_RANK=rank,
+                  PADDLE_CURRENT_ENDPOINT="127.0.0.1:%d"
+                  % (port + 10 + rank),
+                  XLA_FLAGS="--xla_force_host_platform_device_count=4")
+         for rank in range(2)], 120, cwd=REPO)
+    assert all(rc == 0 for _, rc, _, _ in outs), report(outs)
+    losses = [_parse_losses(out) for _, _, out, _ in outs]
+    assert len(losses[0]) == steps and len(losses[1]) == steps, \
+        report(outs)
     # both processes observe the same (replicated) loss...
     np.testing.assert_allclose(losses[0], losses[1], rtol=1e-5)
     # ...and the 2-process global mesh reproduces the single-process run

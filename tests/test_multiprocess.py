@@ -8,8 +8,6 @@ from __future__ import annotations
 
 import json
 import os
-import socket
-import subprocess
 import sys
 
 import numpy as np
@@ -19,38 +17,18 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WORKER = os.path.join(REPO, "tests", "dist_worker.py")
 
 
-from dist_utils import free_port as _free_port  # shared harness
+from dist_utils import free_port, rank_env, report, run_ranks  # noqa: E402
 
 
-def _run_cluster(nranks, timeout=240):
-    port = _free_port()
-    procs = []
-    for rank in range(nranks):
-        env = dict(os.environ)
-        env.update({
-            "PYTHONPATH": REPO + os.pathsep + env.get("PYTHONPATH", ""),
-            "JAX_PLATFORMS": "cpu",
-            "PADDLE_TRAINER_ID": str(rank),
-            "PADDLE_TRAINERS_NUM": str(nranks),
-            "PADDLE_MASTER": "127.0.0.1:%d" % port,
-            "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
-        })
-        procs.append(subprocess.Popen(
-            [sys.executable, WORKER], env=env, stdout=subprocess.PIPE,
-            stderr=subprocess.PIPE, text=True))
-    outs = []
-    for rank, p in enumerate(procs):
-        try:
-            out, err = p.communicate(timeout=timeout)
-        except subprocess.TimeoutExpired:
-            for q in procs:
-                q.kill()
-            raise
-        assert p.returncode == 0, (
-            "rank %d failed (rc=%d):\nstdout:\n%s\nstderr:\n%s"
-            % (rank, p.returncode, out[-2000:], err[-3000:]))
-        outs.append(out)
-    return outs
+def _run_cluster(nranks):
+    port = free_port()
+    res = run_ranks(
+        [sys.executable, WORKER],
+        [rank_env(rank, nranks, port,
+                  XLA_FLAGS="--xla_force_host_platform_device_count=2")
+         for rank in range(nranks)], 90)
+    assert all(rc == 0 for _, rc, _, _ in res), report(res)
+    return [out for _, _, out, _ in res]
 
 
 class TestMultiProcess2Ranks:

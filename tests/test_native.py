@@ -5,6 +5,7 @@ import json
 import os
 import pickle
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -134,6 +135,48 @@ class TestTCPStore:
             master.barrier("b0", 3, timeout_s=10)
             [t.join() for t in ts]
             assert not errs
+
+    def test_barrier_master_waits_for_a_slow_peer(self):
+        # the master closes its store right after the barrier; the peer
+        # reads the go key only 0.5 s later. Without the peer's
+        # confirmation it would find no server and time out.
+        from paddle_tpu.distributed.store import TCPStore
+
+        master = TCPStore(is_master=True)
+        errs = []
+
+        def peer():
+            try:
+                with TCPStore(port=master.port) as c:
+                    get = c.get
+
+                    def slow_get(key, timeout_s=None):
+                        if "/go/" in key:
+                            time.sleep(0.5)
+                        return get(key, timeout_s)
+
+                    c.get = slow_get
+                    c.barrier("slow", 2, timeout_s=5)
+            except Exception as e:
+                errs.append(e)
+
+        t = threading.Thread(target=peer)
+        t.start()
+        master.barrier("slow", 2, timeout_s=5)
+        master.close()
+        t.join(timeout=30)
+        assert not t.is_alive() and not errs, errs
+
+    def test_close_wakes_an_idle_peer_connection(self):
+        from paddle_tpu.distributed.store import TCPStore
+
+        master = TCPStore(is_master=True)
+        with TCPStore(port=master.port) as c:
+            c.set("k", b"v")
+            t = threading.Thread(target=master.close)
+            t.start()
+            t.join(timeout=10)
+            assert not t.is_alive()
 
     def test_large_value(self):
         from paddle_tpu.distributed.store import TCPStore

@@ -8,7 +8,6 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 
 import numpy as np
@@ -21,7 +20,7 @@ from paddle_tpu.distributed.ps import (
     TheOnePSRuntime,
 )
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from dist_utils import REPO, rank_env, report, run_ranks
 
 
 @pytest.fixture()
@@ -438,35 +437,18 @@ class TestWideDeepE2E:
         boot = PsClient(port=srv.port)
         boot.create_sparse_table(0, 8, optimizer="adam", lr=0.02)
         boot.create_sparse_table(1, 1, optimizer="sgd", lr=0.1)
-        procs = []
-        try:
-            for wid in range(2):
-                env = dict(os.environ)
-                env.update({
-                    "PYTHONPATH": REPO + os.pathsep
-                    + env.get("PYTHONPATH", ""),
-                    "JAX_PLATFORMS": "cpu",
-                    "PADDLE_PSERVER": "127.0.0.1:%d" % srv.port,
-                    "PS_WORKER_ID": str(wid),
-                    "PS_NUM_STEPS": "40",
-                })
-                procs.append(subprocess.Popen(
-                    [sys.executable, os.path.join(REPO, "tests",
-                                                  "ps_worker.py")],
-                    env=env, stdout=subprocess.PIPE,
-                    stderr=subprocess.PIPE, text=True))
-            results = {}
-            for p in procs:
-                out, err = p.communicate(timeout=300)
-                assert p.returncode == 0, (out[-1500:], err[-2500:])
-                line = [l for l in out.splitlines()
-                        if l.startswith("PS_RESULT ")][0]
-                rec = json.loads(line[len("PS_RESULT "):])
-                results[rec["worker"]] = rec["losses"]
-        finally:
-            for p in procs:
-                if p.poll() is None:
-                    p.kill()
+        res = run_ranks(
+            [sys.executable, os.path.join(REPO, "tests", "ps_worker.py")],
+            [rank_env(wid, 2, srv.port,
+                      PADDLE_PSERVER="127.0.0.1:%d" % srv.port,
+                      PS_NUM_STEPS=40) for wid in range(2)], 60)
+        assert all(rc == 0 for _, rc, _, _ in res), report(res)
+        results = {}
+        for _, _, out, _ in res:
+            line = [l for l in out.splitlines()
+                    if l.startswith("PS_RESULT ")][0]
+            rec = json.loads(line[len("PS_RESULT "):])
+            results[rec["worker"]] = rec["losses"]
         for wid, losses in results.items():
             first = np.mean(losses[:5])
             last = np.mean(losses[-5:])

@@ -16,7 +16,7 @@ Covers the ISSUE-7 acceptance surface:
   goodput > 0, and drain() completes in-flight work while rejecting
   admissions;
 - ResilientTrainLoop snapshots async, restores bit-identically, and
-  the multi-process chaos run (rank killed mid-run_steps) recovers via
+  the multi-process chaos run (rank killed in its third window) recovers via
   ElasticManager to a pinned loss trajectory with rc=0 and a clean
   watchdog;
 - PT_WATCHDOG_ACTION=recover escalates a stall into the registered
@@ -44,7 +44,9 @@ from paddle_tpu.resilience.train import ResilientTrainLoop, list_snapshots
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "tests"))
-from dist_utils import free_port  # noqa: E402
+from dist_utils import free_port, rank_env, report, run_ranks  # noqa: E402
+
+WORKER = os.path.join(REPO, "tests", "resilience_train_worker.py")
 
 
 @pytest.fixture(autouse=True)
@@ -678,53 +680,36 @@ class TestTrainChaosMultiProc:
         snap_dir = str(tmp_path_factory.mktemp("res_snaps"))
         dump_dir = str(tmp_path_factory.mktemp("res_dumps"))
         port = free_port()
-        worker = os.path.join(REPO, "tests",
-                              "resilience_train_worker.py")
-        procs = []
-        for rank in range(self.WORLD):
-            env = dict(os.environ)
-            env.update({
-                "PYTHONPATH": REPO + os.pathsep +
-                env.get("PYTHONPATH", ""),
-                "JAX_PLATFORMS": "cpu",
-                "PADDLE_TRAINER_ID": str(rank),
-                "PADDLE_TRAINERS_NUM": str(self.WORLD),
-                "PADDLE_MASTER": "127.0.0.1:%d" % port,
-                "PADDLE_ELASTIC_FAULT_TOLERANC_LEVEL": "1",
-                "PT_MONITOR_DUMP_DIR": dump_dir,
-                "PT_FR_GRACE_S": "2",
-                "SNAP_DIR": snap_dir,
-                "DIE_RANK": str(self.DIE_RANK),
-                "DIE_AT_WINDOW": "3",
-                "TOTAL_STEPS": "12",
-                # clean-watchdog criterion: enabled, generous threshold
-                "PT_WATCHDOG": "1",
-                "PT_WATCHDOG_STALL_S": "90",
-            })
-            procs.append(subprocess.Popen(
-                [sys.executable, worker], env=env,
-                stdout=subprocess.PIPE, stderr=subprocess.PIPE,
-                text=True))
-        outs = []
-        for rank, p in enumerate(procs):
-            try:
-                out, err = p.communicate(timeout=300)
-            except subprocess.TimeoutExpired:
-                for q in procs:
-                    q.kill()
-                raise
-            outs.append((rank, p.returncode, out, err))
-        return dump_dir, outs
+        envs = [rank_env(
+            rank, self.WORLD, port,
+            PADDLE_ELASTIC_FAULT_TOLERANC_LEVEL=1,
+            PT_MONITOR_DUMP_DIR=dump_dir, PT_FR_GRACE_S=2,
+            SNAP_DIR=snap_dir, DIE_RANK=self.DIE_RANK, DIE_AT_WINDOW=3,
+            TOTAL_STEPS=12,
+            # clean-watchdog criterion: enabled, generous threshold
+            PT_WATCHDOG=1, PT_WATCHDOG_STALL_S=90)
+            for rank in range(self.WORLD)]
+        return dump_dir, run_ranks([sys.executable, WORKER], envs, 120)
+
+    def test_dying_rank_never_leaves_its_window(self):
+        # the death is certain: window 3's batch (steps 7-8) never
+        # returns, while the windows before it build their batches
+        r = subprocess.run(
+            [sys.executable, "-c",
+             "import resilience_train_worker as w\n"
+             "b = w.make_batch_fn(die_window=3)\n"
+             "b(5); print('WINDOW2', flush=True); b(7); print('RETURNED')"],
+            cwd=os.path.dirname(WORKER), capture_output=True, text=True,
+            timeout=60)
+        assert (r.returncode, r.stdout) == (17, "WINDOW2\n"), r
 
     def test_survivors_recover_and_exit_clean(self, chaos_run):
         _, outs = chaos_run
         for rank, rc, out, err in outs:
+            assert rc == (17 if rank == self.DIE_RANK else 0), \
+                report(outs)
             if rank == self.DIE_RANK:
-                assert rc == 17, (rc, out[-500:], err[-1000:])
                 continue
-            assert rc == 0, (
-                "rank %d rc=%d\nstdout:\n%s\nstderr:\n%s"
-                % (rank, rc, out[-2000:], err[-4000:]))
             assert "CHAOS_OK" in out, (rank, out)
             assert "rank_death" in out, (rank, out)
 

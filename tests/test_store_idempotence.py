@@ -23,7 +23,6 @@ Layers pinned here:
 import ctypes
 import json
 import os
-import subprocess
 import sys
 import textwrap
 
@@ -35,7 +34,7 @@ from paddle_tpu.resilience import faultinject as fi
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO_ROOT, "tests"))
-from dist_utils import free_port  # noqa: E402
+from dist_utils import free_port, rank_env, report, run_ranks  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
@@ -233,9 +232,9 @@ _WORKER = textwrap.dedent("""
     from paddle_tpu.distributed.store import TCPStore
     from paddle_tpu.resilience import faultinject as fi
 
-    rank = int(sys.argv[1])
-    port = int(sys.argv[2])
-    store = TCPStore("127.0.0.1", port, is_master=False)
+    rank = int(os.environ["PADDLE_TRAINER_ID"])
+    host, _, port = os.environ["PADDLE_MASTER"].partition(":")
+    store = TCPStore(host, int(port), is_master=False)
     # every rank loses the ack of its FIRST add: the claim itself
     fi.enable("store.add:lost_ack@1", seed=rank)
     claim = store.add("leader", 1)
@@ -260,25 +259,16 @@ class TestMultiProcess:
         master = TCPStore(port=port, is_master=True)
         worker = tmp_path / "idem_worker.py"
         worker.write_text(_WORKER % {"root": REPO_ROOT})
-        procs = [
-            subprocess.Popen(
-                [sys.executable, str(worker), str(rank),
-                 str(master.port)],
-                stdout=subprocess.PIPE, stderr=subprocess.PIPE,
-                text=True)
-            for rank in range(3)]
-        outs = []
         try:
-            for p in procs:
-                stdout, stderr = p.communicate(timeout=60)
-                assert p.returncode == 0, stderr
-                outs.append(json.loads(stdout.strip().splitlines()[-1]))
-            claims = sorted(o["claim"] for o in outs)
+            res = run_ranks(
+                [sys.executable, str(worker)],
+                [rank_env(rank, 3, master.port) for rank in range(3)],
+                60)
+            assert all(rc == 0 for _, rc, _, _ in res), report(res)
+            claims = sorted(json.loads(out.strip().splitlines()[-1])
+                            ["claim"] for _, _, out, _ in res)
             assert claims == [1, 2, 3], claims
             assert master.counter_get("leader") == 3
             assert master.counter_get("ctr") == 12
         finally:
-            for p in procs:
-                if p.poll() is None:
-                    p.kill()
             master.close()

@@ -230,34 +230,16 @@ class TestCrossRankMessageBus:
         pipeline split across two OS processes; interceptor messages
         (ready/ack) cross ranks over the TCP-store bus and the sink's
         completion releases both carriers."""
-        import os
-        import subprocess
         import sys
 
-        from dist_utils import free_port
+        from dist_utils import REPO, free_port, rank_env, report, run_ranks
 
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         port = free_port()
-        procs = []
-        for rank in range(2):
-            env = dict(os.environ)
-            env.update({"FEXEC_RANK": str(rank), "FEXEC_PORT": str(port),
-                        "FEXEC_MICRO": "5", "JAX_PLATFORMS": "cpu"})
-            procs.append(subprocess.Popen(
-                [sys.executable,
-                 os.path.join(repo, "tests", "fexec_worker.py")],
-                env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
-                text=True))
-        outs = []
-        for p in procs:
-            try:
-                outs.append(p.communicate(timeout=120))
-            except subprocess.TimeoutExpired:
-                for q in procs:
-                    q.kill()
-                raise
-        for p, (o, e) in zip(procs, outs):
-            assert p.returncode == 0, e[-2000:]
-        assert "RANK0_DONE" in outs[0][0]
+        res = run_ranks(
+            [sys.executable, os.path.join(REPO, "tests", "fexec_worker.py")],
+            [rank_env(rank, 2, port, FEXEC_MICRO=5) for rank in range(2)],
+            60)
+        assert all(rc == 0 for _, rc, _, _ in res), report(res)
+        assert "RANK0_DONE" in res[0][2]
         # source i*10 -> stageA +1 -> stageB *2, in microbatch order
-        assert "RESULTS [2, 22, 42, 62, 82]" in outs[1][0], outs[1][0]
+        assert "RESULTS [2, 22, 42, 62, 82]" in res[1][2], report(res)

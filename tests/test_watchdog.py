@@ -22,7 +22,6 @@ from __future__ import annotations
 import glob
 import json
 import os
-import subprocess
 import sys
 import threading
 import time
@@ -38,7 +37,7 @@ from paddle_tpu.monitor import watchdog as wd
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 sys.path.insert(0, os.path.join(REPO, "tests"))
-from dist_utils import free_port  # noqa: E402
+from dist_utils import free_port, rank_env, report, run_ranks  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
@@ -394,43 +393,17 @@ class TestForcedStallMultiProc:
         dump_dir = str(tmp_path_factory.mktemp("wd_dumps"))
         port = free_port()
         worker = os.path.join(REPO, "tests", "watchdog_stall_worker.py")
-        procs = []
-        for rank in range(self.WORLD):
-            env = dict(os.environ)
-            env.update({
-                "PYTHONPATH": REPO + os.pathsep +
-                env.get("PYTHONPATH", ""),
-                "JAX_PLATFORMS": "cpu",
-                "PADDLE_TRAINER_ID": str(rank),
-                "PADDLE_TRAINERS_NUM": str(self.WORLD),
-                "PADDLE_MASTER": "127.0.0.1:%d" % port,
-                "PT_MONITOR_DUMP_DIR": dump_dir,
-                "STALL_RANK": str(self.STALL_RANK),
-                "STALL_SLEEP_S": "12",
-                "WD_STALL_S": "1.5",
-                "WD_GRACE_S": "4",
-            })
-            procs.append(subprocess.Popen(
-                [sys.executable, worker], env=env,
-                stdout=subprocess.PIPE, stderr=subprocess.PIPE,
-                text=True))
-        outs = []
-        for rank, p in enumerate(procs):
-            try:
-                out, err = p.communicate(timeout=240)
-            except subprocess.TimeoutExpired:
-                for q in procs:
-                    q.kill()
-                raise
-            outs.append((rank, p.returncode, out, err))
-        return dump_dir, outs
+        envs = [rank_env(rank, self.WORLD, port,
+                         PT_MONITOR_DUMP_DIR=dump_dir,
+                         STALL_RANK=self.STALL_RANK, STALL_SLEEP_S=12,
+                         WD_STALL_S=1.5, WD_GRACE_S=4)
+                for rank in range(self.WORLD)]
+        return dump_dir, run_ranks([sys.executable, worker], envs, 150)
 
     def test_all_ranks_recover_and_exit_clean(self, stall_run):
         _, outs = stall_run
         for rank, rc, out, err in outs:
-            assert rc == 0, (
-                "rank %d rc=%d\nstdout:\n%s\nstderr:\n%s"
-                % (rank, rc, out[-2000:], err[-3000:]))
+            assert rc == 0, report(outs)
             assert "STALL_RUN_OK" in out, (rank, out)
 
     def test_postmortem_names_stalled_rank_with_stack(self, stall_run):
