@@ -53,7 +53,7 @@ from ..nn import initializer as I
 from ..nn.layer import Layer
 from ..nn.functional.norm import rms_norm as _rms_norm
 from ..nn.layers.container import LayerList
-from ..parallel.moe import MoELayer, moe_forward
+from ..parallel.moe import MoELayer, moe_forward, swiglu_clamped
 from .generation import rows_at
 
 _F32 = jnp.float32
@@ -76,12 +76,14 @@ class DeepseekV2Config:
                  norm_topk_prob=False, first_k_dense_replace=1,
                  rope_theta=10000.0, rope_scaling=None, rms_norm_eps=1e-6,
                  experts_held=None, max_position_embeddings=163840,
-                 dtype="float32"):
+                 gated_attention=False, dtype="float32"):
         """``n_routed_experts`` is the router's published width;
         ``experts_held`` (a range, default all) the experts that live
         here. ``vocab_size`` is the number of vocabulary rows held here
         (ids, logits and argmax are over them). ``rope_scaling`` is the
-        published YaRN dict (default: DeepSeek-V2's)."""
+        published YaRN dict (default: DeepSeek-V2's).
+        ``gated_attention`` gives the latent attention a per-channel
+        output gate (``DeepseekV2Attention``); DeepSeek-V2 has none."""
         self.vocab_size = vocab_size
         self.hidden_size = hidden_size
         self.intermediate_size = intermediate_size
@@ -108,6 +110,7 @@ class DeepseekV2Config:
         self.experts_held = (range(n_routed_experts) if experts_held is None
                              else experts_held)
         self.max_position_embeddings = max_position_embeddings
+        self.gated_attention = gated_attention
         self.dtype = dtype
 
     @classmethod
@@ -131,12 +134,15 @@ def _val(x):
     return x._value if isinstance(x, Tensor) else x
 
 
-def swiglu(x, gate_up, down):
+def swiglu(x, gate_up, down, limit=None):
     """down(silu(gate) * up) with gate and up side by side in one
-    matrix."""
+    matrix; with ``limit`` both halves clamped first
+    (parallel/moe.py ``swiglu_clamped``)."""
     f = down.shape[0]
     gu = jnp.matmul(x, gate_up)
-    return jnp.matmul(jax.nn.silu(gu[..., :f]) * gu[..., f:], down)
+    if limit is None:
+        return jnp.matmul(jax.nn.silu(gu[..., :f]) * gu[..., f:], down)
+    return jnp.matmul(swiglu_clamped(gu[..., :f], gu[..., f:], limit), down)
 
 
 # -- YaRN rotary ---------------------------------------------------------------
@@ -199,7 +205,11 @@ def _head_groups(heads, rows):
     return heads
 
 class DeepseekV2Attention(Layer):
-    """Multi-head latent attention; see the module docstring."""
+    """Multi-head latent attention; see the module docstring. With the
+    config's ``gated_attention`` every head's value output is multiplied
+    by ``sigmoid(x W_g)`` (``gate_proj`` [hidden, heads x v_head_dim],
+    per channel) before ``o_proj``, in both forms: after the value
+    up-projection in the absorbed one."""
 
     def __init__(self, config):
         super().__init__()
@@ -240,9 +250,18 @@ class DeepseekV2Attention(Layer):
         self.o_proj = self.create_parameter(
             [self.heads * self.v_dim, c.hidden_size], dtype=dt,
             default_initializer=xavier)
+        self.gate_proj = None
+        if c.gated_attention:
+            self.gate_proj = self.create_parameter(
+                [c.hidden_size, self.heads * self.v_dim], dtype=dt,
+                default_initializer=xavier)
 
     def forward(self, x, cache=None, position_offset=0):
         b, t, _ = x.shape
+        gate = None
+        if self.gate_proj is not None:
+            gate = jax.nn.sigmoid(jnp.matmul(
+                x, self.gate_proj._value).astype(_F32))
         c_q = rms_norm(jnp.matmul(x, self.q_a_proj._value),
                        self.q_a_layernorm._value, self.eps)
         kv_a = jnp.matmul(x, self.kv_a_proj_with_mqa._value)
@@ -255,7 +274,7 @@ class DeepseekV2Attention(Layer):
             cache = cache.update(
                 jnp.concatenate([c_kv, k_pe[:, :, 0]], axis=-1))
         if cache is not None and cache.absorbed:
-            return self._absorbed(c_q, cache, position_offset), cache
+            return self._absorbed(c_q, cache, position_offset, gate), cache
         # heads a group at a time, each group's share of o_proj summed
         # as it comes: at 8192 rows a head's q, k and v are 8.4 MB, all
         # 128 heads' 1.07 GB, and the flash kernel folds a copy of each
@@ -264,7 +283,7 @@ class DeepseekV2Attention(Layer):
 
         def group(g, acc):
             return acc + self._expanded(c_q, c_kv, k_pe, g * size, size,
-                                        cache, position_offset)
+                                        cache, position_offset, gate)
 
         zero = jnp.zeros((b, t, self.o_proj.shape[1]), _F32)
         out = (group(0, zero) if groups == 1
@@ -290,7 +309,7 @@ class DeepseekV2Attention(Layer):
                                           self.nope + self.v_dim)
         return jax.lax.dynamic_slice_in_dim(w, first, size, axis=1)
 
-    def _absorbed(self, c_q, cache, position_offset):
+    def _absorbed(self, c_q, cache, position_offset, gate=None):
         """A decode step: the up-projections on the query's and the
         output's side, the cached rows as they are."""
         b, t, _ = c_q.shape
@@ -302,11 +321,11 @@ class DeepseekV2Attention(Layer):
         ctx = jnp.einsum("bthr,rhv->bthv",
                          _val(cache.attend(q_lat, self.scale, self.rank)),
                          w_ukv[..., self.nope:])
-        return jnp.matmul(ctx.reshape(b, t, self.heads * self.v_dim),
-                          self.o_proj._value)
+        return jnp.matmul(_gated(ctx.reshape(b, t, self.heads * self.v_dim),
+                                 gate), self.o_proj._value)
 
     def _expanded(self, c_q, c_kv, k_pe, first, size, cache,
-                  position_offset):
+                  position_offset, gate=None):
         """float32 [B, T, hidden]: what heads ``first .. first + size -
         1`` add to the layer's output, keys and values expanded from the
         latent for the whole sequence."""
@@ -328,8 +347,20 @@ class DeepseekV2Attention(Layer):
         w_o = jax.lax.dynamic_slice_in_dim(
             self.o_proj._value, first * self.v_dim, size * self.v_dim,
             axis=0)
-        return jnp.matmul(_val(ctx).reshape(b, t, size * self.v_dim), w_o,
-                          preferred_element_type=_F32)
+        if gate is not None:
+            gate = jax.lax.dynamic_slice_in_dim(
+                gate, first * self.v_dim, size * self.v_dim, axis=2)
+        return jnp.matmul(
+            _gated(_val(ctx).reshape(b, t, size * self.v_dim), gate), w_o,
+            preferred_element_type=_F32)
+
+
+def _gated(ctx, gate):
+    """``ctx`` [B, T, channels] times the float32 output gate of those
+    channels, in ``ctx``'s dtype; ``ctx`` itself where there is none."""
+    if gate is None:
+        return ctx
+    return (ctx.astype(_F32) * gate).astype(ctx.dtype)
 
 
 class DeepseekV2MLP(Layer):

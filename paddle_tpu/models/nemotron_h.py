@@ -56,7 +56,8 @@ from ..nn import initializer as I
 from ..nn.functional.norm import rms_norm as _rms_norm
 from ..nn.layer import Layer
 from ..nn.layers.container import LayerList
-from ..parallel.moe import MoELayer, moe_forward
+from ..parallel.moe import (MoELayer, balance_router_biases,
+                            balance_select_bias, moe_forward)
 from .generation import rows_at
 # the hook a slot_state layer gets when nobody keeps its state
 from .qwen3_next import _NoCache
@@ -405,28 +406,13 @@ class NemotronHMoE(Layer):
         return jnp.matmul(up * up, self.shared_down._value)
 
     def balance(self, flat, rounds, step):
-        """``rounds`` updates of ``e_score_correction_bias`` by the rule
-        that keeps it in training (auxiliary-loss-free balancing, Wang
-        et al., arXiv:2408.15664): with the scores of the rows ``flat``
-        [rows, hidden] fixed, every expert's bias moves by the round's
-        step (``step`` falling to a fortieth of it) toward the mean
-        load: up if the top-k of score + bias sent it fewer rows than
-        the mean, down if more."""
-        e = self.experts
-        scores = jax.nn.sigmoid(jnp.dot(
-            flat.astype(_F32), e.gate_weight._value.astype(_F32),
-            precision=jax.lax.Precision.HIGHEST))
-        experts = scores.shape[-1]
-
-        def update(r, bias):
-            _, chosen = jax.lax.top_k(scores + bias, e.top_k)
-            load = jnp.zeros((experts,), _F32).at[chosen.reshape(-1)].add(1.0)
-            size = step * (1.0 - 0.975 * r / rounds)
-            return bias + size * jnp.sign(jnp.mean(load) - load)
-
-        bias = self.e_score_correction_bias._value
-        self.e_score_correction_bias._value = jax.lax.fori_loop(
-            0, rounds, update, bias.astype(_F32)).astype(bias.dtype)
+        """``rounds`` updates of ``e_score_correction_bias`` on the rows
+        ``flat`` [rows, hidden] by the rule that keeps it in training
+        (parallel/moe.py ``balance_select_bias``)."""
+        bias = self.e_score_correction_bias
+        bias._value = balance_select_bias(
+            flat, self.experts.gate_weight._value, bias._value,
+            self.experts.top_k, rounds, step)
 
     def forward(self, x):
         flat = x.reshape(-1, x.shape[-1])
@@ -535,26 +521,10 @@ class NemotronHForCausalLM(Layer):
         state-space layer averages its inputs), so without this every
         token picks much the same few experts and which ones is a draw
         of the seed."""
-        routers = [layer.mixer for layer in self.backbone.layers
-                   if layer.kind == "E"]
-        names, values = self.functional_state()
-
-        def router_inputs(vals, ids):
-            caught = []
-            hooks = [mixer.register_forward_pre_hook(
-                lambda _layer, inputs: caught.append(inputs[0]))
-                for mixer in routers]
-            try:
-                with self.bind_state(names, list(vals)):
-                    self._run(ids, None)
-            finally:
-                for hook in hooks:
-                    hook.remove()
-            return caught
-
-        caught = jax.jit(router_inputs)(values, _val(input_ids))
-        for mixer, x in zip(routers, caught):
-            mixer.balance(x.reshape(-1, x.shape[-1]), rounds, step)
+        balance_router_biases(
+            self, [layer.mixer for layer in self.backbone.layers
+                   if layer.kind == "E"],
+            lambda ids: self._run(ids, None), _val(input_ids), rounds, step)
 
     def moe_step_stats(self):
         """int32 [expert layers, 4] of the step just traced: pairs

@@ -63,11 +63,17 @@ class Qwen3NextConfig:
                  shared_expert_intermediate_size=512, num_experts=512,
                  num_experts_per_tok=10, norm_topk_prob=True,
                  experts_held=None, max_position_embeddings=262144,
-                 dtype="float32"):
+                 linear_sigmoid_gate_scale=None,
+                 linear_attn_o_norm_eps=None, dtype="float32"):
         """``num_experts`` is the router's published width;
         ``experts_held`` (a range, default all) the experts that live
         here. ``vocab_size`` is the number of vocabulary rows held here
-        (ids, logits and argmax are over them)."""
+        (ids, logits and argmax are over them).
+        ``linear_sigmoid_gate_scale`` None is Qwen3-Next's Gated
+        DeltaNet output gate (a plain-weight head norm times silu(z));
+        a number s makes it a zero-centred head norm times s sigmoid(z)
+        (``Qwen3NextGatedDeltaNet``). ``linear_attn_o_norm_eps`` is that
+        head norm's eps (None: ``rms_norm_eps``)."""
         self.vocab_size = vocab_size
         self.hidden_size = hidden_size
         self.num_hidden_layers = num_hidden_layers
@@ -92,6 +98,10 @@ class Qwen3NextConfig:
         self.experts_held = (range(num_experts) if experts_held is None
                              else experts_held)
         self.max_position_embeddings = max_position_embeddings
+        self.linear_sigmoid_gate_scale = linear_sigmoid_gate_scale
+        self.linear_attn_o_norm_eps = (rms_norm_eps
+                                       if linear_attn_o_norm_eps is None
+                                       else linear_attn_o_norm_eps)
         self.dtype = dtype
 
     def is_full_attention(self, i):
@@ -227,13 +237,21 @@ class _NoCache:
 # -- layers ------------------------------------------------------------------
 
 class Qwen3NextGatedDeltaNet(Layer):
+    """The Gated DeltaNet mixer. Its output is per value head
+    ``rmsnorm(o) * w * silu(z)`` (Qwen3-Next), or with the config's
+    ``linear_sigmoid_gate_scale`` s ``rmsnorm(o) * (1 + w) * s
+    sigmoid(z)``: a zero-centred norm gated by a scaled sigmoid
+    (GigaChat3.5's ``gated_rmsnorm_sigmoid_zero_centered``); the head
+    norm's eps is the config's ``linear_attn_o_norm_eps``."""
+
     def __init__(self, config):
         super().__init__()
         c = config
         self.hk, self.dk = c.linear_num_key_heads, c.linear_key_head_dim
         self.hv, self.dv = c.linear_num_value_heads, c.linear_value_head_dim
         self.kernel = c.linear_conv_kernel_dim
-        self.eps = c.rms_norm_eps
+        self.eps = c.linear_attn_o_norm_eps
+        self.gate_scale = c.linear_sigmoid_gate_scale
         self.key_dim = self.hk * self.dk
         self.value_dim = self.hv * self.dv
         self.conv_dim = 2 * self.key_dim + self.value_dim
@@ -254,7 +272,8 @@ class Qwen3NextGatedDeltaNet(Layer):
         self.dt_bias = self.create_parameter(
             [self.hv], dtype=dt, default_initializer=I.Uniform(-6.9, -2.25))
         self.norm_weight = self.create_parameter(
-            [self.dv], dtype=dt, default_initializer=I.Constant(1.0))
+            [self.dv], dtype=dt, default_initializer=I.Constant(
+                1.0 if self.gate_scale is None else 0.0))
         self.out_proj = self.create_parameter(
             [self.value_dim, c.hidden_size], dtype=dt,
             default_initializer=xavier)
@@ -304,11 +323,16 @@ class Qwen3NextGatedDeltaNet(Layer):
             tail = jax.lax.dynamic_slice_in_dim(
                 window, cache.valid_len, self.kernel - 1, axis=1)
         cache = cache.write({"state": state, "conv": tail})
-        # per-head RMSNorm (plain weight) gated by silu(z)
+        # per-head RMSNorm, then the gate
         inv = jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
                             + self.eps)
-        o = (o * inv * self.norm_weight._value.astype(_F32)
-             * jax.nn.silu(z.astype(_F32))).astype(x.dtype)
+        if self.gate_scale is None:
+            o = (o * inv * self.norm_weight._value.astype(_F32)
+                 * jax.nn.silu(z.astype(_F32))).astype(x.dtype)
+        else:
+            o = (o * inv * (1.0 + self.norm_weight._value.astype(_F32))
+                 * (self.gate_scale * jax.nn.sigmoid(z.astype(_F32)))
+                 ).astype(x.dtype)
         out = jnp.matmul(o.reshape(b, t, self.value_dim),
                          self.out_proj._value)
         return out, cache

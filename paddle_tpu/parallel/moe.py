@@ -107,6 +107,60 @@ def route(x, gate_w, top_k, norm_topk_prob, n_group=1, topk_group=1,
     return weights, experts.astype(jnp.int32), aux
 
 
+def balance_select_bias(x, gate_w, select_bias, top_k, rounds, step):
+    """``rounds`` updates of a router's ``select_bias`` [E] by the rule
+    that keeps it in training (auxiliary-loss-free balancing, Wang et
+    al., arXiv:2408.15664): with the sigmoid scores of the rows ``x``
+    [rows, D] fixed, every expert's bias moves by the round's step
+    (``step`` falling to a fortieth of it) toward the mean load: up if
+    the top-k of score + bias sent it fewer rows than the mean, down if
+    more. -> the new bias, in its own dtype."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), _A(gate_w).astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    experts = scores.shape[-1]
+
+    def update(r, bias):
+        _, chosen = jax.lax.top_k(scores + bias, top_k)
+        load = jnp.zeros((experts,), jnp.float32).at[
+            chosen.reshape(-1)].add(1.0)
+        size = step * (1.0 - 0.975 * r / rounds)
+        return bias + size * jnp.sign(jnp.mean(load) - load)
+
+    bias = _A(select_bias)
+    return jax.lax.fori_loop(0, rounds, update,
+                             bias.astype(jnp.float32)).astype(bias.dtype)
+
+
+def balance_router_biases(model, blocks, run, input_ids, rounds, step):
+    """What training does to the selection bias of each expert block of
+    ``blocks`` (a layer with ``balance(rows, rounds, step)``), done here
+    on ``input_ids`` [B, T]: one jitted ``run(ids)`` of ``model`` with
+    its weights bound and each block's input caught on its way in, then
+    every block's ``balance`` on the rows it caught. A published
+    selection bias exists to keep the experts' loads even; a router of
+    seeded random weights sends every token to much the same few
+    experts without it."""
+    names, values = model.functional_state()
+
+    def router_inputs(vals, ids):
+        caught = []
+        hooks = [block.register_forward_pre_hook(
+            lambda _layer, inputs: caught.append(inputs[0]))
+            for block in blocks]
+        try:
+            with model.bind_state(names, list(vals)):
+                run(ids)
+        finally:
+            for hook in hooks:
+                hook.remove()
+        return caught
+
+    caught = jax.jit(router_inputs)(values, input_ids)
+    for block, x in zip(blocks, caught):
+        block.balance(x.reshape(-1, x.shape[-1]), rounds, step)
+
+
 # A block of the sorted pair list is no fewer rows than a decode step
 # has pairs (256 slots x 6): a decode step lays out every pair.
 _MIN_BLOCK = 2048
@@ -125,19 +179,30 @@ def _pair_block(pairs, held, num_experts):
     return -(-block // 128) * 128
 
 
+def swiglu_clamped(gate, up, limit):
+    """silu(min(gate, limit)) * clip(up, -limit, limit): a SwiGLU whose
+    two halves are clamped (the ``swiglu_limit`` of a config)."""
+    return (jax.nn.silu(jnp.minimum(gate, limit))
+            * jnp.clip(up, -limit, limit))
+
+
 def _run_experts(rows, row_key, sizes, w_in, b_in, w_out, b_out,
-                 activation, gated):
+                 activation, gated, swiglu_limit=None):
     """The held experts on rows sorted by expert: ``rows`` [M, D],
     ``row_key`` [M] a row's held expert (``held`` for a row of no
     group), ``sizes`` [H] rows a group -> y [M, D]. A row of no group
-    comes back undefined, not zero."""
+    comes back undefined, not zero. ``swiglu_limit`` clamps a gated
+    silu expert's two halves (``swiglu_clamped``)."""
     h = grouped_matmul(rows, w_in, sizes)
     # a row's own expert, for the biases (rows past every group: any)
     expert_of_row = jnp.minimum(row_key, w_in.shape[0] - 1)
     if b_in is not None:
         h = h + _A(b_in)[expert_of_row]
     act = _ACTIVATIONS[activation]
-    if gated:
+    if gated and swiglu_limit is not None:
+        f = h.shape[-1] // 2
+        h = swiglu_clamped(h[:, :f], h[:, f:], swiglu_limit)
+    elif gated:
         f = h.shape[-1] // 2
         h = act(h[:, :f]) * h[:, f:]
     else:
@@ -179,7 +244,7 @@ def _lay_out(x, weights, here, order, sorted_key, sizes, rows, top_k,
 def moe_forward(x, gate_w, w_in, b_in, w_out, b_out, *, top_k, lo=0,
                 activation="gelu", gated=False, norm_topk_prob=None,
                 n_group=1, topk_group=1, routed_scaling_factor=1.0,
-                select_bias=None):
+                select_bias=None, swiglu_limit=None, row_mask=None):
     """The dropless expert layer on raw arrays.
 
     x [T, D]; gate_w [D, E] routes over all E published experts; the
@@ -189,7 +254,11 @@ def moe_forward(x, gate_w, w_in, b_in, w_out, b_out, *, top_k, lo=0,
     or None. ``norm_topk_prob`` None means "when top_k > 1" (a single
     choice keeps its raw probability, or the router would get no
     gradient); ``n_group``, ``topk_group``, ``routed_scaling_factor``
-    and ``select_bias`` are ``route``'s. -> (out [T, D], aux loss, stats
+    and ``select_bias`` are ``route``'s; ``swiglu_limit`` clamps a gated
+    silu expert (``swiglu_clamped``); ``row_mask`` (bool [T]) names the
+    rows whose pairs are computed at all: a row outside it (a prefill's
+    padding) is routed, its pairs are counted as held elsewhere and its
+    routed share is 0. -> (out [T, D], aux loss, stats
     int32 [4]): the share of the result the held experts give; pairs
     routed here, held experts that received a row, the largest load of
     one expert, rows of the sorted pair list handed to the grouped
@@ -210,6 +279,8 @@ def moe_forward(x, gate_w, w_in, b_in, w_out, b_out, *, top_k, lo=0,
                                   n_group, topk_group,
                                   routed_scaling_factor, select_bias)
     here = jnp.logical_and(experts >= lo, experts < lo + held)   # [T, k]
+    if row_mask is not None:
+        here = jnp.logical_and(here, row_mask[:, None])
     # pairs sorted by held expert; the pairs of experts held elsewhere
     # sort to the end, past every group: the live count is ends[held]
     key = jnp.where(here, experts - lo, held).reshape(-1)        # [T*k]
@@ -220,7 +291,8 @@ def moe_forward(x, gate_w, w_in, b_in, w_out, b_out, *, top_k, lo=0,
     sizes = (ends[1:] - ends[:-1]).astype(jnp.int32)
     lay_out = functools.partial(
         _lay_out, top_k=top_k,
-        experts=(w_in, b_in, w_out, b_out, activation, gated))
+        experts=(w_in, b_in, w_out, b_out, activation, gated,
+                 swiglu_limit))
     routed = (x, weights, here, order, sorted_key, sizes)
     pairs, num_experts = t * top_k, jnp.shape(gate_w)[-1]
     block = _pair_block(pairs, held, num_experts)

@@ -203,6 +203,13 @@ def _nemotron_h_programs():
     return _engine_programs(m.NemotronHForCausalLM(m.NemotronHConfig.tiny()))
 
 
+def _gigachat3_5_programs():
+    from paddle_tpu.models import gigachat3_5 as m
+
+    return _engine_programs(
+        m.GigaChat35ForCausalLM(m.GigaChat35Config.tiny()))
+
+
 FAMILIES = {
     "llama": (_llama_programs, {"embed", "attn", "mlp", "lm_head"}),
     "llama_train": (_llama_train_program,
@@ -213,6 +220,8 @@ FAMILIES = {
                     {"embed", "mla", "mlp", "moe", "lm_head"}),
     "nemotron_h": (_nemotron_h_programs,
                    {"embed", "attn", "ssm", "moe", "lm_head"}),
+    "gigachat3_5": (_gigachat3_5_programs,
+                    {"embed", "gdn", "mla", "mlp", "moe", "lm_head"}),
 }
 _compiled = {}
 
@@ -228,6 +237,7 @@ def _program(family, program):
     ("qwen3_next", "decode"), ("qwen3_next", "prefill"),
     ("deepseek_v2", "decode"), ("deepseek_v2", "prefill"),
     ("nemotron_h", "decode"), ("nemotron_h", "prefill"),
+    ("gigachat3_5", "decode"), ("gigachat3_5", "prefill"),
 ])
 def test_every_named_instruction_of_a_compiled_step_has_a_scope(
         family, program):

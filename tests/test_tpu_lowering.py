@@ -31,6 +31,7 @@ from paddle_tpu.analysis.graph.hlo import mosaic_kernels
 from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import fused_ce as fc
 from paddle_tpu.kernels import moe_gmm as mg
+from paddle_tpu.parallel import moe
 
 # the package re-exports a function under the module's name
 pa = importlib.import_module("paddle_tpu.serving.kernels.paged_attention")
@@ -70,6 +71,13 @@ MLA_QK, MLA_V = 192, 128
 # (benchmark/traffic/longreason-backlog.json)
 SSM_S, SSM_H, SSM_P, SSM_G, SSM_N = 256, 64, 64, 8, 128
 NEMO_EXPERTS, NEMO_HID, NEMO_WIDTH, NEMO_TOPK = 64, 2688, 1856, 6
+# gigachat35-longdoc-backlog: 64 latent heads over the same 576-value
+# row, 192 slots x 640 pages of an 80,000-page pool; 8 experts held of
+# 256 at 7168 x 2048, clamped SwiGLU, eight pairs a token
+# (benchmark/traffic/longdoc-hybrid-backlog.json)
+GIGA_S, GIGA_NB, GIGA_H = 192, 80000, 64
+GIGA_EXPERTS, GIGA_ROUTER, GIGA_HID, GIGA_WIDTH, GIGA_TOPK = (
+    8, 256, 7168, 2048, 8)
 
 
 def _flash(dtype, d, segmented=False):
@@ -139,6 +147,13 @@ def _cases():
          ((MLA_S, MLA_MB), I32), ((MLA_S,), I32)],
         {"mla_decode"}))
     cases.append((
+        "mla_decode_bf16_gigachat_cell",
+        lambda q, pool, bt, ln: mla.mla_attention_kernel(
+            q, pool, bt, ln, scale=0.1053, rank=MLA_RANK, interpret=False),
+        [((GIGA_S, GIGA_H, MLA_W), BF16), ((GIGA_NB, BS, MLA_W), BF16),
+         ((GIGA_S, MLA_MB), I32), ((GIGA_S,), I32)],
+        {"mla_decode"}))
+    cases.append((
         "mla_decode_f32_tiny",
         lambda q, pool, bt, ln: mla.mla_attention_kernel(
             q, pool, bt, ln, scale=0.2, rank=128, interpret=False),
@@ -187,6 +202,23 @@ def _cases():
              ((QWEN_EXPERTS, QWEN_WIDTH, QWEN_HID), BF16),
              ((QWEN_EXPERTS,), I32)],
             {"moe_gmm"}))
+    def clamped_experts(x, w1, w2, sizes):
+        # the clamped SwiGLU between the two calls
+        h = mg.moe_gmm(x, w1, sizes, interpret=False)
+        h = moe.swiglu_clamped(h[:, :GIGA_WIDTH], h[:, GIGA_WIDTH:], 10.0)
+        return mg.moe_gmm(h.astype(BF16), w2, sizes, interpret=False)
+
+    for name, tokens in (("moe_gmm_bf16_k7168_decode", GIGA_S),
+                         ("moe_gmm_bf16_k7168_prefill", 2048)):
+        cases.append((
+            name, clamped_experts,
+            [((tokens * GIGA_TOPK * GIGA_EXPERTS // GIGA_ROUTER, GIGA_HID),
+              BF16),
+             ((GIGA_EXPERTS, GIGA_HID, 2 * GIGA_WIDTH), BF16),
+             ((GIGA_EXPERTS, GIGA_WIDTH, GIGA_HID), BF16),
+             ((GIGA_EXPERTS,), I32)],
+            {"moe_gmm"}))
+
     def relu2_experts(x, w1, w2, sizes):
         # an ungated expert layer's two calls: up, then down
         h = jnp.square(jax.nn.relu(mg.moe_gmm(x, w1, sizes,
@@ -316,7 +348,7 @@ DSV2_EXPERTS, DSV2_ROUTER, DSV2_HID, DSV2_WIDTH, DSV2_TOPK = (
 
 
 class TestExpertLayerPrefill:
-    """``moe_forward`` on the 8192 rows of the three sparse cells'
+    """``moe_forward`` on the 8192 rows of the four sparse cells'
     largest prefill, compiled for the v5e at each family's widths: two
     programs under one ``cond``, each with the grouped kernel twice
     (the jitted body is one kernel name), the first on a block's rows
@@ -336,11 +368,13 @@ class TestExpertLayerPrefill:
         (NEMO_EXPERTS, 2 * NEMO_EXPERTS, NEMO_HID, NEMO_WIDTH, NEMO_TOPK,
          dict(activation="relu2", norm_topk_prob=True,
               routed_scaling_factor=2.5, select_bias=True)),
-    ], ids=["deepseek-v2", "qwen3-next", "nemotron-h"])
+        (GIGA_EXPERTS, GIGA_ROUTER, GIGA_HID, GIGA_WIDTH, GIGA_TOPK,
+         dict(gated=True, activation="silu", norm_topk_prob=True,
+              routed_scaling_factor=2.5, select_bias=True,
+              swiglu_limit=10.0)),
+    ], ids=["deepseek-v2", "qwen3-next", "nemotron-h", "gigachat3.5"])
     def test_a_block_and_every_pair_compile_under_one_cond(
             self, v5e, monkeypatch, held, router, hid, width, top_k, kw):
-        from paddle_tpu.parallel import moe
-
         # the kernels' dispatch asks the backend; here it is the CPU
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         kw = dict(kw)
