@@ -17,10 +17,12 @@ The Gated DeltaNet recurrence, per value head, state S in float32::
     S <- exp(g_t) S;  u = (v_t - S^T k_t) beta_t;  S <- S + k_t u^T
     o_t = S^T q_t
 
-runs in two forms that give the same numbers: ``gated_delta_chunked``
-(the WY form, 64 tokens a chunk, for a prompt) and ``gated_delta_step``
-(one token for every slot, for decode). A padded row takes g = 0 and
-beta = 0, which leaves S exactly as it was.
+runs in two forms that give the same numbers: the chunked WY form, 64
+tokens a chunk, for a prompt (``kernels/gdn_chunked.py``: the Mosaic
+kernel ``gdn_chunked`` on a TPU, its jnp twin ``gated_delta_chunked``
+elsewhere) and ``gated_delta_step`` (one token for every slot, for
+decode). A padded row takes g = 0 and beta = 0, which leaves S exactly
+as it was.
 
 The experts are ``parallel/moe.py``'s dropless layer, told which
 experts live here (``experts_held``): it routes over the published
@@ -40,6 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor
+from ..kernels.gdn_chunked import gdn_chunked
 from ..nn import initializer as I
 from ..nn.layer import Layer
 from ..nn.layers.container import LayerList
@@ -47,7 +50,6 @@ from ..parallel.moe import MoELayer, moe_forward
 from .generation import rows_at
 from .llama import rope_apply
 
-GDN_CHUNK = 64
 _F32 = jnp.float32
 
 
@@ -157,68 +159,6 @@ def gated_delta_step(q, k, v, g, beta, state):
     return o, new_state
 
 
-def gated_delta_chunked(q, k, v, g, beta, state, chunk=GDN_CHUNK):
-    """The same recurrence over T tokens of B sequences, a chunk at a
-    time (the WY representation: inside a chunk the token-by-token
-    updates are one unit-lower-triangular solve, between chunks the
-    state is carried). q, k [B, T, Hk, Dk], v [B, T, Hv, Dv], g and
-    beta [B, T, Hv], state [B, Hv, Dk, Dv], float32. A row with g = 0
-    and beta = 0 changes nothing, which is how padding is expressed
-    (T is padded up to a whole chunk that way here).
-    -> (o [B, T, Hv, Dv], state after the last row)."""
-    b, t, hk, dk = q.shape
-    hv, dv = v.shape[2], v.shape[3]
-    rep = hv // hk
-    pad = -t % chunk
-    if pad:
-        q, k, v, g, beta = (
-            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-            for a in (q, k, v, g, beta))
-    n = (t + pad) // chunk
-
-    def chunks(a):          # [B, T, H, ...] -> [n, B, H, C, ...]
-        a = a.reshape((b, n, chunk) + a.shape[2:])
-        return jnp.moveaxis(jnp.moveaxis(a, 1, 0), 2, 3)
-
-    qc, kc, vc = chunks(q), chunks(k), chunks(v)
-    gc = jnp.cumsum(chunks(g), axis=-1)                         # [n, B, Hv, C]
-    bc = chunks(beta)
-    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
-    # exp(G_i - G_j) for j <= i; masked before the exp, not after
-    decay = jnp.exp(jnp.where(lower, gc[..., :, None] - gc[..., None, :],
-                              -jnp.inf))                        # [n,B,Hv,C,C]
-    kk = jnp.repeat(jnp.einsum("nbhcd,nbhed->nbhce", kc, kc), rep, axis=2)
-    qk = jnp.repeat(jnp.einsum("nbhcd,nbhed->nbhce", qc, kc), rep, axis=2)
-    kv_heads = jnp.repeat(kc, rep, axis=2)                      # [n,B,Hv,C,Dk]
-    qv_heads = jnp.repeat(qc, rep, axis=2)
-    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
-    a = jnp.where(strict, bc[..., None] * kk * decay, 0.0)
-    rhs = jnp.concatenate(
-        [vc * bc[..., None],
-         kv_heads * (bc * jnp.exp(gc))[..., None]], axis=-1)
-    # (I + A) sol = rhs; the solve takes the diagonal as 1 unread
-    sol = jax.lax.linalg.triangular_solve(
-        a, rhs, left_side=True, lower=True, unit_diagonal=True)
-    u, w = sol[..., :dv], sol[..., dv:]
-    local = qk * decay                                          # j <= i
-    q_in = qv_heads * jnp.exp(gc)[..., None]
-    last = gc[..., -1:]                                         # [n,B,Hv,1]
-    k_out = kv_heads * jnp.exp(last - gc)[..., None]
-
-    def body(s, xs):
-        u_i, w_i, local_i, q_i, k_i, last_i = xs
-        v_new = u_i - jnp.einsum("bhck,bhkv->bhcv", w_i, s)
-        o_i = (jnp.einsum("bhck,bhkv->bhcv", q_i, s)
-               + jnp.einsum("bhce,bhev->bhcv", local_i, v_new))
-        s = (jnp.exp(last_i)[..., None] * s
-             + jnp.einsum("bhck,bhcv->bhkv", k_i, v_new))
-        return s, o_i
-
-    state, o = jax.lax.scan(body, state, (u, w, local, q_in, k_out, last))
-    o = jnp.moveaxis(jnp.moveaxis(o, 3, 2), 0, 1)               # [B,n,C,Hv,Dv]
-    return o.reshape(b, t + pad, hv, dv)[:, :t], state
-
-
 class _NoCache:
     """The hook a Gated DeltaNet layer gets when nobody keeps its state
     (a plain forward over whole sequences): zeros in, nothing out."""
@@ -317,7 +257,7 @@ class Qwen3NextGatedDeltaNet(Layer):
             # a prompt, right-padded: the rows past valid_len change
             # neither the state nor the tail
             live = (jnp.arange(t) < cache.valid_len)[None, :, None]
-            o, state = gated_delta_chunked(
+            o, state = gdn_chunked(
                 q, k, v, jnp.where(live, g, 0.0),
                 jnp.where(live, beta, 0.0), held["state"])
             tail = jax.lax.dynamic_slice_in_dim(

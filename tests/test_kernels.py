@@ -944,3 +944,123 @@ class TestSsmDecodeKernel:
         text = str(jax.make_jaxpr(two_layers)(*args))
         assert text.count("name=_ssm_decode") == 2
         assert text.count("name=ssm_decode") == 1
+
+
+class TestGdnChunkedKernel:
+    """``gdn_chunked`` (kernels/gdn_chunked.py) in interpret mode against
+    the token-by-token recurrence (``gated_delta_step``) and its jnp twin
+    (``gated_delta_chunked``), from a state that is not zero."""
+
+    @staticmethod
+    def _inputs(b, t, hk, hv, d, seed):
+        from paddle_tpu.models import qwen3_next as qn
+
+        rng = np.random.RandomState(seed)
+        q = qn.l2_normalise(jnp.asarray(rng.randn(b, t, hk, d),
+                                        jnp.float32)) * d ** -0.5
+        k = qn.l2_normalise(jnp.asarray(rng.randn(b, t, hk, d),
+                                        jnp.float32))
+        v = jnp.asarray(rng.randn(b, t, hv, d), jnp.float32)
+        g = -jnp.asarray(rng.rand(b, t, hv), jnp.float32)
+        beta = jnp.asarray(rng.rand(b, t, hv), jnp.float32)
+        state = jnp.asarray(rng.randn(b, hv, d, d), jnp.float32)
+        return q, k, v, g, beta, state
+
+    @staticmethod
+    def _recurrence(q, k, v, g, beta, state):
+        from paddle_tpu.models import qwen3_next as qn
+
+        outs = []
+        for t in range(q.shape[1]):
+            o, state = qn.gated_delta_step(q[:, t], k[:, t], v[:, t],
+                                           g[:, t], beta[:, t], state)
+            outs.append(o)
+        return jnp.stack(outs, 1), state
+
+    @pytest.mark.parametrize("tokens,hk", [
+        (1, 2), (63, 2), (64, 2), (65, 2), (150, 2), (129, 2), (65, 4),
+        (64, 1)], ids=["t1", "t63", "t64", "t65", "t150", "t129",
+                       "t65_one_key_a_value_head", "t64_four_value_heads_a_key"])
+    def test_kernel_matches_the_recurrence_and_its_twin(self, tokens, hk):
+        """One chunk short, whole, one over, several with a remainder and
+        one over two chunks; two value heads a key head (the published
+        ratio), and a pair of value heads on two key heads or on one."""
+        from paddle_tpu.kernels import gdn_chunked as gk
+
+        args = self._inputs(2, tokens, hk, 4, 16, seed=tokens + hk)
+        o_k, s_k = gk.gdn_chunked_kernel(*args, interpret=True)
+        assert o_k.shape == (2, tokens, 4, 16) and o_k.dtype == jnp.float32
+        assert s_k.dtype == jnp.float32
+        o_r, s_r = self._recurrence(*args)
+        o_t, s_t = gk.gated_delta_chunked(*args)
+        for want_o, want_s in ((o_r, s_r), (o_t, s_t)):
+            np.testing.assert_allclose(np.asarray(o_k), np.asarray(want_o),
+                                       rtol=1e-4, atol=1e-5)
+            np.testing.assert_allclose(np.asarray(s_k), np.asarray(want_s),
+                                       rtol=1e-4, atol=1e-5)
+
+    def test_padded_rows_leave_the_state_as_the_real_rows_do(self):
+        """g = 0 and beta = 0 on the rows past the real ones: the state is
+        what the real rows alone leave, and the real rows' outputs are
+        theirs."""
+        from paddle_tpu.kernels import gdn_chunked as gk
+
+        q, k, v, g, beta, state = self._inputs(1, 128, 2, 4, 16, seed=5)
+        live = (jnp.arange(128) < 75)[None, :, None]
+        o_pad, padded = gk.gdn_chunked_kernel(
+            q, k, v, jnp.where(live, g, 0.0), jnp.where(live, beta, 0.0),
+            state, interpret=True)
+        o_real, real = gk.gdn_chunked_kernel(
+            q[:, :75], k[:, :75], v[:, :75], g[:, :75], beta[:, :75], state,
+            interpret=True)
+        np.testing.assert_allclose(np.asarray(padded), np.asarray(real),
+                                   rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(o_pad[:, :75]),
+                                   np.asarray(o_real), rtol=1e-5, atol=1e-6)
+
+    def test_gradients_through_the_kernel_are_the_twins(self):
+        from paddle_tpu.kernels import gdn_chunked as gk
+
+        args = self._inputs(1, 70, 2, 4, 16, seed=3)
+        rng = np.random.RandomState(4)
+        w_o = jnp.asarray(rng.randn(1, 70, 4, 16), jnp.float32)
+        w_s = jnp.asarray(rng.randn(1, 4, 16, 16), jnp.float32)
+
+        def loss(fn):
+            def f(*a):
+                o, s = fn(*a)
+                return jnp.sum(o * w_o) + jnp.sum(s * w_s)
+            return jax.grad(f, argnums=tuple(range(6)))
+
+        got = loss(lambda *a: gk.gdn_chunked_kernel(*a, interpret=True))(
+            *args)
+        want = loss(gk.gated_delta_chunked)(*args)
+        for x, y in zip(got, want):
+            np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                       rtol=1e-4, atol=1e-5)
+
+    def test_off_the_tpu_the_dispatch_takes_the_twin(self):
+        from paddle_tpu.kernels import gdn_chunked as gk
+
+        args = self._inputs(1, 20, 2, 4, 16, seed=1)
+        text = str(jax.make_jaxpr(gk.gdn_chunked)(*args))
+        assert "pallas_call" not in text
+        o, s = gk.gdn_chunked(*args)
+        o_t, s_t = gk.gated_delta_chunked(*args)
+        assert np.array_equal(np.asarray(o), np.asarray(o_t))
+        assert np.array_equal(np.asarray(s), np.asarray(s_t))
+
+    def test_all_layers_share_one_trace_of_the_kernel(self):
+        """The kernel body sits in a jitted wrapper: two calls in one
+        program are two calls of one ``_gdn_forward``."""
+        from paddle_tpu.kernels import gdn_chunked as gk
+
+        args = self._inputs(1, 20, 2, 4, 16, seed=2)
+
+        def two_layers(*a):
+            o, state = gk.gdn_chunked_kernel(*a, interpret=True)
+            return gk.gdn_chunked_kernel(*a[:5], state, interpret=True)
+
+        text = str(jax.make_jaxpr(two_layers)(*args))
+        assert text.count("name=_gdn_forward") == 2
+        assert text.count("name=gdn_chunked") == 1

@@ -17,6 +17,7 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu import serving
 from paddle_tpu.core import flags as _flags
+from paddle_tpu.kernels.gdn_chunked import gated_delta_chunked
 from paddle_tpu.models import qwen3_next as qn
 from paddle_tpu.serving.kv_cache import KVBlockPool
 from tools.serving_parity import logits_through_cache, program_routing
@@ -118,7 +119,7 @@ def test_chunked_form_matches_the_recurrence(tokens):
     """One chunk short, whole, one over, and several with a remainder,
     from a state that is not zero."""
     args = _gdn_inputs(2, tokens, seed=tokens)
-    o_c, s_c = qn.gated_delta_chunked(*args)
+    o_c, s_c = gated_delta_chunked(*args)
     o_r, s_r = _recurrence(*args)
     np.testing.assert_allclose(np.asarray(o_c), np.asarray(o_r),
                                rtol=1e-4, atol=1e-5)
@@ -131,10 +132,10 @@ def test_padded_rows_leave_the_state_as_it_was():
     what the real rows alone leave."""
     q, k, v, g, beta, state = _gdn_inputs(1, 128, seed=5)
     live = (jnp.arange(128) < 75)[None, :, None]
-    _, padded = qn.gated_delta_chunked(
+    _, padded = gated_delta_chunked(
         q, k, v, jnp.where(live, g, 0.0), jnp.where(live, beta, 0.0), state)
-    _, real = qn.gated_delta_chunked(q[:, :75], k[:, :75], v[:, :75],
-                                     g[:, :75], beta[:, :75], state)
+    _, real = gated_delta_chunked(q[:, :75], k[:, :75], v[:, :75],
+                                  g[:, :75], beta[:, :75], state)
     np.testing.assert_allclose(np.asarray(padded), np.asarray(real),
                                rtol=1e-5, atol=1e-6)
 

@@ -30,6 +30,7 @@ import pytest
 from paddle_tpu.analysis.graph.hlo import mosaic_kernels
 from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import fused_ce as fc
+from paddle_tpu.kernels import gdn_chunked as gdn
 from paddle_tpu.kernels import moe_gmm as mg
 from paddle_tpu.parallel import moe
 
@@ -78,6 +79,11 @@ NEMO_EXPERTS, NEMO_HID, NEMO_WIDTH, NEMO_TOPK = 64, 2688, 1856, 6
 GIGA_S, GIGA_NB, GIGA_H = 192, 80000, 64
 GIGA_EXPERTS, GIGA_ROUTER, GIGA_HID, GIGA_WIDTH, GIGA_TOPK = (
     8, 256, 7168, 2048, 8)
+# the Gated DeltaNet prefill of both hybrid cells, heads of 128: key /
+# value heads 16 / 32 (Qwen3-Next) and 32 / 64 (GigaChat3.5), the
+# largest bucket and one chunk
+GDN_SHAPES = (("qwen", 16, 32), ("gigachat", 32, 64))
+GDN_D, GDN_TOKENS = 128, (8192, 64)
 
 
 def _flash(dtype, d, segmented=False):
@@ -255,6 +261,16 @@ def _cases():
             [((S, C, h, D), BF16), pool, pool, ((S, MB), I32),
              ((S,), I32), ((S,), I32)],
             {"paged_mixed"}))
+    for model, hk, hv in GDN_SHAPES:
+        for tokens in GDN_TOKENS:
+            cases.append((
+                "gdn_chunked_%s_%d" % (model, tokens),
+                lambda q, k, v, g, beta, state: gdn.gdn_chunked_kernel(
+                    q, k, v, g, beta, state, interpret=False),
+                [((1, tokens, hk, GDN_D), F32), ((1, tokens, hk, GDN_D), F32),
+                 ((1, tokens, hv, GDN_D), F32), ((1, tokens, hv), F32),
+                 ((1, tokens, hv), F32), ((1, hv, GDN_D, GDN_D), F32)],
+                {"gdn_chunked"}))
     for name, dtype in (("fused_ce_bf16", BF16), ("fused_ce_f32", F32)):
         args = [((T, HID), dtype), ((HID, V), dtype), ((T,), I32)]
 
@@ -339,6 +355,9 @@ class TestMosaicCompile:
             assert found == {"moe_gmm": 2}
         if name.startswith("ssm_decode"):
             assert found == {"ssm_decode": 1}
+        if name.startswith("gdn_chunked"):
+            # one call for a prompt's whole recurrence, every head
+            assert found == {"gdn_chunked": 1}
 
 
 # deepseekv2-longctx-backlog's expert layer: 20 experts held of 160 at
@@ -609,12 +628,7 @@ class TestBlocksAreNamed:
         books device time under and what chip_smoke.py asserts on must
         be the same names, on a forward and backward with the autodiff
         wrappers around them."""
-        spec = importlib.util.spec_from_file_location(
-            "benchmark_trace_reduce", os.path.join(
-                os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                "benchmark", "trace_reduce.py"))
-        trace_reduce = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(trace_reduce)
+        trace_reduce = _benchmark_trace_reduce()
         fwd, values = forward
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         avals = [jax.ShapeDtypeStruct(v.shape, BF16, sharding=v5e)
@@ -626,6 +640,17 @@ class TestBlocksAreNamed:
         found = mosaic_kernels(text)
         assert found == {"flash_fwd": 2, "flash_dq": 2, "flash_dkv": 2}
         assert trace_reduce.kernel_counts(text) == found
+
+
+def _benchmark_trace_reduce():
+    """``benchmark/trace_reduce.py``, which stands alone (no package)."""
+    spec = importlib.util.spec_from_file_location(
+        "benchmark_trace_reduce", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "benchmark", "trace_reduce.py"))
+    trace_reduce = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(trace_reduce)
+    return trace_reduce
 
 
 def _bf16_zeros(self, shape, dtype=None, name=None):
@@ -816,3 +841,65 @@ class TestNemotronDecodeStep:
         # in as an argument, through the kernel, out as a result: twice
         assert sum(" parameter(" in line for line in touching) == 2
         assert sum(" custom-call(" in line for line in touching) == 2
+
+
+class TestGatedDeltaNetPrefill:
+    """A whole-sequence forward of Qwen3-Next (two Gated DeltaNet layers)
+    and of GigaChat3.5 (its published layer 4: GDN over the experts) at
+    the published widths, 1024 rows, two experts held, compiled for the
+    v5e: one ``gdn_chunked`` a GDN layer, by the name the benchmark books
+    device time under, each under its layer's ``gdn`` scope, and none of
+    XLA's triangular-solve custom calls, of which the jnp twin's program
+    holds one a layer."""
+
+    ROWS = 1024
+
+    def _compiled(self, v5e, monkeypatch, model):
+        from paddle_tpu.core.dispatch import no_grad
+        from paddle_tpu.core.tensor import Tensor
+
+        names, values = model.functional_state()
+
+        def logits(values, ids):
+            with model.bind_state(names, list(values)), no_grad():
+                return model(Tensor(ids))._value
+
+        avals = [jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=v5e)
+                 for v in values]
+        ids = jax.ShapeDtypeStruct((1, self.ROWS), I32, sharding=v5e)
+        return jax.jit(logits).lower(avals, ids).compile().as_text()
+
+    @pytest.mark.parametrize("family", ["qwen3-next", "gigachat3.5"])
+    def test_one_kernel_a_gdn_layer_and_no_triangular_solve(
+            self, v5e, monkeypatch, family):
+        from paddle_tpu.nn import initializer
+
+        monkeypatch.setattr(initializer.Initializer, "create", _bf16_zeros)
+        # the kernels' dispatch asks the backend; here it is the CPU
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        if family == "qwen3-next":
+            from paddle_tpu.models.qwen3_next import (Qwen3NextConfig,
+                                                      Qwen3NextForCausalLM)
+
+            model = Qwen3NextForCausalLM(Qwen3NextConfig(
+                vocab_size=1024, num_hidden_layers=2,
+                experts_held=range(2), dtype="bfloat16"))
+            gdn_layers = (0, 1)
+        else:
+            from paddle_tpu.models.gigachat3_5 import (GigaChat35Config,
+                                                       GigaChat35ForCausalLM)
+
+            model = GigaChat35ForCausalLM(GigaChat35Config(
+                vocab_size=1024, num_hidden_layers=1, layers_held=(4,),
+                experts_held=range(2), dtype="bfloat16"))
+            gdn_layers = (0,)
+        text = self._compiled(v5e, monkeypatch, model)
+        counts = _benchmark_trace_reduce().kernel_counts(text)
+        assert counts["gdn_chunked"] == len(gdn_layers), counts
+        assert mosaic_kernels(text)["gdn_chunked"] == len(gdn_layers)
+        for layer in gdn_layers:
+            assert ("layer_%d/gdn/jit(_gdn_forward)/gdn_chunked/pallas_call"
+                    % layer) in text
+        # the twin's solve: its op_name and its custom call's target
+        assert '/triangular_solve"' not in text
+        assert "InvertDiagBlocksLowerTriangular" not in text
