@@ -23,7 +23,11 @@ framework convention [B, N, H, D].
 
 Causal semantics are start-aligned (query i attends to keys j <= i) in both
 the kernel and the XLA fallback/VJP; causal cross-attention with
-kv_len != q_len uses the same convention everywhere.
+kv_len != q_len uses the same convention everywhere. A causal call may
+name a ``window``: query i then sees keys i - window + 1 .. i (the token
+itself counted), a banded mask; the forward neither computes nor fetches a
+tile wholly left of the band, and a call without one compiles to what it
+did before the option existed.
 """
 from __future__ import annotations
 
@@ -73,7 +77,7 @@ def _fwd_vmem_bytes(block_q, block_k, block_kv, d, dv, itemsize):
     return io + scratch + block_q * block_k * (4 + 4 + itemsize)
 
 
-def _fwd_tiles(n, kv_len, d, dv, itemsize, segmented=False):
+def _fwd_tiles(n, kv_len, d, dv, itemsize, segmented=False, window=None):
     """(block_q, block_k, block_kv) of a forward call that names no tile,
     from its shapes alone. block_q x block_k is the score tile: 1024 a
     side where the sequence divides by it, else the backward's 512 (or
@@ -82,9 +86,11 @@ def _fwd_tiles(n, kv_len, d, dv, itemsize, segmented=False):
     K/V block a grid step keeps resident and walks block_k rows a trip:
     the whole of K/V where the budget allows, so that a head's K/V are
     fetched once and not once a q block. A segmented call keeps
-    block_kv = block_k (its kv segment ids are cut by the BlockSpec)."""
+    block_kv = block_k (its kv segment ids are cut by the BlockSpec); so
+    does a windowed one, at the backward's 512, so that a K/V block
+    wholly left of a q block's band is never fetched."""
     def side(length, default):
-        return (_FWD_BLOCK if length % _FWD_BLOCK == 0
+        return (_FWD_BLOCK if length % _FWD_BLOCK == 0 and window is None
                 else min(default, length))
 
     bq, bk = side(n, DEFAULT_BLOCK_Q), side(kv_len, DEFAULT_BLOCK_K)
@@ -98,7 +104,7 @@ def _fwd_tiles(n, kv_len, d, dv, itemsize, segmented=False):
     while not fits(bq, bk, bk) and bq % 256 == 0:
         bq //= 2
     bkv = bk
-    if not segmented:
+    if not segmented and window is None:
         trips = kv_len // bk
         bkv = next((bk * t for t in range(trips, 1, -1)
                     if trips % t == 0 and fits(bq, bk, bk * t)), bk)
@@ -145,7 +151,7 @@ def _dot(a, b, dims, batch=((), ())):
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_k,
-               segmented):
+               segmented, window=None):
     """One (bh, q_block, kv_block) program. Refs: q [1, bq, d];
     k [1, block_kv, d]; v [1, block_kv, dv]: the K/V block this grid step
     keeps resident, walked ``block_k`` rows a loop trip; optional
@@ -159,7 +165,14 @@ def _fa_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_k,
     diagonal take no mask, the ones it crosses do; a tile above it is
     neither computed nor (the index maps of _flash_fwd_bhnd) fetched.
     The running max is kept over the raw products and ``scale`` folded
-    with log2(e) into the one multiply before a base-2 exponential."""
+    with log2(e) into the one multiply before a base-2 exponential.
+
+    With a ``window`` every tile a row can see is masked (the band's
+    lower edge crosses tiles the diagonal does not), and the trips
+    wholly left of the band are skipped like those above the diagonal.
+    A row whose tile holds none of its keys adds exp(0) terms only while
+    its running max is still the initial -1e30; its own key, which every
+    row sees in a later trip, rescales them by exactly 0."""
     if segmented:
         sq_ref, sk_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -199,7 +212,10 @@ def _fa_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_k,
             row = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
             col = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
             first = kv_i * block_kv + j * block_k - q_idx * bq - r0
-            s = jnp.where(row - col >= first, s, NEG_INF)
+            keep = row - col >= first
+            if window is not None:
+                keep = jnp.logical_and(keep, row - col < first + window)
+            s = jnp.where(keep, s, NEG_INF)
         if segmented:
             s = jnp.where(
                 sq_ref[0, 0, rs][:, None] == sk_ref[0, 0, ks][None, :],
@@ -223,6 +239,11 @@ def _fa_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_k,
         below = jnp.minimum(jnp.maximum(q_lo + 1, 0) // block_k, trips)
         seen = jnp.minimum(
             jnp.maximum(q_lo + bq - 1 + block_k, 0) // block_k, trips)
+        if window is not None:
+            # the first trip that holds a key of the band of some row
+            left = jnp.clip((q_lo - window + 1) // block_k, 0, trips)
+            walk(left, seen, lambda j: tile(j, True))
+    if causal and window is None:
         walk(0, below, lambda j: tile(j, False))
 
         def on_diagonal(j):
@@ -235,7 +256,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_k,
                 tile(j, True)
 
         walk(below, seen, on_diagonal)
-    else:
+    elif not causal:
         walk(0, trips, lambda j: tile(j, False))
 
     @pl.when(kv_i == num_kv - 1)
@@ -250,13 +271,15 @@ def _fa_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_k,
 # kernel body: traced once a layer, its tile programs cost every start of
 # a 12-layer engine 2.3 s a prefill bucket (measured on the chip, PR 35)
 @functools.partial(jax.jit, static_argnames=(
-    "scale", "causal", "block_q", "block_k", "interpret", "block_kv"))
+    "scale", "causal", "block_q", "block_k", "interpret", "block_kv",
+    "window"))
 def _flash_fwd_bhnd(q, k, v, scale, causal, block_q, block_k, interpret,
-                    segs=None, block_kv=None):
+                    segs=None, block_kv=None, window=None):
     """q,k: [BH, N, D], v: [BH, N, Dv] (heads folded into batch); segs:
     optional [BH, N] int32 segment ids (ragged/packed attention);
     block_kv: the K/V rows a grid step keeps resident (a multiple of
-    block_k, block_k itself when not given).
+    block_k, block_k itself when not given); window: causal only, the
+    keys a query sees counting itself.
     -> (out [BH, N, Dv], lse [BH, 1, N])."""
     bh, n, d = q.shape
     kv_len = k.shape[1]
@@ -266,8 +289,16 @@ def _flash_fwd_bhnd(q, k, v, scale, causal, block_q, block_k, interpret,
     segmented = segs is not None
     kernel = functools.partial(
         _fa_kernel, scale=scale, causal=causal, block_k=block_k,
-        segmented=segmented)
-    if causal:
+        segmented=segmented,
+        **({} if window is None else {"window": window}))
+    if causal and window is not None:
+        # the blocks left of a q block's band are not fetched either:
+        # they name the first block it can see, fetched once
+        def kv_block(i, j):
+            lo = jnp.maximum(i * block_q - window + 1, 0) // block_kv
+            hi = (i * block_q + block_q - 1) // block_kv
+            return jnp.clip(j, lo, hi)
+    elif causal:
         # a block above the diagonal is not computed (the kernel's `seen`
         # is 0 there); naming the last block the q block can see in its
         # place keeps the pipeline from fetching it, since a block whose
@@ -331,8 +362,16 @@ def _flash_fwd_bhnd(q, k, v, scale, causal, block_q, block_k, interpret,
     )(*args)
 
 
+def _sees(q_pos, k_pos, window):
+    """The causal mask, banded to ``window`` keys when one is named."""
+    keep = q_pos >= k_pos
+    if window is not None:
+        keep = jnp.logical_and(keep, q_pos - k_pos < window)
+    return keep
+
+
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *rest,
-               scale, causal, block_k, segmented):
+               scale, causal, block_k, segmented, window=None):
     """dq pass: grid (bh, q_block, kv_block); dq accumulated in VMEM
     (v and do are [.., dv], q, k and dq [.., d]).
     ds = p * (dout.v^T - delta); dq = scale * ds @ k (FlashAttention-2
@@ -363,7 +402,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *rest,
                 jnp.int32, (bq, block_k), 0)
             k_pos = kv_i * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+            s = jnp.where(_sees(q_pos, k_pos, window), s, NEG_INF)
         if segmented:
             s = jnp.where(
                 sq_ref[0, 0][:, None] == sk_ref[0, 0][None, :], s, NEG_INF)
@@ -385,7 +424,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *rest,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *rest,
-                scale, causal, block_q, segmented):
+                scale, causal, block_q, segmented, window=None):
     """dk/dv pass: grid (bh, kv_block, q_block); dk [.., d] and dv
     [.., dv] accumulated in VMEM.
     dv = p^T @ dout; dk = scale * ds^T @ q."""
@@ -417,7 +456,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *rest,
                 jnp.int32, (bq, bk), 0)
             k_pos = kv_i * bk + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, bk), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+            s = jnp.where(_sees(q_pos, k_pos, window), s, NEG_INF)
         if segmented:
             s = jnp.where(
                 sq_ref[0, 0][:, None] == sk_ref[0, 0][None, :], s, NEG_INF)
@@ -443,7 +482,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *rest,
 
 
 def _flash_bwd_bhnd(q, k, v, out, lse, g, scale, causal, block_q, block_k,
-                    interpret, segs=None):
+                    interpret, segs=None, window=None):
     """Pallas backward: returns (dq, dk [BH, N, D], dv [BH, N, Dv]);
     ``out`` and ``g`` are [BH, N, Dv]."""
     bh, n, d = q.shape
@@ -479,7 +518,8 @@ def _flash_bwd_bhnd(q, k, v, out, lse, g, scale, causal, block_q, block_k,
         dq_args += [segs[:, None, :]] * 2
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          block_k=block_k, segmented=segmented),
+                          block_k=block_k, segmented=segmented,
+                          window=window),
         grid=(bh, n // block_q, kv_len // block_k),
         in_specs=dq_in_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
@@ -517,7 +557,8 @@ def _flash_bwd_bhnd(q, k, v, out, lse, g, scale, causal, block_q, block_k,
         dkv_args += [segs[:, None, :]] * 2
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, segmented=segmented),
+                          block_q=block_q, segmented=segmented,
+                          window=window),
         grid=(bh, kv_len // block_k, n // block_q),
         in_specs=dkv_in_specs,
         out_specs=[
@@ -542,7 +583,7 @@ def _flash_bwd_bhnd(q, k, v, out, lse, g, scale, causal, block_q, block_k,
     return dq, dk, dv
 
 
-def _reference_attention(q, k, v, scale, causal, segs=None):
+def _reference_attention(q, k, v, scale, causal, segs=None, window=None):
     """[BH, N, D] (v and the result [BH, N, Dv]) fp32-statistics
     attention — the VJP recompute form.
 
@@ -557,7 +598,7 @@ def _reference_attention(q, k, v, scale, causal, segs=None):
         n, m = logits.shape[-2], logits.shape[-1]
         q_pos = jax.lax.broadcasted_iota(jnp.int32, (n, m), 0)
         k_pos = jax.lax.broadcasted_iota(jnp.int32, (n, m), 1)
-        logits = jnp.where(q_pos >= k_pos, logits, NEG_INF)
+        logits = jnp.where(_sees(q_pos, k_pos, window), logits, NEG_INF)
     if segs is not None:
         logits = jnp.where(segs[:, :, None] == segs[:, None, :], logits,
                            NEG_INF)
@@ -571,9 +612,9 @@ def _reference_attention(q, k, v, scale, causal, segs=None):
 FLASH_SAVED_NAMES = ("flash_out", "flash_lse")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
 def _flash_carry(q, k, v, segs, out, lse, scale, causal, block_q, block_k,
-                 interpret):
+                 interpret, window):
     """`out`, with the blocked backward as its VJP in q, k, v. The forward
     kernel has already run (_flash_core); this only carries its outputs to
     the backward kernels as residuals."""
@@ -581,15 +622,17 @@ def _flash_carry(q, k, v, segs, out, lse, scale, causal, block_q, block_k,
 
 
 def _flash_carry_fwd(q, k, v, segs, out, lse, scale, causal, block_q,
-                     block_k, interpret):
+                     block_k, interpret, window):
     return out, (q, k, v, segs, out, lse)
 
 
-def _flash_carry_bwd(scale, causal, block_q, block_k, interpret, res, g):
+def _flash_carry_bwd(scale, causal, block_q, block_k, interpret, window,
+                     res, g):
     q, k, v, segs, out, lse = res
     # Pallas blocked backward: O(N) memory, never materializes [N, N]
     dq, dk, dv = _flash_bwd_bhnd(q, k, v, out, lse, g, scale, causal,
-                                 block_q, block_k, interpret, segs=segs)
+                                 block_q, block_k, interpret, segs=segs,
+                                 window=window)
     dsegs = (None if segs is None
              else jnp.zeros(segs.shape, jax.dtypes.float0))
     # out and lse came from stop_gradient-ed operands: no cotangent (None)
@@ -601,7 +644,7 @@ _flash_carry.defvjp(_flash_carry_fwd, _flash_carry_bwd)
 
 
 def _flash_core(q, k, v, segs, scale, causal, block_q, block_k,
-                interpret, fwd_tiles=None):
+                interpret, fwd_tiles=None, window=None):
     """[BH, N, D] attention: one forward-kernel call, then _flash_carry.
     ``block_q`` / ``block_k`` are the backward kernels' tile and, unless
     ``fwd_tiles`` (block_q, block_k, block_kv) names another, the
@@ -617,15 +660,17 @@ def _flash_core(q, k, v, segs, scale, causal, block_q, block_k,
     out, lse = _flash_fwd_bhnd(
         jax.lax.stop_gradient(q), jax.lax.stop_gradient(k),
         jax.lax.stop_gradient(v), scale, causal, fwd_q, fwd_k,
-        interpret, segs=segs, block_kv=fwd_kv)
+        interpret, segs=segs, block_kv=fwd_kv,
+        **({} if window is None else {"window": window}))
     out = checkpoint_name(out, FLASH_SAVED_NAMES[0])
     lse = checkpoint_name(lse, FLASH_SAVED_NAMES[1])
     return _flash_carry(q, k, v, segs, out, lse, scale, causal, block_q,
-                        block_k, interpret)
+                        block_k, interpret, window)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
-                    block_k=None, interpret=None, segment_ids=None):
+                    block_k=None, interpret=None, segment_ids=None,
+                    window=None):
     """q,k: [B, N, H, D], v: [B, N, H, Dv] jax arrays (Dv is D unless v
     has a head dim of its own). Returns [B, N, H, Dv].
 
@@ -636,12 +681,16 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
 
     segment_ids: optional [B, N] int32 — ragged/packed attention
     (serving varlen batching): tokens attend only within their segment,
-    composable with `causal` (packed causal LM)."""
+    composable with `causal` (packed causal LM).
+
+    window: causal only; query i sees keys i - window + 1 .. i."""
     b, n, h, d = q.shape
     kv_n = k.shape[1]
     dv = v.shape[3]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
+    if window is not None and not causal:
+        raise ValueError("flash_attention: a window is a causal band")
     interpret = resolve_interpret(interpret)
     chosen = block_q is None and block_k is None
     block_q = min(block_q or DEFAULT_BLOCK_Q, n)
@@ -670,13 +719,15 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                 jnp.swapaxes(q, 1, 2).reshape(b * h, n, d),
                 jnp.swapaxes(k, 1, 2).reshape(b * h, kv_n, d),
                 jnp.swapaxes(v, 1, 2).reshape(b * h, kv_n, dv),
-                scale, causal, segs=segs).reshape(b, h, n, dv), 1, 2)
+                scale, causal, segs=segs, window=window).reshape(
+                    b, h, n, dv), 1, 2)
 
     def fold(x):
         return jnp.swapaxes(x, 1, 2).reshape(b * h, x.shape[1], x.shape[3])
 
     fwd_tiles = _fwd_tiles(n, kv_n, d, dv, q.dtype.itemsize,
-                           segmented=segs is not None) if chosen else None
+                           segmented=segs is not None,
+                           window=window) if chosen else None
     out = _flash_core(fold(q), fold(k), fold(v), segs, scale, causal,
-                      block_q, block_k, interpret, fwd_tiles)
+                      block_q, block_k, interpret, fwd_tiles, window=window)
     return jnp.swapaxes(out.reshape(b, h, n, dv), 1, 2)

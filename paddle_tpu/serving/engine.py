@@ -182,7 +182,10 @@ class Engine:
                  "a slot's recurrent state is rebuilt by a whole prefill"),
                 (self.cache.has_latent, "latent_pages",
                  "a latent page is written by a whole prefill and a "
-                 "decode step")):
+                 "decode step"),
+                (self.cache.has_window, "window_ring",
+                 "a ring is filled by a whole prefill and a decode "
+                 "step")):
             for flag in ("FLAGS_serving_prefix_cache",
                          "FLAGS_serving_chunked_prefill",
                          "FLAGS_serving_quant_kv"):
@@ -216,6 +219,11 @@ class Engine:
         self.metrics = EngineMetrics(max_slots)
         self.metrics.moe_experts_held = int(
             getattr(model, "moe_experts_held", 0))
+        # a model that runs part of a prefill on fewer rows (YOCO) says
+        # how many a bucket; [prefills, prompt rows, rows run past the
+        # self-decoder] for stats()["yoco"]
+        self._yoco = ([0, 0, 0] if getattr(model, "yoco_rows", None)
+                      is not None else None)
         # memory plane (monitor/memory.py, FLAGS_monitor_memory),
         # LATCHED HERE like the tier-2 flags: the step hot path only
         # ever checks the handle. None = flags-off, bit-identical.
@@ -604,6 +612,14 @@ class Engine:
             out["latent"] = dict(
                 self.cache.latent_stats(),
                 cached_tokens=self.metrics.live_tokens_mean())
+        out["window"] = (self.cache.window_stats()
+                         if self.cache.has_window else None)
+        out["yoco"] = None
+        if self._yoco is not None and self._yoco[0]:
+            n, prompt_rows, cross_rows = self._yoco
+            out["yoco"] = {"prefills": n,
+                           "prompt_rows_per_prefill": prompt_rows / n,
+                           "cross_rows_per_prefill": cross_rows / n}
         out["ssm"] = None
         ssm_layers = getattr(self.model, "ssm_layers", 0)
         if ssm_layers:
@@ -804,6 +820,10 @@ class Engine:
             self.cache.seq_lens[slot] = L
             self.metrics.on_prefill_run()
             self.metrics.on_prefill_done(t1 - t0, L, P)
+            if self._yoco is not None:
+                self._yoco[0] += 1
+                self._yoco[1] += L
+                self._yoco[2] += self.model.yoco_rows.get(P, P)
             if self.prefix_cache is not None:
                 # publish the freshly-computed prompt pages immediately
                 # — the next queued request sharing this prompt head

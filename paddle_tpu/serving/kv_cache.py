@@ -41,6 +41,22 @@ release and preemption do to it:
     that is a layer of its own, not the second half of one): no pool
     (``None``, an empty node of the pools' tree), nothing to grow,
     release or rebuild, and a hook the layer never calls.
+``WindowRing`` (kind ``window_ring``)
+    window attention's K/V: a slot keeps its last ``window`` rows in a
+    ring, position p in row ``p mod window``, heads side by side on the
+    lanes. The ring is ``window / block_size`` pages of its own pool,
+    slot s's from page ``s * window / block_size`` on, so it never
+    grows and takes nothing from the allocator; a prefill fills it from
+    the prompt's last ``window`` rows, a decode step overwrites the
+    oldest row, and release only stops counting it as held (the next
+    prefill of the slot overwrites it whole). Attention reads a ring's
+    valid rows in row order, not position order: with no positional
+    rotation the order of the keys is nothing to a softmax.
+``SharedPages`` (kind ``shared_pages``)
+    a layer that reads another layer's pages and owns none (a cross-
+    decoder's attention over the K/V one full-attention layer wrote): no
+    pool, and the hook of a layer that keeps nothing; the model hands
+    the layer the ``source`` layer's view after that layer's write.
 
 The rest of this docstring is the kv_pages kind.
 
@@ -94,11 +110,15 @@ TRASH_BLOCK = 0
 
 
 class KVPages(NamedTuple):
-    """One layer's entry of a cache spec, kind ``kv_pages``."""
+    """One layer's entry of a cache spec, kind ``kv_pages``. ``flat``:
+    a page is [block_size, num_kv_heads * head_dim], every head of a
+    token side by side on the lanes (what ``diff_decode`` reads), not
+    [block_size, num_kv_heads, head_dim]."""
 
     num_kv_heads: int
     head_dim: int
     dtype: str = "float32"
+    flat: bool = False
     kind = "kv_pages"
 
 
@@ -123,6 +143,33 @@ class NoCache(NamedTuple):
     """One layer's entry of a cache spec, kind ``nothing``."""
 
     kind = "nothing"
+
+
+class WindowRing(NamedTuple):
+    """One layer's entry of a cache spec, kind ``window_ring``: a slot's
+    last ``window`` K/V rows."""
+
+    window: int
+    num_kv_heads: int
+    head_dim: int
+    dtype: str = "float32"
+    kind = "window_ring"
+
+
+class SharedPages(NamedTuple):
+    """One layer's entry of a cache spec, kind ``shared_pages``: it reads
+    the pages of layer ``source``."""
+
+    source: int
+    kind = "shared_pages"
+
+
+class RingPool(NamedTuple):
+    """One window layer's rings: k/v [max_slots * window / block_size,
+    block_size, num_kv_heads * head_dim]."""
+
+    k: "object"
+    v: "object"
 
 
 class LatentPool(NamedTuple):
@@ -238,24 +285,37 @@ class PagedKVCache:
             spec.kind == "slot_state" for spec in self.layers)
         self.has_latent = any(
             spec.kind == "latent_pages" for spec in self.layers)
+        self.has_window = any(
+            spec.kind == "window_ring" for spec in self.layers)
         self.num_blocks = num_blocks
         self.pools = [self._new_pool(spec) for spec in self.layers]
         self.allocator = BlockAllocator(num_blocks)
-        # with a slot_state layer a row's last column is the slot's own
-        # index: a prefill is told its page-table row and nothing else,
-        # and that is how it learns which slot's state to write
+        # with a slot_state or window_ring layer a row's last column is
+        # the slot's own index: a prefill is told its page-table row and
+        # nothing else, and that is how it learns which slot's state or
+        # ring to write
+        self._slot_column = self.has_slot_state or self.has_window
         self.block_tables = np.zeros(
-            (max_slots, max_blocks_per_slot + self.has_slot_state),
+            (max_slots, max_blocks_per_slot + self._slot_column),
             np.int32)
-        if self.has_slot_state:
+        if self._slot_column:
             self.block_tables[:, -1] = np.arange(max_slots)
         self.seq_lens = np.zeros((max_slots,), np.int32)
         self._slot_pages = [[] for _ in range(max_slots)]
         self.cow_clones = 0             # copy-on-write page splits
 
     def _new_pool(self, spec):
-        if spec.kind == "nothing":
+        if spec.kind in ("nothing", "shared_pages"):
             return None
+        if spec.kind == "window_ring":
+            if spec.window % self.block_size:
+                raise ValueError(
+                    "a window of %d rows is not whole pages of %d"
+                    % (spec.window, self.block_size))
+            ring = (self.max_slots * spec.window // self.block_size,
+                    self.block_size, spec.num_kv_heads * spec.head_dim)
+            return RingPool(jnp.zeros(ring, jnp.dtype(spec.dtype)),
+                            jnp.zeros(ring, jnp.dtype(spec.dtype)))
         if spec.kind == "slot_state":
             return {name: jnp.zeros((self.max_slots,) + tuple(shape),
                                     jnp.dtype(dtype))
@@ -264,6 +324,13 @@ class PagedKVCache:
             return LatentPool(jnp.zeros(
                 (self.num_blocks, self.block_size,
                  -(-spec.width // 128) * 128), jnp.dtype(spec.dtype)))
+        if spec.flat:
+            if self.quantized:
+                raise ValueError("flat K/V pages have no int8 form")
+            page = (self.num_blocks, self.block_size,
+                    spec.num_kv_heads * spec.head_dim)
+            return KVBlockPool(jnp.zeros(page, jnp.dtype(spec.dtype)),
+                               jnp.zeros(page, jnp.dtype(spec.dtype)))
         dt = jnp.dtype("int8") if self.quantized else jnp.dtype(spec.dtype)
         page = (self.num_blocks, self.block_size, spec.num_kv_heads,
                 spec.head_dim)
@@ -293,6 +360,19 @@ class PagedKVCache:
                 "row_bytes": pools[0].shape[2] * pools[0].dtype.itemsize,
                 "pool_bytes": sum(a.nbytes for a in pools)}
 
+    def window_stats(self):
+        """The window_ring side, for ``Engine.stats()["window"]``:
+        ``held_bytes`` is what the rings of the slots that hold a
+        sequence take."""
+        rings = [p for p in self.pools if isinstance(p, RingPool)]
+        pool_bytes = sum(p.k.nbytes + p.v.nbytes for p in rings)
+        slot_bytes = pool_bytes // self.max_slots
+        return {"layers": len(rings),
+                "window": next(spec.window for spec in self.layers
+                               if spec.kind == "window_ring"),
+                "slot_bytes": slot_bytes, "pool_bytes": pool_bytes,
+                "held_bytes": slot_bytes * int((self.seq_lens > 0).sum())}
+
     # -- the per-layer hooks of one compiled step (called in its trace)
 
     def prefill_views(self, pools, table_row, true_len):
@@ -301,14 +381,17 @@ class PagedKVCache:
                 return PagedPrefillView(p, table_row, self.block_size)
             if spec.kind == "latent_pages":
                 return LatentPrefillView(p, table_row, self.block_size)
-            if spec.kind == "nothing":
+            if spec.kind in ("nothing", "shared_pages"):
                 return NoView()
+            if spec.kind == "window_ring":
+                return RingPrefillView(p, table_row[-1], true_len,
+                                       spec.window, self.block_size)
             return StatePrefillView(p, table_row[-1], true_len)
 
         return [view(spec, p) for spec, p in zip(self.layers, pools)]
 
     def decode_views(self, pools, block_tables, seq_lens):
-        if self.has_slot_state:
+        if self._slot_column:
             # the page kernels take the page columns, not the slot's
             block_tables = block_tables[:, :self.max_blocks_per_slot]
 
@@ -319,8 +402,11 @@ class PagedKVCache:
             if spec.kind == "latent_pages":
                 return LatentDecodeView(p, block_tables, seq_lens,
                                         self.block_size)
-            if spec.kind == "nothing":
+            if spec.kind in ("nothing", "shared_pages"):
                 return NoView()
+            if spec.kind == "window_ring":
+                return RingDecodeView(p, seq_lens, spec.window,
+                                      self.block_size)
             return StateDecodeView(p, seq_lens > 0)
 
         return [view(spec, p) for spec, p in zip(self.layers, pools)]
@@ -493,10 +579,11 @@ class PagedPrefillView:
     rows past the true length attend only forward of real tokens, so
     real rows are exactly the unpadded computation."""
 
-    def __init__(self, pool, table_row, block_size):
+    def __init__(self, pool, table_row, block_size, fresh=None):
         self.pool = pool
         self.table_row = table_row            # [MB] int32, trash-padded
         self.block_size = block_size
+        self.fresh = fresh                    # (k, v) of ``update``
 
     def update_and_attend(self, q, k, v):
         from ..nn import functional as F
@@ -518,6 +605,32 @@ class PagedPrefillView:
                                              _warn_rect_causal=False)
         return out, PagedPrefillView(new_pool, self.table_row,
                                      self.block_size)
+
+    # differential attention (models/phi4flash.py): ``update`` then
+    # ``attend_diff``, the second by this layer and by every layer that
+    # reads its pages
+
+    def update(self, k, v):
+        """k, v [1, P, Hkv, D]: every position's row into the pages. ->
+        the view after the write, which keeps the fresh rows to attend
+        over."""
+        kv, vv = _raw(k), _raw(v)
+        p = kv.shape[1]
+        pos = jnp.arange(p)
+        row = self.pool.k.shape[2:]
+        return PagedPrefillView(
+            _write_pages(self.pool, self.table_row[pos // self.block_size],
+                         pos % self.block_size, kv[0].reshape((p,) + row),
+                         vv[0].reshape((p,) + row)),
+            self.table_row, self.block_size, (kv, vv))
+
+    def attend_diff(self, q, lam, scale, positions):
+        """q [1, R, H, D], the rows at ``positions`` [R] of the prompt,
+        over the fresh rows at or before each. -> [1, R, H / 2, 2 D]
+        float32."""
+        from .kernels.diff_attention import diff_prefill
+
+        return diff_prefill(_raw(q), *self.fresh, positions, lam, scale)
 
 
 class PagedDecodeView:
@@ -550,6 +663,31 @@ class PagedDecodeView:
                               v_scale=new_pool.v_scale)
         return Tensor(out[:, None]), PagedDecodeView(
             new_pool, self.block_tables, lens, self.block_size)
+
+    def update(self, k, v):
+        """k, v [S, 1, Hkv, D]: each slot's row at its length. -> the
+        view after the write, its lengths counting the new row."""
+        kv, vv = _raw(k), _raw(v)
+        s = kv.shape[0]
+        lens = self.seq_lens
+        row = self.pool.k.shape[2:]
+        return PagedDecodeView(
+            _write_pages(self.pool,
+                         self.block_tables[jnp.arange(s),
+                                           lens // self.block_size],
+                         lens % self.block_size,
+                         kv[:, 0].reshape((s,) + row),
+                         vv[:, 0].reshape((s,) + row)),
+            self.block_tables, lens + 1, self.block_size)
+
+    def attend_diff(self, q, lam, scale, positions=None):
+        """q [S, 1, H, D] over each slot's pages (``diff_decode``). ->
+        [S, 1, H / 2, 2 D] float32."""
+        from .kernels.diff_attention import diff_decode
+
+        return diff_decode(_raw(q)[:, 0], self.pool.k, self.pool.v,
+                           self.block_tables, self.seq_lens, lam,
+                           scale)[:, None]
 
 
 def _lane_padded(x, width):
@@ -698,6 +836,93 @@ class NoView:
     which reads every layer's ``pool`` back after a step."""
 
     pool = None
+
+
+class RingPrefillView:
+    """A window_ring layer's hook for single-request prefill ([1, P]
+    right-padded prompt of ``valid_len`` real rows): ``update`` fills
+    slot ``slot``'s ring from the last ``window`` real rows and keeps
+    the fresh rows; ``attend_diff`` is banded causal attention over
+    them."""
+
+    def __init__(self, pool, slot, valid_len, window, block_size,
+                 fresh=None):
+        self.pool = pool                      # RingPool
+        self.slot = slot                      # traced int32 scalar
+        self.valid_len = valid_len            # traced int32 scalar
+        self.window = window
+        self.block_size = block_size
+        self.fresh = fresh                    # (k, v) of ``update``
+
+    def update(self, k, v):
+        """k, v [1, P, Hkv, D]. Ring row r takes the last real position
+        p with p = r mod window (a row no position reached yet takes
+        row 0's, and is never read)."""
+        kv, vv = _raw(k), _raw(v)
+        w, bs = self.window, self.block_size
+        r = jnp.arange(w)
+        last = self.valid_len - 1
+        pos = jnp.maximum(last - jnp.mod(last - r, w), 0)
+        pages = self.slot * (w // bs) + r // bs
+        width = self.pool.k.shape[2]
+
+        def put(plane, rows):
+            return plane.at[pages, r % bs].set(
+                rows[0, pos].reshape(w, width).astype(plane.dtype))
+
+        return RingPrefillView(
+            RingPool(put(self.pool.k, kv), put(self.pool.v, vv)),
+            self.slot, self.valid_len, w, bs, (kv, vv))
+
+    def attend_diff(self, q, lam, scale, positions):
+        from .kernels.diff_attention import diff_prefill
+
+        return diff_prefill(_raw(q), *self.fresh, positions, lam, scale,
+                            window=self.window)
+
+
+class RingDecodeView:
+    """A window_ring layer's hook for the batched decode step: ``update``
+    writes each slot's new row over its oldest (row ``len mod window``),
+    ``attend_diff`` reads a slot's min(len + 1, window) valid rows through
+    ``diff_decode``, the ring's pages standing for a block table. An
+    idle slot writes its own ring, which its next prefill overwrites,
+    and reads nothing."""
+
+    def __init__(self, pool, seq_lens, window, block_size, active=None):
+        self.pool = pool                      # RingPool
+        self.seq_lens = seq_lens              # [S] int32
+        self.window = window
+        self.block_size = block_size
+        self.active = seq_lens > 0 if active is None else active
+
+    def update(self, k, v):
+        kv, vv = _raw(k), _raw(v)
+        s = kv.shape[0]
+        w, bs = self.window, self.block_size
+        row = self.seq_lens % w
+        pages = jnp.arange(s) * (w // bs) + row // bs
+        width = self.pool.k.shape[2]
+
+        def put(plane, rows):
+            return plane.at[pages, row % bs].set(
+                rows[:, 0].reshape(s, width).astype(plane.dtype))
+
+        return RingDecodeView(
+            RingPool(put(self.pool.k, kv), put(self.pool.v, vv)),
+            self.seq_lens + 1, w, bs, self.active)
+
+    def attend_diff(self, q, lam, scale, positions=None):
+        from .kernels.diff_attention import diff_decode
+
+        s = self.seq_lens.shape[0]
+        ppr = self.window // self.block_size
+        tables = (jnp.arange(s, dtype=jnp.int32)[:, None] * ppr
+                  + jnp.arange(ppr, dtype=jnp.int32)[None, :])
+        lens = jnp.where(self.active,
+                         jnp.minimum(self.seq_lens, self.window), 0)
+        return diff_decode(_raw(q)[:, 0], self.pool.k, self.pool.v, tables,
+                           lens, lam, scale)[:, None]
 
 
 class StatePrefillView:

@@ -210,6 +210,12 @@ def _gigachat3_5_programs():
         m.GigaChat35ForCausalLM(m.GigaChat35Config.tiny()))
 
 
+def _phi4flash_programs():
+    from paddle_tpu.models import phi4flash as m
+
+    return _engine_programs(m.Phi4FlashForCausalLM(m.Phi4FlashConfig.tiny()))
+
+
 FAMILIES = {
     "llama": (_llama_programs, {"embed", "attn", "mlp", "lm_head"}),
     "llama_train": (_llama_train_program,
@@ -222,6 +228,8 @@ FAMILIES = {
                    {"embed", "attn", "ssm", "moe", "lm_head"}),
     "gigachat3_5": (_gigachat3_5_programs,
                     {"embed", "gdn", "mla", "mlp", "moe", "lm_head"}),
+    "phi4flash": (_phi4flash_programs,
+                  {"embed", "attn", "ssm", "mlp", "lm_head"}),
 }
 _compiled = {}
 
@@ -238,6 +246,7 @@ def _program(family, program):
     ("deepseek_v2", "decode"), ("deepseek_v2", "prefill"),
     ("nemotron_h", "decode"), ("nemotron_h", "prefill"),
     ("gigachat3_5", "decode"), ("gigachat3_5", "prefill"),
+    ("phi4flash", "decode"), ("phi4flash", "prefill"),
 ])
 def test_every_named_instruction_of_a_compiled_step_has_a_scope(
         family, program):

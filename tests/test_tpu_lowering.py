@@ -38,6 +38,8 @@ from paddle_tpu.parallel import moe
 pa = importlib.import_module("paddle_tpu.serving.kernels.paged_attention")
 mla = importlib.import_module("paddle_tpu.serving.kernels.mla_attention")
 ssm = importlib.import_module("paddle_tpu.serving.kernels.ssm")
+da = importlib.import_module("paddle_tpu.serving.kernels.diff_attention")
+sc = importlib.import_module("paddle_tpu.serving.kernels.selective_scan")
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 # the forward kernel's place in an instruction's ``op_name``, below the
@@ -84,6 +86,14 @@ GIGA_EXPERTS, GIGA_ROUTER, GIGA_HID, GIGA_WIDTH, GIGA_TOPK = (
 # largest bucket and one chunk
 GDN_SHAPES = (("qwen", 16, 32), ("gigachat", 32, 64))
 GDN_D, GDN_TOKENS = 128, (8192, 64)
+# phi4flash-mathreason-backlog: 160 slots; 40 query heads over 20 KV
+# heads of 64, a page [16, 1280] (every head of a token on the lanes),
+# 384 pages a slot of a 34,000-page pool, and a 512-row ring a slot of
+# each window layer (32 pages); Mamba-1 over 5120 channels with a state
+# of 16; prefill buckets up to 2048, window 512
+# (benchmark/traffic/mathreason-backlog.json)
+PHI_S, PHI_NB, PHI_MB, PHI_H, PHI_HKV, PHI_D = 160, 34000, 384, 40, 20, 64
+PHI_WINDOW, PHI_C, PHI_N = 512, 5120, 16
 
 
 def _flash(dtype, d, segmented=False):
@@ -271,6 +281,38 @@ def _cases():
                  ((1, tokens, hv, GDN_D), F32), ((1, tokens, hv), F32),
                  ((1, tokens, hv), F32), ((1, hv, GDN_D, GDN_D), F32)],
                 {"gdn_chunked"}))
+    for name, nb, mb in (
+            ("diff_decode_bf16_phi4flash_pages", PHI_NB, PHI_MB),
+            ("diff_decode_bf16_phi4flash_rings",
+             PHI_S * PHI_WINDOW // BS, PHI_WINDOW // BS)):
+        pool = ((nb, BS, PHI_HKV * PHI_D), BF16)
+        cases.append((
+            name,
+            lambda q, k, v, bt, ln, lam: da.diff_decode_kernel(
+                q, k, v, bt, ln, lam, interpret=False),
+            [((PHI_S, PHI_H, PHI_D), BF16), pool, pool,
+             ((PHI_S, mb), I32), ((PHI_S,), I32), ((), F32)],
+            {"diff_decode"}))
+    for tokens in (2048, 64):
+        rows = ((1, tokens, PHI_C), BF16)
+        cases.append((
+            "selective_scan_phi4flash_%d" % tokens,
+            lambda x, dt, a, b, c, d: sc.selective_scan_kernel(
+                x, dt, a, b, c, d, interpret=False),
+            [rows, ((1, tokens, PHI_C), F32), ((PHI_N, PHI_C), F32),
+             ((1, tokens, PHI_N), BF16), ((1, tokens, PHI_N), BF16),
+             ((PHI_C,), BF16)],
+            {"selective_scan"}))
+    # a window layer's prefill: one of its two maps, 20 query pairs over
+    # a 128-wide pair of values, banded to 512 keys
+    qk = ((1, 2048, PHI_H // 2, PHI_D), BF16)
+    cases.append((
+        "flash_bf16_d64_v128_window_fwd_2048",
+        lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
+                                           window=PHI_WINDOW,
+                                           interpret=False),
+        [qk, qk, ((1, 2048, PHI_H // 2, 2 * PHI_D), BF16)],
+        {"flash_fwd"}))
     for name, dtype in (("fused_ce_bf16", BF16), ("fused_ce_f32", F32)):
         args = [((T, HID), dtype), ((HID, V), dtype), ((T,), I32)]
 
