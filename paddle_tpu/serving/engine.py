@@ -34,14 +34,25 @@ object (``update_and_attend`` for pages, ``read``/``write`` for state)
 
 Greedy decoding (argmax, matching GenerationMixin.generate's
 ``do_sample=False`` semantics token-for-token) — the parity contract
-tests/test_serving.py pins. Driving loop is host-side: one device
-round-trip per decode step for the sampled tokens, which is what the
-lifecycle (EOS, admission, preemption) needs to see anyway.
+tests/test_serving.py pins. Driving loop is host-side, and ONE STEP
+AHEAD of the device: the sampled tokens stay on the device as the next
+step's input (a prefill writes its first token there itself), so
+``Engine.step()`` dispatches decode step N before it reads step N-1's
+tokens back, and the device always has the next program queued while
+the host accepts, schedules and admits. ``seq_lens`` advance when a row
+is dispatched; a request that finishes by length is known then and
+gives its slot back at once; one that finishes by EOS is known a step
+late, and its row in the step already in flight is discarded. Device
+order keeps slot and page reuse safe: a program dispatched later writes
+them later. While the poison quarantine holds requests the engine steps
+synchronously (each program read back right after its dispatch), and
+the chunked-prefill mixed step always does.
 """
 from __future__ import annotations
 
 import time
 import weakref
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -76,6 +87,19 @@ class DrainingError(AdmissionError):
     drain-and-reschedule building block."""
 
     reason = "draining"
+
+
+class _Program(NamedTuple):
+    """A compiled step dispatched and not read back yet."""
+
+    out: object         # its tokens (and expert counters), on the device
+    rows: tuple         # ((slot, request), ...) whose tokens it computes
+    # a prefill's (prompt tokens, bucket, tokens fed, dispatch stamp);
+    # None for a decode step
+    prefill: tuple = None
+    live_tokens: int = 0    # a decode step's cached tokens (latent cache)
+    step: int = None        # a decode step's number, as its span has it
+
 
 # watchdog heartbeat (monitor/watchdog.py): every engine iteration runs
 # inside a busy bracket, so a scheduler deadlock or a hung decode
@@ -246,6 +270,7 @@ class Engine:
         self._state_vals = list(values)
         # everything lives where the weights live: one device
         device = min(values[0].devices(), key=lambda d: d.id)
+        self._device = device
         self._mesh = _mesh.Mesh(np.array([device]), ("dp",))
         # weight-only quantized decode (FLAGS_serving_quant_weights):
         # projection weights quantized ONCE here; _decode_vals is the
@@ -267,8 +292,12 @@ class Engine:
                     self._qw_dtypes[i] = val.dtype
                     self._decode_vals[i] = quantize_int8_weight(val)
         # slot_tokens[s]: last generated token, not yet written to KV —
-        # the next decode step's input for that slot
-        self._slot_tokens = np.zeros((max_slots,), np.int32)
+        # the next decode step's input for that slot. It lives on the
+        # device: a decode step's output is the next one's input, and a
+        # prefill writes its first token into it
+        self._slot_tokens = self._zero_tokens()
+        # programs dispatched and not read back yet, in dispatch order
+        self._inflight = []
         # donate_argnums=(1,): the KV pools are CARRIED state — every
         # step consumes the previous pools and returns the next, and
         # the caller rebinds self.cache.pools immediately — so the
@@ -285,15 +314,21 @@ class Engine:
             self._mixed = jax.jit(self._mixed_fn, donate_argnums=(1,))
         else:
             self._decode = jax.jit(self._decode_fn, donate_argnums=(1,))
+            # a prefill also takes the slot tokens and writes its first
+            # token there. They are not donated: they are the output of
+            # the decode step before it, which the host has yet to read
+            # back (a copy of max_slots int32 costs nothing)
             if self.prefix_cache is not None:
                 # cache-aware prefill: runs only the uncached suffix
                 # over the adopted pool history (hist == 0 on a miss),
                 # jitted per suffix-length bucket like _prefill was
-                self._suffix_prefill = jax.jit(self._suffix_prefill_fn,
-                                               donate_argnums=(1,))
+                self._suffix_prefill = jax.jit(
+                    self._writing_first_token(self._suffix_prefill_fn),
+                    donate_argnums=(1,))
             else:
-                self._prefill = jax.jit(self._prefill_fn,
-                                        donate_argnums=(1,))
+                self._prefill = jax.jit(
+                    self._writing_first_token(self._prefill_fn),
+                    donate_argnums=(1,))
         self._mem = _monitor.memory.tracker(
             "serving", self._mem_components(),
             context_fn=self._mem_context)
@@ -459,11 +494,16 @@ class Engine:
         return req.id
 
     def has_work(self):
-        return self.scheduler.has_work()
+        return self.scheduler.has_work() or bool(self._inflight)
 
     def step(self):
-        """One engine iteration: admit+prefill, grow pages (preempting
-        on exhaustion), one batched decode step. Returns has_work()."""
+        """One engine iteration, in this order: (a) grow pages
+        (preempting on exhaustion) and dispatch decode step N; (b) read
+        back step N-1's tokens and those of the prefills the previous
+        call dispatched, which ran before N on the device; (c) accept
+        them; (d) expire, admit and dispatch prefills into the slots now
+        free. Under chunked prefill: admit, grow, one mixed step, read
+        back. Returns has_work()."""
         with _HB_SERVE.busy("serving.step"):
             try:
                 # engine-level injection site: a fault here models a
@@ -492,32 +532,30 @@ class Engine:
                 # the engine's own account (serving/metrics.py): one
                 # now() at each phase boundary, a span between them
                 self.metrics.on_step_begin()
-                self._scheduling(self._expire_waiting)
-                self._timed_phase(prof, "prefill",
-                                  self._admit_and_prefill)
-                self._scheduling(self._grow_or_preempt)
-                # perf attribution (FLAGS_perf_attribution): KV-page
-                # occupancy + goodput per engine iteration, sampled at
-                # the step's high-water point (pages grown, nothing
-                # released yet) — pure host arithmetic, but still
-                # flag-gated so the default serving hot path does no
-                # new work
-                if _monitor.is_enabled() \
-                        and _monitor.perf.attribution_enabled():
-                    alloc = self.cache.allocator
-                    self.metrics.on_kv_occupancy(
-                        1.0 - alloc.free_blocks
-                        / max(alloc.usable_blocks, 1))
                 if self.chunked_prefill:
+                    self._scheduling(self._expire_waiting)
+                    self._timed_phase(prof, "prefill",
+                                      self._admit_and_prefill)
+                    self._scheduling(self._grow_or_preempt)
+                    self._note_kv_occupancy()
                     rows = self.scheduler.occupied()
                     if rows:
                         self._timed_phase(prof, "decode",
                                           self._mixed_once, rows)
                 else:
+                    self._scheduling(self._grow_or_preempt)
+                    self._note_kv_occupancy()
                     active = self.scheduler.active()
-                    if active:
-                        self._timed_phase(prof, "decode",
-                                          self._decode_once, active)
+                    went = bool(active) and self._timed_phase(
+                        prof, "decode", self._dispatch_decode, active)
+                    # the programs before the step just dispatched ran
+                    # before it on the device; stepping synchronously,
+                    # it is read back too
+                    ahead = went and not self._quarantine
+                    self._collect(len(self._inflight) - ahead)
+                    self._scheduling(self._expire_waiting)
+                    self._timed_phase(prof, "prefill",
+                                      self._admit_and_prefill)
                 self.metrics.on_step_end()
                 if self.prefix_cache is not None:
                     self.metrics.on_prefix_stats(
@@ -534,12 +572,23 @@ class Engine:
                     prof.step_abort()
                 raise
             if prof is not None:
-                # no block arg: the decode path already synced the
-                # step's outputs to host numpy — the iteration wall IS
-                # the host-exposed time; gap covers the scheduler idle
+                # no block arg: the iteration synced the previous step's
+                # outputs to host numpy — the iteration wall IS the
+                # host-exposed time; gap covers the scheduler idle
                 # between iterations
                 prof.step_end(_pt0, time.perf_counter())
         return self.has_work()
+
+    def _note_kv_occupancy(self):
+        """Perf attribution (FLAGS_perf_attribution): KV-page
+        occupancy + goodput per engine iteration, sampled at the step's
+        high-water point (pages grown, nothing released yet) — pure host
+        arithmetic, but still flag-gated so the default serving hot path
+        does no new work."""
+        if _monitor.is_enabled() and _monitor.perf.attribution_enabled():
+            alloc = self.cache.allocator
+            self.metrics.on_kv_occupancy(
+                1.0 - alloc.free_blocks / max(alloc.usable_blocks, 1))
 
     def _scheduling(self, fn):
         """``fn()`` as scheduler and KV-allocator time: under the span
@@ -554,13 +603,14 @@ class Engine:
     def _timed_phase(self, prof, phase, fn, *args):
         """Run one step phase, feeding its host wall into the ptprof
         per-phase timers when the handle is latched (one call site per
-        phase instead of three copies of the stamp dance)."""
+        phase instead of three copies of the stamp dance). -> what
+        ``fn`` returns."""
         if prof is None:
-            fn(*args)
-            return
+            return fn(*args)
         t = time.perf_counter()
-        fn(*args)
+        out = fn(*args)
         prof.note_phase(phase, time.perf_counter() - t)
+        return out
 
     def run(self):
         """Drain all queued work; returns {request_id: generated tokens}."""
@@ -686,6 +736,10 @@ class Engine:
                 self._prefill_request(slot, req)
             except Exception as e:  # poison quarantine: the request's
                 self._fail_request(req, e)  # OWN step failed, not the engine
+                continue
+            if self._quarantine:
+                # stepping synchronously: the token on the host now
+                self._collect(len(self._inflight))
 
     def _admit_one(self):
         """The admission decision: (slot, request) of the next waiting
@@ -704,44 +758,51 @@ class Engine:
                     kv_pages_free=self.cache.allocator.free_blocks)
         return admitted
 
-    def _fail_request(self, req, exc):
+    def _fail_request(self, req, exc, lost=False):
         """Poison quarantine: one request's step raised — fail IT with
-        a terminal status and keep serving everyone else."""
-        if req.slot is not None:
+        a terminal status and keep serving everyone else. ``lost``: the
+        step failed on the device, so the pools it handed on are gone
+        whatever their buffers say."""
+        if req.slot is not None and self.scheduler.slots[req.slot] is req:
             self.scheduler.release(req)
+        req.slot = None
         req.close(RequestState.FAILED, "poison", error=exc)
         self._quarantine.discard(req.id)
         self.metrics.on_request_shed("poison")
         if self._replay is not None:
             self._replay.terminal(req)
-        self._recover_consumed_pools()
+        self._recover_consumed_pools(force=lost)
 
-    def _recover_consumed_pools(self):
+    def _recover_consumed_pools(self, force=False):
         """The donated-pools failure path: the compiled steps donate
         their input pools (``donate_argnums=(1,)``), so a step that
-        raises AFTER execution started leaves ``cache.pools`` pointing
-        at DELETED buffers — every slot's KV, not just the failing
-        request's, is gone. (A pre-dispatch failure — fault injection,
-        a trace-time error — never consumes anything and this is one
-        cheap liveness check.) Recovery is preempt-by-recompute for
-        every occupied slot over a fresh zeroed pool plane: recompute
-        re-prefills from host-side tokens deterministically, so
-        outputs stay bit-identical and a one-step transient cannot
-        become permanent engine death. The prefix cache is REBUILT,
-        not kept: its pages map into the dead pools, and the
-        keep-warm release path must not re-serve garbage KV — which
-        is also why the requeue below bypasses scheduler.release
-        (its insert would cache those pages)."""
-        if self.cache.pools_alive():
+        raises AFTER execution started leaves ``cache.pools`` pointing at
+        DELETED buffers — every slot's KV, not just the failing
+        request's, is gone. ``force``: a step failed on the device, and
+        every program queued behind it read its garbage, the slot tokens
+        too. (A pre-dispatch failure — fault injection, a trace-time
+        error — never consumes anything and this is one cheap liveness
+        check.) Recovery is preempt-by-recompute for every
+        occupied slot, and for every request whose last token was in a
+        program now dropped, over a fresh zeroed pool plane: recompute
+        re-prefills from host-side tokens deterministically, so outputs
+        stay bit-identical and a one-step transient cannot become
+        permanent engine death. The prefix cache is REBUILT, not kept:
+        its pages map into the dead pools, and the keep-warm release
+        path must not re-serve garbage KV — which is also why the
+        requeue below bypasses scheduler.release (its insert would
+        cache those pages)."""
+        if not force and self.cache.pools_alive():
             return
         from ..monitor.registry import warn_once
 
         warn_once(
             "serving.pools_consumed",
-            "paddle_tpu.serving: a compiled step failed after its "
-            "donated KV pools were consumed; resetting the pool "
-            "plane and requeueing every occupied slot "
+            "paddle_tpu.serving: a compiled step failed on the device or "
+            "after its donated KV pools were consumed; resetting the "
+            "pool plane and requeueing every occupied slot "
             "(preempt-by-recompute)")
+        self._drop_inflight()
         # reversed + requeue_front, the _on_decode_failure idiom:
         # appendleft in reverse slot order keeps the survivors'
         # re-admission strictly FCFS
@@ -750,19 +811,47 @@ class Engine:
                 continue
             self.cache.release_slot(slot)
             self.scheduler.slots[slot] = None
-            req.slot = None
-            req.state = RequestState.PREEMPTED
-            req.metrics.preemptions += 1
-            self.scheduler.requeue_front(req)
-            self.metrics.on_preemption()
+            self._requeue(req)
         if self.prefix_cache is not None:
             from .prefix_cache import RadixPrefixCache
 
             self.prefix_cache = RadixPrefixCache(self.cache)
             self.scheduler.prefix_cache = self.prefix_cache
         self.cache.reset_pools()
+        self._slot_tokens = self._zero_tokens()
+
+    def _requeue(self, req):
+        """Back to the queue's front, holding no slot, to resume by
+        recompute from the tokens on the host."""
+        req.slot = None
+        req.state = RequestState.PREEMPTED
+        req.metrics.preemptions += 1
+        self.scheduler.requeue_front(req)
+        self.metrics.on_preemption()
+
+    def _drop_inflight(self):
+        """Forget every program in flight: its tokens are never read.
+        A request whose last token was among them has given its slot
+        back already, so it is requeued here; the requests in slots are
+        the caller's."""
+        dropped, self._inflight = self._inflight, []
+        for program in dropped:
+            for slot, req in program.rows:
+                req.in_flight = 0
+                if req.slot == slot and not req.terminal \
+                        and self.scheduler.slots[slot] is not req:
+                    self._requeue(req)
+
+    def _zero_tokens(self):
+        """A fresh slot-token array on the engine's device."""
+        return jax.device_put(np.zeros((self.max_slots,), np.int32),
+                              self._device)
 
     def _prefill_request(self, slot, req):
+        """Dispatch the request's prefill into ``slot``. The program
+        writes the first token into the slot tokens on the device, so
+        the next decode step can take the row before the host has read
+        it; ``_collect`` reads it back later."""
         # per-request injection site: the poison-request model — an
         # error here is attributable to THIS request and fails only it
         if _fi.is_enabled():
@@ -794,8 +883,10 @@ class Engine:
             ids = np.zeros((1, P), np.int32)
             ids[0, :len(feed)] = feed
             with span("serving.upload"):
+                # a copy of the table row: the host grows it before the
+                # program has run
                 args = [jnp.asarray(ids),
-                        jnp.asarray(self.cache.block_tables[slot])]
+                        jnp.asarray(self.cache.block_tables[slot].copy())]
                 if hist is None:
                     fn = self._prefill
                 else:
@@ -803,41 +894,32 @@ class Engine:
                     args.append(jnp.asarray(hist, jnp.int32))
                 args.append(jnp.asarray(len(feed), jnp.int32))
             with span("serving.dispatch"):
-                tok, new_pools = self._run_eval(
-                    fn, self._state_vals, self.cache.pools, *args)
-            self.cache.pools = new_pools
-            with span("serving.readback"):
-                if self._moe_layers:
-                    tok = np.asarray(tok)
-                    self.metrics.on_moe_call(tok[1:], len(feed), P,
-                                             decode=False)
-                    tok = tok[0]
-                tok = int(tok)
-            # the token is on the host: the prefill's end, and the
-            # token's stamp
-            t1 = now()
-        with span("serving.accept"):
-            self.cache.seq_lens[slot] = L
-            self.metrics.on_prefill_run()
-            self.metrics.on_prefill_done(t1 - t0, L, P)
-            if self._yoco is not None:
-                self._yoco[0] += 1
-                self._yoco[1] += L
-                self._yoco[2] += self.model.yoco_rows.get(P, P)
-            if self.prefix_cache is not None:
-                # publish the freshly-computed prompt pages immediately
-                # — the next queued request sharing this prompt head
-                # admits against them, not against a finished-request
-                # race
-                self.prefix_cache.insert(
-                    tokens, self.cache.slot_pages(slot), L)
-            req.state = RequestState.DECODING
-            req.metrics.on_first_token(t1)
-            # decode phase opens BEFORE the first token is accepted: a
-            # max_new_tokens=1 request finishes inside _accept_token and
-            # its trace_finish must close the decode span, not prefill
-            req.trace_phase("decode", slot=slot)
-            self._accept_token(req, tok, t1)
+                out, self._slot_tokens, self.cache.pools = self._run_eval(
+                    fn, self._state_vals, self.cache.pools,
+                    self._slot_tokens, jnp.asarray(slot, jnp.int32), *args)
+        self.cache.seq_lens[slot] = L
+        req.state = RequestState.DECODING
+        req.in_flight += 1
+        if self.prefix_cache is not None:
+            # publish the prompt pages now — the next queued request
+            # sharing this prompt head admits against them, and its
+            # prefill runs after this one on the device
+            self.prefix_cache.insert(tokens, self.cache.slot_pages(slot),
+                                     L)
+        self._inflight.append(
+            _Program(out, ((slot, req),), (L, P, len(feed), t0)))
+        if req.remaining <= req.in_flight:
+            self._detach(req)
+
+    def _detach(self, req):
+        """``req``'s last token is in flight: its slot and pages go back
+        now, so an admission of this same iteration can take them (a
+        program dispatched later writes them later on the device), and
+        no step runs a row for it again. ``req.slot`` keeps the row its
+        token comes from until that token is on the host."""
+        slot = req.slot
+        self.scheduler.release(req)
+        req.slot = slot
 
     def _grow_or_preempt(self):
         """Every live row writes K/V this step — decode rows one
@@ -846,9 +928,11 @@ class Engine:
         splits a partially-shared prefix page before the first write).
         On pool exhaustion the ESCALATION ORDER is: (1) LRU-reclaim
         pages held only by the prefix cache — dropping cold cached
-        state costs nothing already-computed in flight; (2) preempt the
+        state costs nothing already-computed in flight; (2) read back
+        the programs in flight, so a victim resumes from every token it
+        has and what finished gives its pages back; (3) preempt the
         most recent other request (recompute-requeue) — now the LAST
-        resort, not the first; (3) shed the grower when every victim is
+        resort, not the first; (4) shed the grower when every victim is
         preemption-capped."""
         rows = (self.scheduler.occupied() if self.chunked_prefill
                 else self.scheduler.active())
@@ -879,6 +963,11 @@ class Engine:
                         - self.cache.allocator.free_blocks, 1)
                     if self.prefix_cache.reclaim(shortfall):
                         continue
+                if self._inflight:
+                    self._collect(len(self._inflight))
+                    if self.scheduler.slots[slot] is not req:
+                        break       # finished, or requeued by a failure
+                    continue
                 victim = self.scheduler.preempt_victim(
                     slot, self.max_preemptions,
                     include_prefill=self.chunked_prefill)
@@ -910,12 +999,148 @@ class Engine:
                         "preempt", victim=victim.id, grower=req.id,
                         kv_pages_free=self.cache.allocator.free_blocks)
 
+    def _dispatch_decode(self, rows):
+        """Dispatch one decode step over ``rows`` from the tables the
+        host holds, under the span ``serving.decode_step``: its tokens
+        stay on the device as the next step's input, and ``_collect``
+        reads them back later. Each row's ``seq_len`` advances now.
+        -> whether it went (False when it raised and
+        ``_on_decode_failure`` has dealt with it)."""
+        ahead = any(p.prefill is None for p in self._inflight)
+        step = self.metrics.decode_dispatches
+        with span("serving.decode_step", step=step, rows=len(rows)):
+            try:
+                # batched injection site: a decode failure is NOT
+                # attributable to one request — the quarantine
+                # serializes the batch until it is
+                if _fi.is_enabled():
+                    _fi.fire("serving.decode", batch=len(rows))
+                t0 = now()
+                with span("serving.upload"):
+                    # copies: the host advances seq_lens and grows the
+                    # tables before the step has run
+                    tables = jnp.asarray(self.cache.block_tables.copy())
+                    lens = jnp.asarray(self.cache.seq_lens.copy())
+                t1 = now()
+                with span("serving.dispatch"):
+                    out, pools = self._run_eval(
+                        self._decode, self._decode_vals, self.cache.pools,
+                        self._slot_tokens, tables, lens)
+                    # behind a model's tokens ride its expert counters
+                    tokens = (out[:self.max_slots] if self._moe_layers
+                              else out)
+            except Exception as e:  # poison quarantine
+                # the rows requeued there resume from every token they
+                # have: the programs before this one are read first
+                self._collect(len(self._inflight))
+                self._on_decode_failure(rows, e)
+                return False
+            t2 = now()
+        self.cache.pools = pools
+        self._slot_tokens = tokens
+        phase_s = self.metrics.phase_s
+        phase_s["upload"] += t1 - t0
+        phase_s["dispatch"] += t2 - t1
+        live = int(self.cache.seq_lens.sum()) if self.cache.has_latent \
+            else 0
+        self._note_quant_step()
+        for slot, req in rows:
+            # the input token's K/V row lands at position seq_len
+            self.cache.seq_lens[slot] += 1
+            req.in_flight += 1
+        self._inflight.append(_Program(out, tuple(rows), None, live, step))
+        self.metrics.on_decode_dispatch(ahead)
+        for slot, req in rows:
+            if req.remaining <= req.in_flight:
+                self._detach(req)
+        return True
+
+    def _collect(self, n):
+        """Read back and accept the first ``n`` programs in flight, in
+        the order they were dispatched (the device's): each program's
+        tokens are stamped when they reach the host."""
+        phase_s = self.metrics.phase_s
+        for _ in range(n):
+            if not self._inflight:
+                return
+            program = self._inflight.pop(0)
+            names = ({"step": program.step} if program.prefill is None
+                     else {"request": program.rows[0][1].id})
+            t0 = now()
+            try:
+                with span("serving.readback", **names):
+                    host = self._readback(program.out)
+            except Exception as e:
+                self._on_readback_failure(program, e)
+                return
+            t = now()
+            phase_s["readback"] += t - t0
+            with span("serving.accept"):
+                if program.prefill is None:
+                    self._accept_decode(program, host, t)
+                else:
+                    self._accept_prefill(program, host, t)
+            phase_s["accept"] += now() - t
+
+    @staticmethod
+    def _readback(out):
+        """A program's tokens on the host: waits for the device."""
+        return np.asarray(out)
+
+    def _accept_decode(self, program, out, t):
+        rows = program.rows
+        self.metrics.on_decode_step(len(rows), program.live_tokens)
+        if self._moe_layers:
+            self.metrics.on_moe_call(out[self.max_slots:], len(rows),
+                                     self.max_slots, decode=True)
+        for slot, req in rows:
+            req.in_flight -= 1
+            if req.terminal:
+                # finished by an EOS read after this step went out
+                self.metrics.on_discarded_row()
+                continue
+            self._accept_token(req, int(out[slot]), t)
+
+    def _accept_prefill(self, program, out, t):
+        (slot, req), = program.rows
+        L, P, fed, t0 = program.prefill
+        req.in_flight -= 1
+        tok = out
+        if self._moe_layers:
+            self.metrics.on_moe_call(out[1:], fed, P, decode=False)
+            tok = out[0]
+        self.metrics.on_prefill_run()
+        # from building the ids to the token on the host
+        self.metrics.on_prefill_done(t - t0, L, P)
+        if self._yoco is not None:
+            self._yoco[0] += 1
+            self._yoco[1] += L
+            self._yoco[2] += self.model.yoco_rows.get(P, P)
+        req.metrics.on_first_token(t)
+        # decode phase opens BEFORE the first token is accepted: a
+        # max_new_tokens=1 request finishes inside _accept_token and
+        # its trace_finish must close the decode span, not prefill
+        req.trace_phase("decode", slot=slot)
+        self._accept_token(req, int(tok), t)
+
+    def _on_readback_failure(self, program, exc):
+        """A program failed on the device. Every program dispatched
+        after it read its pools and slot tokens, so none of their
+        results stand: the failed program's rows take the path of a
+        failed dispatch, and everyone else recomputes over a fresh pool
+        plane."""
+        self._inflight.insert(0, program)
+        if program.prefill is None:
+            self._on_decode_failure(program.rows, exc, lost=True)
+        else:
+            self._fail_request(program.rows[0][1], exc, lost=True)
+
     def _batched_step(self, name, rows, fn, *host_args):
-        """One batched step through ``fn`` as the host lives it, under
-        the span ``name``: upload ``host_args``, dispatch, wait for the
-        tokens. -> (the tokens on the host, the stamp taken when they
-        got there), or None when the step raised and
-        ``_on_decode_failure`` has dealt with it."""
+        """One synchronous batched step through ``fn`` (the mixed step)
+        as the host lives it, under the span ``name``: upload
+        ``host_args``, dispatch, wait for the tokens. -> (the tokens on
+        the host, the stamp taken when they got there), or None when the
+        step raised and ``_on_decode_failure`` has dealt with it."""
         with span(name, step=self.metrics.decode_steps, rows=len(rows)):
             try:
                 # batched injection site: a decode failure is NOT
@@ -936,35 +1161,13 @@ class Engine:
             t2 = now()
             self.cache.pools = new_pools
             with span("serving.readback"):
-                out = np.asarray(next_toks)
+                out = self._readback(next_toks)
             t3 = now()
         phase_s = self.metrics.phase_s
         phase_s["upload"] += t1 - t0
         phase_s["dispatch"] += t2 - t1
         phase_s["readback"] += t3 - t2
         return out, t3
-
-    def _decode_once(self, active):
-        done = self._batched_step(
-            "serving.decode_step", active, self._decode,
-            self._slot_tokens, self.cache.block_tables,
-            self.cache.seq_lens)
-        if done is None:
-            return
-        out, t = done
-        with span("serving.accept"):
-            self.metrics.on_decode_step(
-                len(active), int(self.cache.seq_lens.sum())
-                if self.cache.has_latent else 0)
-            self._note_quant_step()
-            if self._moe_layers:
-                self.metrics.on_moe_call(out[self.max_slots:], len(active),
-                                         self.max_slots, decode=True)
-            for slot, req in active:
-                # the input token's K/V row landed at position seq_len
-                self.cache.seq_lens[slot] += 1
-                self._accept_token(req, int(out[slot]), t)
-        self.metrics.phase_s["accept"] += now() - t
 
     def _mixed_once(self, rows):
         """ONE mixed ragged step (chunked prefill): decode rows feed
@@ -985,7 +1188,7 @@ class Engine:
                 q_lens[slot] = n
                 chunk_rows += 1
             else:
-                tokens[slot, 0] = self._slot_tokens[slot]
+                tokens[slot, 0] = req.generated[-1]
                 q_lens[slot] = 1
         done = self._batched_step(
             "serving.mixed_step", rows, self._mixed, tokens,
@@ -1033,13 +1236,14 @@ class Engine:
             alloc.usable_blocks - alloc.free_blocks,
             read_pages * self._quant_page_bytes)
 
-    def _on_decode_failure(self, active, exc):
+    def _on_decode_failure(self, active, exc, lost=False):
         """A batched decode raised. With ONE active request the poison
         is named — fail it, keep the engine. With several, requeue them
         all (preempt-by-recompute keeps their output bit-identical) and
         enter serial quarantine: one request per batch until the set
         clears, so the next failure IS attributable. The engine never
-        dies for one request's exception.
+        dies for one request's exception. ``lost``: the step failed on
+        the device (``_recover_consumed_pools``).
 
         Cost, by design: every quarantined request runs solo to
         completion, so one transient batched failure serializes its
@@ -1057,31 +1261,30 @@ class Engine:
         # failure is)
         if self._mem is not None and _monitor.memory.looks_like_oom(exc):
             self._mem.write_postmortem(exc)
+        # rows whose request finished (or was requeued) since the step
+        # went out are no longer the step's to answer for
+        active = [(slot, req) for slot, req in active
+                  if req.slot == slot and not req.terminal]
         if len(active) == 1:
             _, req = active[0]
-            self._fail_request(req, exc)
+            self._fail_request(req, exc, lost)
             return
-        for slot, req in reversed(list(active)):
-            if self.scheduler.slots[slot] is not req:
-                continue
+        for slot, req in reversed(active):
             seq_len = int(self.cache.seq_lens[slot])
-            self.scheduler.release(req)
-            req.state = RequestState.PREEMPTED
-            req.metrics.preemptions += 1
-            self.scheduler.requeue_front(req)
+            if self.scheduler.slots[slot] is req:
+                self.scheduler.release(req)
+            self._requeue(req)
             self._quarantine.add(req.id)
-            self.metrics.on_preemption()
             if req.trace_id is not None:
                 req.trace_phase(
                     "preempted", seq_len=seq_len, quarantine=True,
                     slots_active=self.scheduler.slots_active())
-        self._recover_consumed_pools()
+        self._recover_consumed_pools(force=lost)
 
     def _accept_token(self, req, tok, t):
         """``t``: when the token reached the host, the stamp taken right
-        after its step's readback."""
+        after its program's readback."""
         req.generated.append(tok)
-        self._slot_tokens[req.slot] = tok
         self.metrics.on_output_token(req.metrics.on_token(t))
         done = (req.remaining <= 0
                 or (req.eos_token_id is not None
@@ -1099,7 +1302,11 @@ class Engine:
                                    - alloc.free_blocks),
                     slots_active=self.scheduler.slots_active())
         if done:
-            self.scheduler.release(req)
+            # an EOS finish still holds its slot; a finish by length
+            # gave it back when its last step went out
+            if self.scheduler.slots[req.slot] is req:
+                self.scheduler.release(req)
+            req.slot = None
             req.finish()
             self._quarantine.discard(req.id)   # survived serial decode
             self.metrics.on_request_finished(len(req.generated))
@@ -1175,19 +1382,22 @@ class Engine:
         steps[name] = artifact(jit_fn, raw_fn, args)
         if not self.chunked_prefill:
             P = self._bucket(8)
-            ids = jnp.zeros((1, P), jnp.int32)
-            row = jnp.asarray(self.cache.block_tables[0])
+            head = (self._state_vals, pools,
+                    jnp.zeros((self.max_slots,), jnp.int32),
+                    jnp.asarray(0, jnp.int32),
+                    jnp.zeros((1, P), jnp.int32),
+                    jnp.asarray(self.cache.block_tables[0]))
             if self.prefix_cache is not None:
                 steps["suffix_prefill"] = artifact(
-                    self._suffix_prefill, self._suffix_prefill_fn,
-                    (self._state_vals, pools, ids, row,
-                     jnp.asarray(0, jnp.int32),
-                     jnp.asarray(P, jnp.int32)))
+                    self._suffix_prefill,
+                    self._writing_first_token(self._suffix_prefill_fn),
+                    head + (jnp.asarray(0, jnp.int32),
+                            jnp.asarray(P, jnp.int32)))
             else:
                 steps["prefill"] = artifact(
-                    self._prefill, self._prefill_fn,
-                    (self._state_vals, pools, ids, row,
-                     jnp.asarray(P, jnp.int32)))
+                    self._prefill,
+                    self._writing_first_token(self._prefill_fn),
+                    head + (jnp.asarray(P, jnp.int32),))
         return {
             "kind": "serving",
             "params": param_census(zip(self._names, self._state_vals)),
@@ -1245,6 +1455,21 @@ class Engine:
         finally:
             if was_training:
                 self.model.train()
+
+    @staticmethod
+    def _writing_first_token(fn):
+        """``fn``, a prefill ``(state_vals, pools, *args) -> (out,
+        pools)``, as the program the engine runs: it also takes the slot
+        tokens and the slot, and writes the prompt's first token there
+        on the device -> (out, slot tokens, pools)."""
+        def prefill(state_vals, pools, slot_tokens, slot, *args):
+            out, pools = fn(state_vals, pools, *args)
+            with jax.named_scope("lm_head"):
+                slot_tokens = slot_tokens.at[slot].set(
+                    jnp.reshape(out, (-1,))[0])
+            return out, slot_tokens, pools
+
+        return prefill
 
     def _prefill_fn(self, state_vals, pools, ids, table_row, true_len):
         from ..core.dispatch import no_grad

@@ -30,18 +30,29 @@ The engine's own account of a step (always on; ``Engine.step()`` takes
 one ``now()`` at each phase boundary and keeps the rows in bounded rings
 here, ``to_dict()`` reduces them when asked):
   host_ms        {schedule, upload, dispatch, readback, accept}: median
-      milliseconds of each host phase over the recent steps in which a
-      decode ran and no prefill did; the device works under ``readback``
-      and waits under the other four
+      milliseconds of each host phase over the recent steps that read
+      back a decode step and no prefill; ``readback`` is the host
+      waiting for the device, and while the engine runs ahead (below)
+      the device works under the other four as well
   recent_steps   how many steps those medians are over
   prefill_ms     median milliseconds of one prefill, from building its
-      ids to its token on the host, over the recent prefills
+      ids to its token on the host, over the recent prefills: with the
+      engine ahead, that includes the rest of the decode step queued on
+      the device before it, since its token is read back in the next
+      ``Engine.step()``
   itl_ms         {p50, p95} of the time between consecutive output
-      tokens of one request, each stamped when its step's tokens reached
-      the host (a prefill's token and the same step's first decode token
-      get their own two stamps), over the tokens of the recent steps: a
-      step leaves one row of (gap, tokens that had it), so the ring
-      covers the same stretch of time whatever the number of slots
+      tokens of one request, each stamped when its program's tokens
+      reached the host (a prefill's token and the request's first decode
+      token get their own two stamps), over the tokens of the recent
+      steps: a step leaves one row of (gap, tokens that had it), so the
+      ring covers the same stretch of time whatever the number of slots
+  ahead          {steps, ahead, discarded_rows, share}: ``steps`` decode
+      steps dispatched, of which ``ahead`` went while the tokens of the
+      step before were still on the device, both over the recent steps,
+      ``share`` = 100 x ahead / steps; ``discarded_rows`` counts, since
+      construction, rows a step computed for a request that had already
+      finished by EOS when they were read back. ``None`` before the
+      first decode step
   moe            for a model that declares expert layers
       (``model.moe_layers``): the counters every compiled step hands
       back behind its tokens, a layer at a time (pairs routed to the
@@ -73,10 +84,12 @@ Spans: ``span(name, **meta)`` IS ``jax.profiler.TraceAnnotation`` — a
 span lands in the profiler's trace, on the device trace's clock, and
 nowhere else; with no profiler session it costs under a microsecond.
 The engine opens eight names at the same boundaries as the stamps:
-``serving.schedule``, ``serving.prefill``, ``serving.decode_step`` /
-``serving.mixed_step`` (each ends after its readback), their children
-``serving.upload``, ``serving.dispatch``, ``serving.readback``, and
-``serving.accept``.
+``serving.schedule``, ``serving.prefill`` and ``serving.decode_step``
+(each ends after its dispatch; its tokens are read back under
+``serving.readback``, named by the same ``request=`` or ``step=``, in
+the next ``Engine.step()``), ``serving.mixed_step`` (ends after its
+readback), their children ``serving.upload``, ``serving.dispatch`` (and
+the mixed step's ``serving.readback``), and ``serving.accept``.
 """
 from __future__ import annotations
 
@@ -396,6 +409,11 @@ class EngineMetrics:
         # (real rows, rows run, int32 [layers, 4] counters, decode step?)
         self.moe_calls = collections.deque(maxlen=MOE_RING)
         self.moe_experts_held = 0
+        # one bool a dispatched decode step: was the step before it
+        # still unread?
+        self.ahead = collections.deque(maxlen=STEP_RING)
+        self.decode_dispatches = 0
+        self.discarded_rows = 0
 
     # -- engine hooks (mirror every sample into the shared registry) ---
 
@@ -566,6 +584,26 @@ class EngineMetrics:
                       for rows, rows_run, c, decode in calls],
         }
 
+    def on_decode_dispatch(self, ahead):
+        """A decode step went to the device; ``ahead``: the tokens of
+        the step before it were not on the host yet."""
+        self.decode_dispatches += 1
+        self.ahead.append(bool(ahead))
+
+    def on_discarded_row(self):
+        """A step's row was read back for a request that had already
+        finished by EOS: computed, and thrown away."""
+        self.discarded_rows += 1
+
+    def _ahead_dict(self):
+        ring = list(self.ahead)
+        if not ring:
+            return None
+        ahead = sum(ring)
+        return {"steps": len(ring), "ahead": ahead,
+                "discarded_rows": self.discarded_rows,
+                "share": 100.0 * ahead / len(ring)}
+
     def on_decode_compile(self):
         self.decode_compiles += 1
         _COMPILES.labels(fn="decode").inc()
@@ -684,4 +722,5 @@ class EngineMetrics:
                         "p95": 1e3 * _weighted_percentile(gaps, 0.95)}
                        if gaps else None),
             "moe": self._moe_dict(),
+            "ahead": self._ahead_dict(),
         }

@@ -91,6 +91,10 @@ class Request:
         # at every (re-)admission.
         self.cached_tokens = 0
         self.prefill_pos = 0
+        # tokens of programs dispatched and not read back yet: the
+        # engine runs one step ahead of the host, so a row's next step
+        # is dispatched while its last token is still on the device
+        self.in_flight = 0
 
     @property
     def resume_tokens(self):
@@ -321,9 +325,12 @@ class Scheduler:
         prompt head skips it entirely."""
         slot = req.slot
         if self.prefix_cache is not None:
-            self.prefix_cache.insert(req.resume_tokens,
-                                     self.cache.slot_pages(slot),
-                                     int(self.cache.seq_lens[slot]))
+            # a step in flight has written positions whose tokens the
+            # host does not hold yet: only the known ones are keys
+            tokens = req.resume_tokens
+            self.prefix_cache.insert(
+                tokens, self.cache.slot_pages(slot),
+                min(int(self.cache.seq_lens[slot]), len(tokens)))
         self.cache.release_slot(slot)
         self.slots[slot] = None
         req.slot = None
@@ -340,8 +347,8 @@ class Scheduler:
         request cannot be evicted, so it finishes and frees pages).
         ``include_prefill`` widens the candidate set to mid-prefill
         chunk rows (chunked prefill holds PREFILL slots across steps;
-        on the default path prefill is synchronous and the wider set is
-        identical to active())."""
+        on the default path a request decodes from its prefill's
+        dispatch on, and the wider set is identical to active())."""
         pool = self.occupied() if include_prefill else self.active()
         candidates = [r for i, r in pool if i != exclude_slot
                       and (max_preemptions is None

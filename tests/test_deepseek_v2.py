@@ -27,6 +27,7 @@ BENCH = os.path.join(os.path.dirname(os.path.dirname(
 if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 import run as bench                                         # noqa: E402
+from serving_checks import serve_an_eos_mid_flight  # noqa: E402
 
 YARN = dict(ds.YARN_V2, original_max_position_embeddings=16)
 CFG = dict(
@@ -269,6 +270,16 @@ def test_engine_tokens_are_the_reference_argmax(family, tiny):
         assert len(outs[rid]) == 9
         assert _reference_greedy_ok(family, weights, p, outs[rid])
     assert eng.stats()["decode_compiles"] == 1
+
+
+def test_an_eos_mid_flight_is_discarded_and_its_slot_reused(family, tiny):
+    """The engine runs a step ahead: a request that finishes by EOS has
+    a row in the step already in flight, which is thrown away, and the
+    next owner of its slot (latent pages) starts clean."""
+    model, weights = tiny
+    prompts = [_ids(n, seed=n) for n in (5, 7, 6)]
+    for p, out in serve_an_eos_mid_flight(_engine(model), prompts, 8):
+        assert _reference_greedy_ok(family, weights, p, out)
 
 
 def test_two_requests_through_one_slot_in_turn(tiny):

@@ -152,8 +152,11 @@ def _engine_programs(model, bucket=32):
     row = jnp.asarray(eng.cache.block_tables[0])
     return {
         "decode": eng._run_eval(decode.lower, *args).compile().as_text(),
+        # the prefill as the engine runs it: it also writes its first
+        # token into the slot tokens
         "prefill": eng._run_eval(
-            eng._prefill.lower, eng._state_vals, eng.cache.pools, ids, row,
+            eng._prefill.lower, eng._state_vals, eng.cache.pools,
+            eng._slot_tokens, jnp.asarray(0, jnp.int32), ids, row,
             jnp.asarray(bucket, jnp.int32)).compile().as_text(),
     }
 

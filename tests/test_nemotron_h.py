@@ -28,6 +28,7 @@ BENCH = os.path.join(os.path.dirname(os.path.dirname(
 if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 import run as bench                                         # noqa: E402
+from serving_checks import serve_an_eos_mid_flight  # noqa: E402
 
 CFG = dict(
     family="nemotron_h", vocab_size=128, hidden_size=64,
@@ -291,6 +292,16 @@ def test_engine_tokens_are_the_reference_argmax(family, tiny):
     assert stats["ssm"]["state_bytes_slot"] == family.state_slot_bytes(CFG)
     assert 1.0 <= stats["ssm"]["active_slots"] <= 3.0
     assert stats["moe"]["layers"] == 2
+
+
+def test_an_eos_mid_flight_is_discarded_and_its_slot_reused(family, tiny):
+    """The engine runs a step ahead: a request that finishes by EOS has
+    a row in the step already in flight, which is thrown away, and the
+    next owner of its slot (slot state) starts clean."""
+    model, weights = tiny
+    prompts = [_ids(n, seed=n) for n in (5, 7, 6)]
+    for p, out in serve_an_eos_mid_flight(_engine(model), prompts, 8):
+        assert _reference_greedy_ok(family, weights, p, out)
 
 
 def test_a_slot_taken_after_another_left_it_starts_from_zero_state(tiny):

@@ -411,14 +411,13 @@ class TestServingChaos:
             "serving_requests_shed_total")
         assert shed.labels(reason="poison").value >= 1
 
-    def test_decode_poison_quarantine_bisects(self):
-        """A batched decode failure is not attributable — the batch is
-        requeued and re-served serially; the request whose SOLO decode
-        fails is the named poison, everyone else finishes."""
+    @staticmethod
+    def _bisect_a_decode_poison(fail_twice):
+        """Two requests through two slots, the first two decode steps
+        made to fail by ``fail_twice(engine)``. -> (engine, the finished
+        request's id)"""
         eng = _tiny_engine(max_slots=2, num_blocks=32, block_size=4)
-        # hit 1: batched decode (2 active) fails -> quarantine both;
-        # hit 2: first SOLO decode fails -> that request is the poison
-        fi.enable("serving.decode:error@1..2", seed=0)
+        fail_twice(eng)
         a = eng.add_request([1, 2, 3], max_new_tokens=4)
         b = eng.add_request([4, 5, 6], max_new_tokens=4)
         eng.run()
@@ -428,6 +427,58 @@ class TestServingChaos:
         failed = sa if sa["state"] == "failed" else sb
         assert failed["reason"] == "poison"
         assert eng.stats()["requests_finished"] == 1
+        return eng, (b if failed is sa else a)
+
+    def test_decode_poison_quarantine_bisects(self):
+        """A batched decode failure is not attributable — the batch is
+        requeued and re-served serially; the request whose SOLO decode
+        fails is the named poison, everyone else finishes."""
+        # hit 1: batched decode (2 active) fails -> quarantine both;
+        # hit 2: first SOLO decode fails -> that request is the poison
+        self._bisect_a_decode_poison(
+            lambda eng: fi.enable("serving.decode:error@1..2", seed=0))
+
+    def test_a_readback_that_raises_bisects_the_same_way(self):
+        """A decode step that fails on the device raises at its
+        readback, after the engine dispatched the step behind it. That
+        step is discarded with the pools, the batch takes the path of a
+        failed dispatch (quarantine, then the serial decode names the
+        poison), and the survivor's tokens are a clean engine's."""
+        dispatched, read, failures = [], [], []
+
+        def fail_twice(eng):
+            run_eval, readback = eng._run_eval, eng._readback
+            on_failure = eng._on_decode_failure
+
+            def spy_run_eval(fn, *args):
+                out = run_eval(fn, *args)
+                if fn is eng._decode:
+                    dispatched.append(out[0])
+                return out
+
+            def failing_readback(out):
+                if any(out is d for d in dispatched):
+                    read.append(out)
+                    if len(read) <= 2:
+                        raise RuntimeError("the step failed on the device")
+                return readback(out)
+
+            def spy_on_failure(rows, exc, lost=False):
+                failures.append((len(rows), lost))
+                return on_failure(rows, exc, lost)
+
+            eng._run_eval, eng._readback = spy_run_eval, failing_readback
+            eng._on_decode_failure = spy_on_failure
+
+        eng, survivor = self._bisect_a_decode_poison(fail_twice)
+        assert failures == [(2, True), (1, True)]
+        # the step dispatched behind the first failure was never read
+        assert sum(not any(d is r for r in read) for d in dispatched) == 1
+        assert eng.cache.pools_alive() and not eng.has_work()
+        clean = _tiny_engine(max_slots=2, num_blocks=32, block_size=4)
+        rid = clean.add_request(eng.requests[survivor].prompt,
+                                max_new_tokens=4)
+        assert clean.run()[rid] == eng.output(survivor)
 
     def test_output_parity_with_flags_off(self):
         """Degradation knobs unset + injection off = the engine's

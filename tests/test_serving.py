@@ -37,6 +37,8 @@ from paddle_tpu.serving.kv_cache import (
     PagedKVCache,
 )
 
+from serving_checks import serve_an_eos_mid_flight
+
 # the package re-exports a function under the module's name
 pa_module = importlib.import_module(
     "paddle_tpu.serving.kernels.paged_attention")
@@ -600,6 +602,79 @@ class TestEngineParity:
         for p, rid in zip(prompts, ids):
             assert outs[rid] == _greedy_ref(m, p, 4)
         assert eng.stats()["decode_compiles"] == 1
+
+
+# ---------------------------------------------------------------------------
+# one step ahead of the device
+# ---------------------------------------------------------------------------
+
+class TestStepAhead:
+    def test_step_n_plus_1_goes_out_before_step_n_is_read(self, llama):
+        """A spy on the dispatches and the readbacks: each decode step
+        is dispatched while the one before it is still on the device,
+        except where the batch ran dry in between, and the engine's own
+        account says so."""
+        m, cfg = llama
+        eng = serving.Engine(m, max_slots=3, num_blocks=64, block_size=4)
+        run_eval, readback = eng._run_eval, eng._readback
+        # id -> (step, its tokens): held, so that no id is reused
+        steps, events = {}, []
+
+        def spy_run_eval(fn, *args):
+            out = run_eval(fn, *args)
+            if fn is eng._decode:
+                steps[id(out[0])] = (len(steps), out[0])
+                events.append(("dispatch", len(steps) - 1))
+            return out
+
+        def spy_readback(out):
+            if id(out) in steps:
+                events.append(("read", steps[id(out)][0]))
+            return readback(out)
+
+        eng._run_eval, eng._readback = spy_run_eval, spy_readback
+        rng = np.random.RandomState(5)
+        work = [(rng.randint(0, cfg.vocab_size, (n,)).tolist(), new)
+                for n, new in ((5, 12), (9, 15), (3, 10), (7, 14),
+                               (6, 11), (4, 13))]
+        rids = [eng.add_request(p, max_new_tokens=new) for p, new in work]
+        outs = eng.run()
+        for (p, new), rid in zip(work, rids):
+            assert outs[rid] == _greedy_ref(m, p, new)
+        at = {e: i for i, e in enumerate(events)}
+        n = len(steps)
+        assert sorted(at) == sorted([("dispatch", k) for k in range(n)]
+                                    + [("read", k) for k in range(n)])
+        ahead = sum(at[("dispatch", k + 1)] < at[("read", k)]
+                    for k in range(n - 1))
+        stats = eng.stats()
+        assert stats["ahead"] == {"steps": n, "ahead": ahead,
+                                  "discarded_rows": 0,
+                                  "share": 100.0 * ahead / n}
+        assert stats["ahead"]["share"] >= 90
+        assert stats["decode_steps"] == n and stats["decode_compiles"] == 1
+
+    def test_an_eos_mid_flight_is_discarded_and_its_slot_reused(self,
+                                                                 llama):
+        m, cfg = llama
+        rng = np.random.RandomState(6)
+        prompts = [rng.randint(0, cfg.vocab_size, (n,)).tolist()
+                   for n in (5, 7, 6)]
+        eng = serving.Engine(m, max_slots=2, num_blocks=64, block_size=4)
+        eos = None
+        for i, (p, out) in enumerate(
+                serve_an_eos_mid_flight(eng, prompts, 8)):
+            if i == 0:
+                eos = out[-1]
+            assert out == _greedy_ref(m, p, 8, eos if i == 0 else None)
+
+    def test_no_stats_before_the_first_decode_step(self, llama):
+        m, _ = llama
+        eng = serving.Engine(m, max_slots=2, num_blocks=16, block_size=4)
+        assert eng.stats()["ahead"] is None
+        eng.add_request([1, 2, 3], max_new_tokens=1)
+        eng.run()       # a prefill alone: no decode step
+        assert eng.stats()["ahead"] is None
 
 
 # ---------------------------------------------------------------------------

@@ -109,20 +109,44 @@ def test_only_the_tables_names_appear(traced, kind, steps):
     assert names == (ALL_SPANS - STEP_SPANS) | steps
 
 
+def _program(event):
+    """What names the program of a span: a decode step's number or a
+    prefill's request."""
+    stats = event[3]
+    return ("step", stats["step"]) if "step" in stats \
+        else ("request", stats["request"])
+
+
 @pytest.mark.parametrize("kind", ["split", "chunked"])
 def test_children_lie_inside_a_step_that_ends_after_its_readback(
         traced, kind):
+    """The mixed step holds its upload, dispatch and readback. A split
+    engine runs a step ahead: a prefill or decode step holds its upload
+    and dispatch, and its tokens are read back once, after it ended,
+    under a ``serving.readback`` that names the same program."""
     spans = traced[kind]
     parents = [e for e in spans if e[0] in STEP_SPANS]
     children = [e for e in spans if e[0] in CHILD_SPANS]
-    assert parents and len(children) == 3 * len(parents)
-    for child in children:
+    inner = ["serving.upload", "serving.dispatch"]
+    if kind == "chunked":
+        inner.append("serving.readback")
+    nested = [c for c in children if any(_inside(c, p) for p in parents)]
+    assert parents and len(nested) == len(inner) * len(parents)
+    for child in nested:
         assert sum(_inside(child, p) for p in parents) == 1, child
     for parent in parents:
         mine = [c for c in children if _inside(c, parent)]
-        assert [c[0] for c in mine] == [
-            "serving.upload", "serving.dispatch", "serving.readback"]
+        assert [c[0] for c in mine] == inner
         assert parent[2] >= mine[-1][2]
+    readbacks = [c for c in children if c not in nested]
+    if kind == "chunked":
+        assert not readbacks
+    else:
+        assert {c[0] for c in readbacks} == {"serving.readback"}
+        ended = {_program(p): p[2] for p in parents}
+        assert sorted(map(_program, readbacks)) == sorted(ended)
+        for c in readbacks:
+            assert c[1] >= ended[_program(c)], c
     # scheduling and accepting are a step's siblings, not its children
     for e in spans:
         if e[0] in ("serving.schedule", "serving.accept"):
@@ -156,14 +180,16 @@ def test_spans_cover_the_engines_time(traced, kind):
 
 
 def _timed_run(eng):
-    """Run the engine dry; -> the walls of its decode-only steps."""
+    """Run the engine dry; -> the walls of its decode-only steps: the
+    calls that read back a decode step and no prefill."""
     walls = []
     while eng.has_work():
-        before = eng.metrics.prefill_runs
+        before = eng.metrics.prefill_runs, eng.metrics.decode_steps
         t0 = time.monotonic()
         eng.step()
         wall = time.monotonic() - t0
-        if eng.metrics.prefill_runs == before:
+        if eng.metrics.prefill_runs == before[0] \
+                and eng.metrics.decode_steps > before[1]:
             walls.append(wall)
     return walls
 

@@ -287,9 +287,15 @@ class TestServingTimelineAcceptance:
                 time.sleep(0.35)
             return orig(slot, req)
 
+        # compile the prefill bucket and the decode step first: the
+        # engine dispatches A's first decode in the step after its
+        # prefill, after B arrived, and B's TTFT must not carry that
+        # compile
+        eng.add_request(prompt_b, max_new_tokens=2)
+        eng.run()
         eng._prefill_request = slow_prefill
         rid_a = eng.add_request(prompt_a, max_new_tokens=16)
-        eng.step()      # A admitted + slow prefill + first decode
+        eng.step()      # A admitted + slow prefill dispatched
         # B arrives AFTER A's slow prefill so only A's TTFT carries the
         # forced outlier — the two must land in different buckets
         rid_b = eng.add_request(prompt_b, max_new_tokens=10)
